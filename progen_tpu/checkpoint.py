@@ -547,14 +547,9 @@ def get_checkpoint_fns(
                 # restored whole onto the default device — exactly what
                 # single-host inference wants
                 dev = jax.sharding.SingleDeviceSharding(jax.devices()[0])
-                # orbax changed metadata()'s return shape: older releases
-                # (<=0.7.x) hand back the pytree itself, newer ones wrap it
-                meta_obj = ckptr.metadata(last / "state")
-                meta_tree = (
-                    meta_obj["params"]
-                    if isinstance(meta_obj, dict)
-                    else meta_obj.item_metadata.tree["params"]
-                )
+                meta_tree = ckptr.metadata(
+                    last / "state"
+                ).item_metadata.tree["params"]
                 abstract_params = jax.tree.map(
                     lambda m: jax.ShapeDtypeStruct(
                         m.shape, m.dtype, sharding=dev
@@ -572,25 +567,14 @@ def get_checkpoint_fns(
                 else ocp.RestoreArgs(),
                 abstract_params,
             )
-            try:
-                restored = ckptr.restore(
-                    last / "state",
-                    args=ocp.args.PyTreeRestore(
-                        item={"params": abstract_params},
-                        restore_args={"params": restore_args},
-                        partial_restore=True,
-                    ),
-                )
-            except TypeError:
-                # pre-0.8 orbax spells partial restore as empty transforms
-                restored = ckptr.restore(
-                    last / "state",
-                    args=ocp.args.PyTreeRestore(
-                        item={"params": abstract_params},
-                        restore_args={"params": restore_args},
-                        transforms={},
-                    ),
-                )
+            restored = ckptr.restore(
+                last / "state",
+                args=ocp.args.PyTreeRestore(
+                    item={"params": abstract_params},
+                    restore_args={"params": restore_args},
+                    partial_restore=True,
+                ),
+            )
         return Package(
             next_seq_index=meta["next_seq_index"],
             state=restored["params"],

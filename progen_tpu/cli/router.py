@@ -70,6 +70,44 @@ import time
 import click
 
 
+def _host_chips() -> list:
+    """The TPU chips this process may hand to spawned replicas, as
+    ``TPU_VISIBLE_CHIPS`` indices; [] on a host without chips. Read from
+    the environment and the device files, never from jax: a chip belongs
+    to one process at a time, and that process is a replica — the router
+    must not initialise a backend. Older VM images expose chips as
+    /dev/accel<N>, newer ones as numbered VFIO groups /dev/vfio/<N>."""
+    import glob
+
+    pinned = os.environ.get("TPU_VISIBLE_CHIPS")
+    if pinned:
+        return [c.strip() for c in pinned.split(",") if c.strip()]
+    n = len(glob.glob("/dev/accel[0-9]*")) + sum(
+        os.path.basename(p).isdigit() for p in glob.glob("/dev/vfio/*")
+    )
+    return [str(i) for i in range(n)]
+
+
+def _replica_env(i: int, chips: list):
+    """Environment for spawned replica ``i``: on a chip host, exactly one
+    chip of its own (with the parent's environment unchanged every
+    replica would try to claim every chip, and all but the first would
+    fail or hang); on a host without chips, the parent's environment."""
+    if not chips:
+        return None
+    if i >= len(chips):
+        raise click.ClickException(
+            f"replica{i} needs a chip of its own, but this host exposes "
+            f"{len(chips)} ({', '.join(chips)}): one process per chip"
+        )
+    return {
+        **os.environ,
+        "TPU_VISIBLE_CHIPS": chips[i],
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+    }
+
+
 @click.command()
 @click.option("--replica", "replica_specs", multiple=True,
               help="replica endpoint, repeatable: 'sock=PATH' or "
@@ -175,8 +213,10 @@ def main(replica_specs, spawn, checkpoint_path, fleet_dir, respawn,
                  "collector's TSDB is the policy's signal source)")
 
     procs = {}  # replica index -> (Popen, replica_dir, log file)
+    chips = _host_chips()
 
     def _spawn_replica(i, replay=False):
+        env = _replica_env(i, chips)
         rdir = os.path.join(fleet_dir, f"replica{i}")
         os.makedirs(rdir, exist_ok=True)
         args = [
@@ -205,11 +245,13 @@ def main(replica_specs, spawn, checkpoint_path, fleet_dir, respawn,
             args += ["--replay", rdir]
         log = open(os.path.join(rdir, "replica.log"), "ab")
         proc = subprocess.Popen(
-            args, stdin=subprocess.DEVNULL, stdout=log, stderr=log
+            args, stdin=subprocess.DEVNULL, stdout=log, stderr=log,
+            env=env,
         )
         procs[i] = (proc, rdir, log)
         print(
             f"replica{i}: pid {proc.pid}"
+            + (f" on chip {chips[i]}" if chips else "")
             + (" (replaying its journal)" if replay else ""),
             file=sys.stderr,
         )

@@ -399,6 +399,12 @@ def main(checkpoint_path, max_slots, max_queue, max_len, quantize_int8,
                 startup_pin = f.read().strip() or None
         except OSError:
             startup_pin = None
+    # the one line that says what this replica is on (same record as
+    # cli/train.py's; each of the router's --spawn children reports its
+    # own visible device set here), before the restore touches the device
+    from progen_tpu.profiling import announce_startup
+
+    startup = announce_startup("serve")
     sched, engine, ckpt_name = _build(
         checkpoint_path, max_slots, max_len, max_queue,
         quantize_int8=quantize_int8, journal=journal,
@@ -415,6 +421,7 @@ def main(checkpoint_path, max_slots, max_queue, max_len, quantize_int8,
     # events.jsonl — `progen-tpu-telemetry export-trace` renders each
     # accepted request as one async track (queued → prefill → decode)
     telemetry.configure(sink=tracker.log_event)
+    telemetry.get_telemetry().emit(startup)
     run_dir = getattr(tracker, "path", None)
     if run_dir is not None:
         print(

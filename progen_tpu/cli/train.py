@@ -138,8 +138,8 @@ class AnomalyRollback(Exception):
                    "finite gate already refused any non-finite update)")
 @click.option("--prom_file", default=None, type=str,
               help="write train-loop Prometheus text exposition here "
-                   "(goodput %, step_ms quantiles, tokens/s/chip, MFU, HBM "
-                   "gauges, resilience counters; atomic rewrite on the "
+                   "(goodput %, step_ms quantiles, tokens/s/chip, MFU on a "
+                   "TPU, HBM gauges, resilience counters; atomic rewrite on the "
                    "--validate_every cadence and at exit; node-exporter "
                    "textfile-collector compatible)")
 @click.option("--prom_port", default=0,
@@ -370,6 +370,17 @@ def main(
         mesh_data = -1 if (data_parallel or mesh_seq * mesh_model > 1) else 1
     mesh = make_mesh(data=mesh_data, seq=mesh_seq, model=mesh_model)
 
+    # the one line that says what this run is on (chip_smoke.py asserts
+    # platform == "tpu" from it); the same record joins the event stream
+    # once the tracker exists below
+    from progen_tpu import profiling
+    from progen_tpu.data import _native
+
+    startup = profiling.announce_startup(
+        "train", mesh,
+        codec="native" if _native.load() is not None else "python",
+    )
+
     if mesh_pipe > 1 and (batch_size // pipe_m) % mesh.shape["data"]:
         raise click.UsageError(
             f"PPxDP composition shards each {batch_size // pipe_m}-row "
@@ -436,8 +447,10 @@ def main(
         step_print,
         write_prometheus,
     )
+    from progen_tpu.telemetry.hbm import device_memory_stats
 
     telemetry.configure(sink=tracker.log_event)
+    telemetry.get_telemetry().emit(startup)
     ledger = GoodputLedger()
 
     # forensics: the black box rides the telemetry tap; the profile pin
@@ -543,12 +556,13 @@ def main(
     signal.signal(signal.SIGTERM, _request_stop)
     signal.signal(signal.SIGINT, _request_stop)
 
-    from progen_tpu import profiling
-
+    # per-chip numbers divide by the MESH's devices, not the host's: the
+    # default mesh is one device even on a four-chip host
+    mesh_devices = list(mesh.devices.flat)
     timer = profiling.StepTimer(
-        n_chips=len(jax.devices()),
+        n_chips=len(mesh_devices),
         flops_per_tok=profiling.flops_per_token(config),
-        peak=profiling.peak_flops(jax.devices()[0]),
+        peak=profiling.peak_flops(mesh_devices[0]),
     )
     import time
 
@@ -692,9 +706,9 @@ def main(
                 # quantiles render from; throughput/MFU ride as gauges
                 reg.observe("step_s", perf["step_ms"] / 1000.0)
                 reg.set_gauges({
-                    "tokens_per_sec_per_chip":
-                        perf["tokens_per_sec_per_chip"],
-                    "mfu": perf["mfu"],
+                    k: perf[k]
+                    for k in ("tokens_per_sec_per_chip", "mfu")
+                    if k in perf  # no mfu without a device peak (CPU)
                 })
             with ledger.track("log"):
                 if is_coordinator():
@@ -705,6 +719,17 @@ def main(
                      **(perf or {}), **hbm_gauges()},
                     step=p_step,
                 )
+                if p_step == start_step + 1 and is_coordinator():
+                    # balance check: with the state placed and one step
+                    # run, every device of the mesh must hold its share
+                    # (not everything on device 0)
+                    step_print(
+                        p_step,
+                        "bytes_in_use per device: " + " ".join(
+                            f"{m['device']}={m.get('bytes_in_use', 'n/a')}"
+                            for m in device_memory_stats(mesh_devices)
+                        ),
+                    )
         pbar = tqdm.tqdm(
             total=num_total, initial=min(seq_cursor, num_total),
             mininterval=10, unit="seq",

@@ -283,22 +283,7 @@ def _specs(w: int, d: int):
 # every kernel here writes disjoint output blocks per grid step (the halo
 # backward's overlap is resolved OUTSIDE the kernel), so Mosaic may reorder
 # and pipeline both grid dimensions freely.
-# jax <0.7 spells CompilerParams as TPUCompilerParams — accept both so the
-# module imports (and the XLA fallback paths run) across the version range
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-
-# Can the kernels in this module actually trace under the installed jax?
-# They lean on the 0.7-era API family — ``jax.typeof`` (vma plumbed into
-# out_shapes), the vma kwarg on ShapeDtypeStruct, CompilerParams (aliased
-# above). ``jax.typeof`` is the discriminating probe: absent it, calling
-# any kernel raises AttributeError mid-trace. Model code (models/layers.py,
-# parallel/ring_attention.py) consults this flag and falls back to the XLA
-# golden path instead, so a config shipping use_pallas_attn=true stays
-# runnable on an older runtime; kernel tests skip on it.
-PALLAS_API_OK = hasattr(jax, "typeof") and _CompilerParams is not None
-_PARALLEL_GRID = _CompilerParams(
+_PARALLEL_GRID = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel")
 )
 
@@ -330,7 +315,8 @@ def _parse_bwd_impl(bwd_impl: str) -> tuple[str, int] | None:
 #
 # pallas_policy.json is a table of on-chip-measured (fwd, bwd, bh_block)
 # winners keyed by the shape they were measured at — (window, n, batch*heads)
-# — written by bench.py's kernel phases (record_policy_entry) and read here.
+# — read here. bench.py's kernel phases write the same shape under runs/
+# (record_policy_entry); promoting a row into the tracked table is a change.
 # Lookup picks the nearest measured shape in log-space with the window
 # dominating (the masked-waste/overhead crossover is a function of w first;
 # n and bh move the per-program amortization second). An exact match applies
@@ -340,9 +326,9 @@ def _parse_bwd_impl(bwd_impl: str) -> tuple[str, int] | None:
 
 _POLICY_PATH = Path(__file__).with_name("pallas_policy.json")
 
-# The round-3 on-chip v5e measurements (BENCH_DETAIL_TPU_r3b.json, honest
-# host-fetch-fenced timings) — the built-in fallback when the JSON table is
-# absent or unreadable:
+# Measurements on one v5e chip, 2026-07-29, older jax; not re-measured on
+# the current code — the built-in rows when the JSON table is absent or
+# unreadable:
 #   w=256 @ n1024 bh128: fwd XLA 3.56 ms vs Pallas 3.99 → XLA fwd;
 #          bwd halo 8.79 ms vs XLA 10.71 → Pallas halo bwd (1.22x)
 #   w=512 @ n1024 bh128: fwd Pallas g4 4.02 vs XLA 7.87 → Pallas fwd g4;
@@ -429,15 +415,16 @@ def measured_impls(
     return e["fwd"], e["bwd"], e["bh_block"]
 
 
-def record_policy_entry(entry: dict, path: Path | None = None) -> None:
-    """Merge one measured winner into the policy table (bench.py's kernel
-    phases call this after an on-chip, non-suspect run; keyed by the
+def record_policy_entry(entry: dict, path: Path) -> None:
+    """Merge one measured winner into the policy-shaped table at ``path``
+    (bench.py's kernel phases call this after an on-chip, non-suspect run,
+    with a path under ``runs/`` — the tracked pallas_policy.json is an
+    input that a reviewed change updates, never a run; keyed by the
     measured (window, n, bh) so re-measurement replaces, never duplicates).
     Extra keys (timings, provenance) are stored verbatim."""
     missing = [k for k in _ENTRY_KEYS if k not in entry]
     if missing:
         raise ValueError(f"policy entry missing keys {missing}")
-    path = path or _POLICY_PATH
     try:
         doc = json.loads(path.read_text())
         assert isinstance(doc.get("entries"), list)
@@ -504,12 +491,10 @@ def _safe_bh_block(bh_block: int, bh: int, w: int, n_probs: int = 1) -> int:
 
 def _sds(shape, dtype, like):
     """ShapeDtypeStruct carrying ``like``'s varying-mesh-axes type (vma):
-    under jax 0.9's shard_map check_vma, pallas_call outputs must declare
-    which manual axes they vary over — inherit it from an input, which is
+    under shard_map's check_vma, pallas_call outputs must declare which
+    manual axes they vary over — inherit it from an input, which is
     frozenset() outside shard_map (a no-op there)."""
-    return jax.ShapeDtypeStruct(
-        shape, dtype, vma=getattr(jax.typeof(like), "vma", None)
-    )
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 def _halo_spec(w: int, d: int, g: int):
     """BlockSpec for a (bh, w, d) halo array: every program reads its own

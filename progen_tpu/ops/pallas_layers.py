@@ -11,8 +11,9 @@ kernels close that gap:
     its (block, d) row-tile in f32 (flax LayerNorm replica: scale-only,
     f32 stats, biased variance via E[x^2]-E[x]^2 clamped at 0),
     normalizes the single halo row it needs from the previous block (a
-    second BlockSpec over the same array, row granularity — no HBM
-    duplication), shifts the whole tile down one row, and keeps the
+    second BlockSpec over the same array fetching the sublane-aligned
+    group of rows that ends at it — Mosaic refuses a one-row block; no
+    HBM duplication), shifts the whole tile down one row, and keeps the
     shifted values only in the first ``d - d//2`` lanes (the split
     ``shift_tokens`` applies). Program 0's halo row is zeroed
     in-register, reproducing the reference's zero pad.
@@ -39,11 +40,13 @@ the right default here because both ops are bandwidth-bound enough that
 the fused forward is where the win lives.
 
 Impl selection mirrors the attention policy: ``layer_entries`` in the
-same pallas_policy.json, keyed (kind, n, d), written by bench.py's
-``kernel-fused-w*`` phases and read via ``measured_layer_impl``.
+same pallas_policy.json, keyed (kind, n, d), read via
+``measured_layer_impl`` (bench.py's ``kernel-fused-w*`` phases write rows
+of the same shape under runs/).
 
-VMEM at block=256, d=1024, f32: SGU acc + normalized gate 2 MB + the
-(256, 256) weight tile 0.25 MB; norm-shift holds one (256, d) tile.
+VMEM: see ``safe_layer_block`` — the SGU kernel's working set is what
+bounds the row tile (256 rows at tiny's gate width 1024, 128 at large's
+3584 under the 16 MiB scoped limit of a v5e).
 """
 
 from __future__ import annotations
@@ -58,32 +61,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from progen_tpu.ops.pallas_attention import _CompilerParams, _POLICY_PATH
+from progen_tpu.ops.pallas_attention import _POLICY_PATH, _sds
 from progen_tpu.ops.sgu import causal_sgu_mix
 from progen_tpu.ops.shift import shift_tokens
-
-# Strictly weaker capability gate than the attention kernel's
-# PALLAS_API_OK: these kernels need CompilerParams but not ``jax.typeof``
-# (the vma declaration below degrades to a plain ShapeDtypeStruct on jax
-# versions that predate shard_map's check_vma), so the interpret-mode
-# parity tests run on the older pins too.
-LAYER_PALLAS_OK = _CompilerParams is not None
-
-
-def _out_struct(shape, dtype, like):
-    """ShapeDtypeStruct for pallas_call outputs: carries ``like``'s
-    varying-mesh-axes type where jax has one (see pallas_attention._sds),
-    plain otherwise."""
-    if hasattr(jax, "typeof"):
-        return jax.ShapeDtypeStruct(
-            shape, dtype, vma=getattr(jax.typeof(like), "vma", None)
-        )
-    return jax.ShapeDtypeStruct(shape, dtype)
 
 
 # --------------------------------------------------------------------------
 # XLA reference compositions — the exact unfused math (flax LayerNorm with
-# use_bias=False + ops/shift.py + ops/sgu.py), used as the fallback
+# use_bias=False + ops/shift.py + ops/sgu.py), used as the policy's "xla"
 # forward, the custom-VJP backward, and the parity golden in tests.
 
 
@@ -127,17 +112,32 @@ def _norm_rows(x32, scale32, epsilon):
     return (x32 - mu) * (jax.lax.rsqrt(var + epsilon) * scale32)
 
 
+def _halo_rows(dtype) -> int:
+    """Rows in fused_norm_shift's halo block: one native sublane tile of
+    the input dtype ((8, 128) for 4-byte, (16, 128) for 2-byte elements).
+    Mosaic refuses a block whose last two dims are not tile-aligned, so
+    the "previous row" is fetched as the aligned group of rows ending at
+    it, not as a one-row block."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
 def _norm_shift_kernel(x_ref, prev_ref, s_ref, o_ref, *, epsilon, split):
     f32 = jnp.float32
     scale = s_ref[...].astype(f32)  # (1, d), broadcasts over rows
     y = _norm_rows(x_ref[0].astype(f32), scale, epsilon)  # (bn, d)
     # the halo: the previous block's LAST row, normalized here rather
-    # than re-read from the neighbor's output (programs are independent);
-    # program 0 reads its own row 0 through the clamped index map and
-    # masks it to the reference's zero pad
-    prev = _norm_rows(prev_ref[0].astype(f32), scale, epsilon)  # (1, d)
-    prev = prev * (pl.program_id(1) > 0).astype(f32)
-    shifted = jnp.concatenate([prev, y[:-1, :]], axis=0)
+    # than re-read from the neighbor's output (programs are independent).
+    # It arrives as the last row of an aligned (hb, d) group; a masked
+    # sublane sum picks it out without an unaligned slice. Program 0
+    # reads its own first group through the clamped index map and masks
+    # it to the reference's zero pad.
+    halo = _norm_rows(prev_ref[0].astype(f32), scale, epsilon)  # (hb, d)
+    hrow = jax.lax.broadcasted_iota(jnp.int32, halo.shape, 0)
+    keep = (hrow == halo.shape[0] - 1) & (pl.program_id(1) > 0)
+    prev = jnp.sum(jnp.where(keep, halo, 0.0), axis=0, keepdims=True)
+    # shift the tile down one row: sublane rotate, then row 0 <- halo
+    row = jax.lax.broadcasted_iota(jnp.int32, y.shape, 0)
+    shifted = jnp.where(row == 0, prev, pltpu.roll(y, 1, 0))
     col = jax.lax.broadcasted_iota(jnp.int32, y.shape, 1)
     out = jnp.where(col < split, shifted, y)
     o_ref[0] = out.astype(o_ref.dtype)
@@ -188,6 +188,12 @@ def _sgu_kernel(x_ref, g_ref, w_ref, b_ref, s_ref, o_ref, acc_ref, *,
 def _norm_shift_pallas(x, scale, epsilon, block, interpret, out_dtype):
     b, n, d = x.shape
     bn = block
+    hb = _halo_rows(x.dtype)
+    if bn % hb or n % bn:
+        raise ValueError(
+            f"fused_norm_shift: row tile {bn} must divide n={n} and be a "
+            f"multiple of the {hb}-row sublane tile of {x.dtype}"
+        )
     scale2 = scale.reshape(1, d)
     grid = (b, n // bn)
     return pl.pallas_call(
@@ -198,11 +204,12 @@ def _norm_shift_pallas(x, scale, epsilon, block, interpret, out_dtype):
         in_specs=[
             pl.BlockSpec((1, bn, d), lambda bi, i: (bi, i, 0),
                          memory_space=pltpu.VMEM),
-            # row-granular halo spec over the SAME array: element row
-            # i*bn - 1 (the previous block's last row), clamped at 0
+            # halo spec over the SAME array: the aligned hb-row group
+            # ending at element row i*bn - 1 (the previous block's last
+            # row), clamped at group 0
             pl.BlockSpec(
-                (1, 1, d),
-                lambda bi, i: (bi, jnp.maximum(i * bn - 1, 0), 0),
+                (1, hb, d),
+                lambda bi, i: (bi, jnp.maximum(i * (bn // hb) - 1, 0), 0),
                 memory_space=pltpu.VMEM,
             ),
             pl.BlockSpec((1, d), lambda bi, i: (0, 0),
@@ -210,8 +217,8 @@ def _norm_shift_pallas(x, scale, epsilon, block, interpret, out_dtype):
         ],
         out_specs=pl.BlockSpec((1, bn, d), lambda bi, i: (bi, i, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=_out_struct((b, n, d), jnp.dtype(out_dtype), x),
-        compiler_params=_CompilerParams(
+        out_shape=_sds((b, n, d), jnp.dtype(out_dtype), x),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
         interpret=interpret,
@@ -274,14 +281,14 @@ def _sgu_pallas(x, gate, weights, biases, scale, epsilon, block, interpret,
         # j reduction, flushed to HBM once when (bi, i) advances
         out_specs=pl.BlockSpec((1, bn, d), lambda bi, i, j: (bi, i, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=_out_struct((b, n, d), jnp.dtype(out_dtype), gate),
+        out_shape=_sds((b, n, d), jnp.dtype(out_dtype), gate),
         scratch_shapes=[pltpu.VMEM((bn, d), jnp.float32)],
         cost_estimate=pl.CostEstimate(
             flops=b * n * n * d,  # causal half of 2*b*n*n*d
             transcendentals=0,
             bytes_accessed=4 * b * n * d * 2 + 4 * n * n,
         ),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -324,17 +331,16 @@ fused_sgu_mix_gate.defvjp(_sgu_fwd, _sgu_bwd)
 # --------------------------------------------------------------------------
 # Measured layer policy: ``layer_entries`` in the same pallas_policy.json
 # the attention table lives in (record_policy_entry there only rewrites
-# "entries", so the two tables coexist). Keyed (kind, n, d); written by
-# bench.py's kernel-fused-w* phases, read at layer trace time.
+# "entries", so the two tables coexist). Keyed (kind, n, d); read at layer
+# trace time.
 
 _LAYER_ENTRY_KEYS = ("kind", "n", "d", "impl", "block")
 
 _LAYER_KINDS = ("norm_shift", "sgu_mix")
 
 # Unmeasured defaults: the fused kernels exist to cut HBM round-trips, so
-# until a kernel-fused-w* phase records on-chip numbers the opt-in flag
-# gets the kernel at the attention bench's proven-good tile size. Marked
-# via provenance in the seeded JSON; bench re-measurement replaces them.
+# until a kernel-fused-w* phase's on-chip numbers are promoted into the
+# table the opt-in flag gets the kernel at the attention bench's tile size.
 _LAYER_FALLBACK_ENTRIES = (
     {"kind": "norm_shift", "n": 1024, "d": 512, "impl": "pallas",
      "block": 256},
@@ -403,14 +409,14 @@ def measured_layer_impl(kind: str, n: int, d: int) -> tuple[str, int]:
     return e["impl"], e["block"]
 
 
-def record_layer_policy_entry(entry: dict, path: Path | None = None) -> None:
-    """Merge one measured layer-kernel winner into ``layer_entries``,
-    preserving every other top-level key (notably the attention table's
-    "entries") — the mirror of record_policy_entry's contract."""
+def record_layer_policy_entry(entry: dict, path: Path) -> None:
+    """Merge one measured layer-kernel winner into ``layer_entries`` of
+    the table at ``path`` (under ``runs/`` for bench phases), preserving
+    every other top-level key (notably the attention table's "entries")
+    — the mirror of record_policy_entry's contract."""
     missing = [k for k in _LAYER_ENTRY_KEYS if k not in entry]
     if missing:
         raise ValueError(f"layer policy entry missing keys {missing}")
-    path = path or _POLICY_PATH
     try:
         doc = json.loads(path.read_text())
         assert isinstance(doc, dict)
@@ -432,53 +438,82 @@ def record_layer_policy_entry(entry: dict, path: Path | None = None) -> None:
 # Dispatch entry points for models/layers.py.
 
 
-def safe_layer_block(block: int, n: int, d: int) -> int | None:
-    """Largest usable row-tile <= block: divides n, >= 8 rows (the f32
-    sublane tile), and keeps the fused-SGU working set (f32 acc + gate
-    tile + (bn, bn) f32 weight tile) within ~8 MB of VMEM. None when no
-    tile qualifies — callers fall back to the XLA reference."""
-    bn = min(max(1, int(block)), n)
-    while bn >= 8:
-        if n % bn == 0 and (bn * d * 8 + bn * bn * 4) <= (8 << 20):
+# The fused-SGU kernel's scoped-VMEM budget. Mosaic's default scoped limit
+# on a v5e is 16 MiB; the estimate below reproduces its own accounting to
+# within a few percent (bf16, bn=256, d=3584: estimated 18.9 MB, Mosaic
+# reported 17.82 MB and refused it; bn=128 compiled — chip run, PR 21), so
+# the budget keeps a margin under the limit.
+_VMEM_BUDGET = 14 << 20
+
+
+def safe_layer_block(kind: str, block: int, n: int, d: int,
+                     dtype=jnp.float32) -> int | None:
+    """Largest row-tile <= block that Mosaic accepts for the ``kind``
+    kernel on (batch, n, d) activations of ``dtype``, or None (the
+    dispatchers below raise on that). A tile must divide n, be whole
+    sublane tiles of the dtype (``_halo_rows``: 8 rows for f32, 16 for
+    bf16) and fit ``_VMEM_BUDGET``. The SGU's row tile is also the lane
+    dim of its (bn, bn) weight tile, so there it must be a multiple of
+    128 unless it spans the whole sequence. Working sets: SGU — the x and
+    gate input tiles and the output tile (each double-buffered by the
+    pipeline), the f32 accumulator, one f32 tile of temporaries and the
+    double-buffered weight tile; norm-shift — the double-buffered input
+    and output tiles plus three f32 tiles of temporaries."""
+    if kind not in _LAYER_KINDS:
+        raise ValueError(f"unknown layer kernel kind {kind!r}")
+    item = jnp.dtype(dtype).itemsize
+    rows = _halo_rows(dtype)
+    if kind == "sgu_mix":
+        step = 128
+        cost = lambda bn: bn * d * (6 * item + 8) + 8 * bn * bn
+    else:
+        step = rows
+        cost = lambda bn: bn * d * (4 * item + 12)
+    block = max(1, int(block))
+    candidates = [n] if n <= block else []
+    candidates += range(block - block % step, 0, -step)
+    for bn in candidates:
+        if n % bn == 0 and bn % rows == 0 and cost(bn) <= _VMEM_BUDGET:
             return bn
-        bn -= 1
     return None
 
 
-def _resolve(kind: str, n: int, d: int, block_override: int):
+def _resolve(kind: str, n: int, d: int, dtype, block_override: int):
     impl, blk = measured_layer_impl(kind, n, d)
     if block_override:
         impl, blk = "pallas", int(block_override)
-    return impl, safe_layer_block(blk, n, d)
+    tile = safe_layer_block(kind, blk, n, d, dtype)
+    if impl == "pallas" and tile is None:
+        # the config asked for the kernel: no silent XLA stand-in
+        raise ValueError(
+            f"fused {kind} kernel: no legal row tile <= {blk} for "
+            f"n={n}, d={d}, {jnp.dtype(dtype).name} (see safe_layer_block)"
+        )
+    return impl, tile
 
 
 def norm_shift(x, scale, epsilon, out_dtype, *, block_override: int = 0,
                interpret: bool = False):
-    """Policy-dispatched fused norm+shift; falls back to the XLA
-    reference (plain autodiff, no VJP indirection) off-policy or when no
-    legal tile exists. ``block_override`` (config.pallas_layer_block)
-    forces the kernel at that tile."""
+    """Policy-dispatched fused norm+shift on (batch, n, d): the kernel, or
+    the XLA reference (plain autodiff, no VJP indirection) where the
+    policy table's measured winner at this shape is "xla".
+    ``block_override`` (config.pallas_layer_block) forces the kernel at
+    that tile."""
     dt = jnp.dtype(out_dtype).name
-    if x.ndim != 3 or x.shape[-1] < 2:
-        return norm_shift_reference(x, scale, epsilon, dt)
-    impl, blk = _resolve("norm_shift", x.shape[-2], x.shape[-1],
+    impl, blk = _resolve("norm_shift", x.shape[-2], x.shape[-1], x.dtype,
                          block_override)
-    if impl != "pallas" or blk is None:
+    if impl != "pallas":
         return norm_shift_reference(x, scale, epsilon, dt)
     return fused_norm_shift(x, scale, epsilon, blk, interpret, dt)
 
 
 def sgu_mix_gate(x, gate, weights, biases, scale, epsilon, out_dtype, *,
                  block_override: int = 0, interpret: bool = False):
-    """Policy-dispatched fused SGU tail; same fallback contract as
-    ``norm_shift``."""
+    """Policy-dispatched fused SGU tail; same contract as ``norm_shift``."""
     dt = jnp.dtype(out_dtype).name
-    if gate.ndim != 3:
-        return sgu_mix_gate_reference(x, gate, weights, biases, scale,
-                                      epsilon, dt)
     impl, blk = _resolve("sgu_mix", gate.shape[-2], gate.shape[-1],
-                         block_override)
-    if impl != "pallas" or blk is None:
+                         gate.dtype, block_override)
+    if impl != "pallas":
         return sgu_mix_gate_reference(x, gate, weights, biases, scale,
                                       epsilon, dt)
     return fused_sgu_mix_gate(x, gate, weights, biases, scale, epsilon,

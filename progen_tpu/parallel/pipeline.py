@@ -34,8 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from progen_tpu.parallel.partition import pcast, shard_map
-
 
 def pipeline_apply(
     block_fn: Callable,
@@ -106,26 +104,24 @@ def pipeline_apply(
             )
             return left_buf, out
 
-        # carry must be marked device-varying over the pipeline axis (jax
-        # 0.9 varying-manual-axes typing for scan-of-ppermute); under DP
+        # carry must be marked device-varying over the pipeline axis (the
+        # varying-manual-axes typing for scan-of-ppermute); under DP
         # composition the zeros_like already inherits the data-varying type
         # from the sharded input, so only the stage axis needs the cast
-        init = pcast(jnp.zeros_like(x_mb[0]), (axis,), to="varying")
+        init = jax.lax.pcast(
+            jnp.zeros_like(x_mb[0]), (axis,), to="varying"
+        )
         _, outs = jax.lax.scan(tick, init, jnp.arange(T))
         # the LAST stage's outputs at ticks P-1 .. P-1+M-1 are the finished
         # microbatches; other stages' rows are bubble garbage that the
         # (P, ...)-stacked out_spec lets the caller discard
         return outs[None]  # (1, T, mb, ...) -> stage-stacked by out_spec
 
-    outs = shard_map(
+    outs = jax.shard_map(
         stage_fn,
         mesh=mesh,
         in_specs=(P(axis), P(None, data_axis) if dp else P()),
         out_specs=P(axis, None, data_axis) if dp else P(axis),
-        # without lax.pcast (jax < 0.7) the scan carry can't be typed as
-        # stage-varying, so the replication checker false-positives on
-        # the scan-of-ppermute; its own error prescribes disabling it
-        check_vma=hasattr(jax.lax, "pcast"),
     )(stacked_params, x_mb)
     # outs: (P, T, mb, ...); finished microbatches live on the last stage
     final = outs[n_stages - 1, n_stages - 1 : n_stages - 1 + M]

@@ -39,8 +39,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from progen_tpu.parallel.partition import pcast, shard_map
-
 
 def _tree_add_masked(acc, new, mask):
     return jax.tree.map(lambda a, n: a + n * mask.astype(n.dtype), acc, new)
@@ -120,7 +118,7 @@ def pipeline_1f1b_loss_and_grads(
         # token shards inside the loop, so the scan carry's vma must carry
         # BOTH axes from the start (scan requires a fixed carry type)
         vaxes = (axis, data_axis) if dp else (axis,)
-        varying = lambda x: pcast(x, vaxes, to="varying")
+        varying = lambda x: jax.lax.pcast(x, vaxes, to="varying")
 
         # CRITICAL: differentiate against VARYING copies of the replicated
         # param groups. vjp wrt an invariant input with a varying cotangent
@@ -138,7 +136,9 @@ def pipeline_1f1b_loss_and_grads(
         # copy keeps d_local per-shard. (pcast rejects already-varying
         # axes, so cast over data alone.)
         if dp:
-            data_varying = lambda x: pcast(x, (data_axis,), to="varying")
+            data_varying = lambda x: jax.lax.pcast(
+                x, (data_axis,), to="varying"
+            )
             local_params = jax.tree.map(data_varying, local_params)
 
         perm_right = [(i, (i + 1) % n_stages) for i in range(n_stages)]
@@ -247,7 +247,7 @@ def pipeline_1f1b_loss_and_grads(
         g_stack = jax.tree.map(lambda x: x[None], g_stack)
         return loss, g_pre, g_stack, g_post
 
-    loss, g_pre, g_stack, g_post = shard_map(
+    loss, g_pre, g_stack, g_post = jax.shard_map(
         stage_fn,
         mesh=mesh,
         in_specs=(P(), P(axis), P(),

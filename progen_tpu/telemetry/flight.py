@@ -65,6 +65,7 @@ from collections import deque
 from pathlib import Path
 from typing import Callable, Optional
 
+from progen_tpu.telemetry.hbm import device_memory_stats
 from progen_tpu.telemetry.registry import get_registry
 from progen_tpu.telemetry.spans import (
     EMIT_TAPS,
@@ -72,7 +73,6 @@ from progen_tpu.telemetry.spans import (
     host_index,
     span,
 )
-from progen_tpu.telemetry.watchdog import _device_memory_stats
 
 # ring size: at serve's per-token event rate this is the last few
 # hundred requests' worth of context — enough to reconstruct what the
@@ -277,7 +277,7 @@ class FlightRecorder:
             "records": records,
             "open_spans": tel.open_spans(),
             "stacks": _thread_stacks(),
-            "memory_stats": _device_memory_stats(),
+            "memory_stats": device_memory_stats(),
         }
         if note:
             payload["note"] = note

@@ -12,17 +12,48 @@ from __future__ import annotations
 
 from typing import Optional
 
+from progen_tpu.telemetry.spans import live_jax
+
+
+def live_devices() -> list:
+    """``jax.devices()`` when a backend is ALREADY live, else ``[]`` —
+    never initialises one (``spans.live_jax``): a jax-free process taking
+    a crash dump must not pull the chip out from under a replica."""
+    jax = live_jax()
+    if jax is None:
+        return []
+    try:
+        return list(jax.devices())
+    except Exception:
+        return []
+
+
+def device_memory_stats(devices=None) -> list:
+    """Per-device ``memory_stats()`` snapshot (numeric fields only) for
+    ``devices`` (default: every device of the live backend); [] when no
+    backend is live, ``{"device": id}`` alone where the backend reports
+    nothing (CPU)."""
+    out = []
+    for d in live_devices() if devices is None else devices:
+        try:
+            stats = d.memory_stats() or {}
+        except Exception:
+            stats = {}
+        out.append({"device": str(d.id), **{
+            k: v for k, v in stats.items() if isinstance(v, (int, float))
+        }})
+    return out
+
 
 def hbm_gauges(device=None, prefix: str = "hbm/") -> dict:
     """Flat gauge dict (GB, rounded) for ``device`` (default: first
-    visible device). Empty when the backend exposes no memory stats."""
+    device of the live backend). Empty when no backend is live or it
+    exposes no memory stats."""
     if device is None:
-        try:
-            import jax
-
-            device = jax.devices()[0]
-        except Exception:
+        devices = live_devices()
+        if not devices:
             return {}
+        device = devices[0]
     stats = getattr(device, "memory_stats", lambda: None)
     try:
         stats = stats() or {}

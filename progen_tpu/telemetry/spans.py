@@ -34,28 +34,40 @@ from typing import Callable, Optional
 _HOST_INDEX: Optional[int] = None
 
 
-def host_index() -> int:
-    """This process's index in a multi-process run (0 single-process).
+def live_jax():
+    """The ``jax`` module when a backend is ALREADY live, else ``None``.
 
-    Deliberately lazy and init-free: ``jax.process_index()`` would
-    *initialize* the backend as a side effect, which telemetry must
-    never do (tests assert backends stay uninitialized at import, and a
-    pure-host tool reading a trace has no business dialing a
-    coordinator). So we only ask jax if it is already imported AND its
-    backends are already live, and cache the answer from then on —
-    before that point every record is host 0, which is exactly right
-    for the only process that can exist pre-init."""
-    global _HOST_INDEX
-    if _HOST_INDEX is not None:
-        return _HOST_INDEX
+    Deliberately init-free: ``jax.devices()`` / ``jax.process_index()``
+    on a cold process *initialise* the backend — which claims every
+    visible chip — and telemetry must never do that (tests assert
+    backends stay uninitialized at import; the router, the collector and
+    the deploy controller emit telemetry and take flight dumps too, and
+    a chip belongs to one process at a time). So jax is consulted only
+    if it is already imported AND its backends are already up."""
     jax = sys.modules.get("jax")
     if jax is None:
-        return 0
+        return None
     try:
         from jax._src import xla_bridge
 
-        if not xla_bridge.backends_are_initialized():
-            return 0
+        return jax if xla_bridge.backends_are_initialized() else None
+    except Exception:
+        return None
+
+
+def host_index() -> int:
+    """This process's index in a multi-process run (0 single-process).
+
+    Asks jax only through ``live_jax`` and caches the answer from then
+    on — before a backend is live every record is host 0, which is
+    exactly right for the only process that can exist pre-init."""
+    global _HOST_INDEX
+    if _HOST_INDEX is not None:
+        return _HOST_INDEX
+    jax = live_jax()
+    if jax is None:
+        return 0
+    try:
         _HOST_INDEX = int(jax.process_index())
     except Exception:
         return 0

@@ -40,6 +40,7 @@ import threading
 import time
 from typing import Callable, Optional
 
+from progen_tpu.telemetry.hbm import device_memory_stats
 from progen_tpu.telemetry.registry import get_registry
 from progen_tpu.telemetry.spans import Telemetry, get_telemetry, host_index
 
@@ -211,7 +212,7 @@ class StallWatchdog:
         mem = (
             self._memory_stats_fn
             if self._memory_stats_fn is not None
-            else _device_memory_stats
+            else device_memory_stats
         )()
         record = {
             "ev": "stall_escalation",
@@ -234,25 +235,3 @@ class StallWatchdog:
             flush=True,
         )
         tel.emit(record)
-
-
-def _device_memory_stats() -> list:
-    """Per-device ``memory_stats()`` snapshot; [] when jax/backend
-    offers none (CPU) — the escalation record is still useful for its
-    open-span list."""
-    try:
-        import jax
-
-        devices = jax.devices()
-    except Exception:
-        return []
-    out = []
-    for d in devices:
-        try:
-            stats = d.memory_stats() or {}
-        except Exception:
-            stats = {}
-        out.append({"device": str(d.id), **{
-            k: v for k, v in stats.items() if isinstance(v, (int, float))
-        }})
-    return out

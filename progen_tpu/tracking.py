@@ -13,8 +13,10 @@ Backends:
     sufficient for loss-curve comparison against the reference;
   * ``NoopTracker`` — the reference's disabled mode.
 
-Only process 0 should construct a real tracker (partition.is_coordinator);
-`make_tracker` enforces that itself.
+Only process 0 should construct a real tracker; `make_tracker` enforces
+that itself through ``telemetry.spans.host_index``, which asks jax only
+when a backend is already live — the router and the deploy controller
+build trackers too, and a jax-free process must never claim a chip.
 """
 
 from __future__ import annotations
@@ -26,7 +28,7 @@ import uuid
 from pathlib import Path
 from typing import Optional
 
-from progen_tpu.parallel.partition import is_coordinator
+from progen_tpu.telemetry.spans import host_index
 
 try:  # template parity with train.py:28; fallback keeps jinja2 optional
     from jinja2 import Template
@@ -156,8 +158,9 @@ def make_tracker(
     dir: str = "./runs",
 ) -> NoopTracker:
     """Tracker factory. Disabled, or on any process but 0 -> Noop
-    (reference logs from its single process; multi-host must gate)."""
-    if disabled or not is_coordinator():
+    (reference logs from its single process; multi-host must gate — call
+    this after the backend is up there, as cli/train.py does)."""
+    if disabled or host_index() != 0:
         return NoopTracker()
     try:
         import wandb  # noqa: F401

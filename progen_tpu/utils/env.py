@@ -3,29 +3,29 @@
 flags). python-dotenv is not in this image, and the needed subset is 10
 lines: KEY=VALUE lines, ``#`` comments, optional ``export`` prefix,
 existing environment wins (dotenv's default override=False).
+
+Jax-free on purpose: the CLIs call this BEFORE importing jax, and
+``chip_smoke.py``'s parent must never initialise a backend.
 """
 
 from __future__ import annotations
 
 import os
 from pathlib import Path
+from typing import Optional
+
+# the checkout's own .env, found relative to the PACKAGE — never the CWD:
+# a CLI started from a work directory, /tmp or a spawned replica's
+# directory must run under the same LIBTPU_INIT_ARGS and compile-cache
+# directory as one started from the repo root
+REPO_ENV = Path(__file__).resolve().parents[2] / ".env"
 
 
-def load_env_file(path: str = ".env") -> dict:
+def load_env_file(path: Optional[str] = None) -> dict:
     """Load KEY=VALUE pairs into os.environ (existing keys win). Returns
     the parsed mapping; missing file -> empty dict, like load_dotenv.
-
-    A relative ``path`` not found in the CWD is searched for UPWARD through
-    parent directories (dotenv's find_dotenv behavior) — so running a CLI
-    from a project subdirectory still picks up the project's ``.env``.
-    """
-    p = Path(path)
-    if not p.is_absolute() and not p.exists():
-        for parent in Path.cwd().resolve().parents:
-            candidate = parent / path
-            if candidate.exists():
-                p = candidate
-                break
+    ``path=None`` loads the checkout's ``.env`` (``REPO_ENV``)."""
+    p = Path(path) if path is not None else REPO_ENV
     if not p.exists():
         return {}
     parsed = {}

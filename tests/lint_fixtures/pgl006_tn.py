@@ -17,7 +17,7 @@ def clean_metrics(reg):
 
 
 def clean_event(emit):
-    emit({"ev": "ring_check_vma", "backend": "tpu"})
+    emit({"ev": "startup", "platform": "tpu"})
 
 
 def clean_beacon(emit):

@@ -266,7 +266,8 @@ class TestSelfLint:
         baseline = load_baseline(REPO / "lint_baseline.json")
         new, _ = lint_paths(
             [REPO / "progen_tpu", REPO / "tests",
-             REPO / "bench.py", REPO / "__graft_entry__.py"],
+             REPO / "bench.py", REPO / "__graft_entry__.py",
+             REPO / "chip_smoke.py"],
             baseline=baseline,
         )
         assert new == [], "\n".join(f.render() for f in new)
@@ -281,7 +282,8 @@ class TestSelfLint:
         baseline = load_baseline(REPO / "lint_baseline.json")
         _, matched = lint_paths(
             [REPO / "progen_tpu", REPO / "tests",
-             REPO / "bench.py", REPO / "__graft_entry__.py"],
+             REPO / "bench.py", REPO / "__graft_entry__.py",
+             REPO / "chip_smoke.py"],
             baseline=baseline,
         )
         from progen_tpu.analysis.runner import _baseline_matches

@@ -47,17 +47,54 @@ class TestLoader:
             os.environ.update(saved)
         assert parsed["CACHE"] == str(tmp_path.resolve() / "runs/xla_cache")
 
-    def test_upward_search(self, tmp_path, monkeypatch):
-        (tmp_path / ".env").write_text("UPWARD_FOUND=yes\n")
-        sub = tmp_path / "a" / "b"
-        sub.mkdir(parents=True)
-        monkeypatch.chdir(sub)
+    def test_default_is_the_checkouts_env_not_the_cwds(self, tmp_path,
+                                                       monkeypatch):
+        # a stray .env in (or above) the CWD must not change what runs
+        (tmp_path / ".env").write_text("STRAY_CWD_ENV=yes\n")
+        monkeypatch.chdir(tmp_path)
         saved = dict(os.environ)
         try:
-            assert load_env_file()["UPWARD_FOUND"] == "yes"
+            parsed = load_env_file()
         finally:
             os.environ.clear()
             os.environ.update(saved)
+        assert "STRAY_CWD_ENV" not in parsed
+        assert parsed["JAX_COMPILATION_CACHE_DIR"] == str(
+            REPO_ROOT / "runs/xla_cache"
+        )
+
+
+class TestCliCacheDir:
+    """Started from any working directory, a CLI reports the same
+    compile-cache directory: the exported JAX_COMPILATION_CACHE_DIR when
+    there is one, else <checkout>/runs/xla_cache."""
+
+    def _cache_dir_seen_by_cli(self, cwd, extra_env):
+        import subprocess
+        import sys
+
+        env = {k: v for k, v in os.environ.items()
+               if k != "JAX_COMPILATION_CACHE_DIR"}
+        env.update(PYTHONPATH=str(REPO_ROOT), **extra_env)
+        # every CLI module loads the env file on import, before jax
+        out = subprocess.run(
+            [sys.executable, "-c",
+             "import os, progen_tpu.cli.generate_data; "
+             "print(os.environ['JAX_COMPILATION_CACHE_DIR'])"],
+            cwd=cwd, env=env, capture_output=True, text=True, timeout=60,
+        )
+        assert out.returncode == 0, out.stderr
+        return out.stdout.strip()
+
+    def test_unrelated_cwd_resolves_checkout_cache(self, tmp_path):
+        assert self._cache_dir_seen_by_cli(tmp_path, {}) == str(
+            REPO_ROOT / "runs/xla_cache"
+        )
+
+    def test_exported_cache_dir_wins(self, tmp_path):
+        assert self._cache_dir_seen_by_cli(
+            tmp_path, {"JAX_COMPILATION_CACHE_DIR": "/elsewhere/cache"}
+        ) == "/elsewhere/cache"
 
 
 class TestShippedDefaultEnv:
@@ -85,3 +122,7 @@ class TestShippedDefaultEnv:
         assert parsed["JAX_COMPILATION_CACHE_DIR"] == str(
             REPO_ROOT / "runs/xla_cache"
         )
+        # JAX's own default (1 s) decides what is worth caching: on the
+        # chip most of serve's jits compile in under the 5 s this used to
+        # demand, and a cold start recompiled them every time
+        assert "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in parsed

@@ -174,6 +174,39 @@ def test_metrics_fn_failure_never_breaks_dump(tmp_path):
     assert payload["metrics"] is None
 
 
+def test_router_process_and_its_flight_dump_never_claim_the_chip(tmp_path):
+    """A chip belongs to one process: the router (and every other
+    jax-free process — collector, deploy controller, top, lint) must not
+    initialise a JAX backend, not even at the moment of a crash dump,
+    which snapshots device memory, or when it opens its tracker."""
+    script = textwrap.dedent("""
+        import sys
+
+        import progen_tpu.cli.router  # noqa: F401
+        from progen_tpu.telemetry import flight, hbm_gauges
+        from progen_tpu.tracking import make_tracker
+
+        tracker = make_tracker("progen-router", dir=sys.argv[1])
+        assert type(tracker).__name__ == "JsonlTracker", tracker
+        flight.arm(sys.argv[1])
+        path = flight.dump_now("killed", note="test")
+        assert flight.verify_dump(path)["memory_stats"] == []
+        assert hbm_gauges() == {}
+        if "jax" in sys.modules:
+            from jax._src import xla_bridge
+
+            assert not xla_bridge.backends_are_initialized()
+        print("jax-free ok")
+    """)
+    out = subprocess.run(
+        [sys.executable, "-c", script, str(tmp_path)],
+        env={**os.environ, "PYTHONPATH": str(REPO)},
+        capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode == 0, out.stderr
+    assert "jax-free ok" in out.stdout
+
+
 # --------------------------------------------------- chaos: flight/dump
 
 

@@ -7,17 +7,7 @@ import numpy as np
 import pytest
 
 from progen_tpu.ops.attention import local_attention
-from progen_tpu.ops.pallas_attention import (
-    PALLAS_API_OK,
-    pallas_local_attention,
-)
-
-pytestmark = pytest.mark.skipif(
-    not PALLAS_API_OK,
-    reason="installed jax predates the Pallas kernel API family "
-    "(jax.typeof / pltpu.CompilerParams); models fall back to the "
-    "XLA golden these tests compare against",
-)
+from progen_tpu.ops.pallas_attention import pallas_local_attention
 
 SHAPE = (2, 3, 64, 32)  # (b, h, n, d)
 
@@ -237,8 +227,8 @@ class TestHaloVariant:
 
 class TestMixedImpl:
     """fwd_impl="xla" + Pallas backward: the per-direction measured-winner
-    combo (BENCH_DETAIL_TPU_r3b: XLA fwd wins at w=256, Pallas bwd wins at
-    both windows). Primal must equal the XLA golden exactly; grads must
+    combo (the policy table's rows: XLA fwd wins at w=256, Pallas bwd wins
+    at both windows). Primal must equal the XLA golden exactly; grads must
     match XLA autodiff to the same tolerance as the pure-Pallas path."""
 
     def test_forward_is_xla_golden(self):
@@ -328,7 +318,7 @@ class TestMixedImpl:
     def test_policy_missing_file_falls_back(self, tmp_path):
         from progen_tpu.ops import pallas_attention as pa
 
-        # unreadable/absent table -> built-in r3b fallback, never a crash
+        # unreadable/absent table -> the built-in rows, never a crash
         decision = pa.policy_decision(512, path=tmp_path / "nope.json")
         assert (decision["fwd"], decision["bwd"]) == ("pallas", "kv")
         bad = tmp_path / "bad.json"

@@ -3,12 +3,8 @@ XLA references, in interpret mode on CPU — the same kernels Mosaic
 compiles on TPU (bench.py kernel-fused-w*). Covers values and grads for
 both kernels, the policy table (dispatch, nearest-shape lookup, the
 record round-trip that must preserve the attention table), and the
-model-level flag (identical param tree, matching outputs/grads).
-
-Gated on LAYER_PALLAS_OK, not PALLAS_API_OK: the layer kernels need
-only pltpu.*CompilerParams, not the newer jax.typeof family the
-attention kernel's tests require — so these run on strictly more jax
-versions than tests/test_pallas.py does.
+model-level flag (identical param tree, matching outputs/grads). The
+Mosaic side of the same kernels is in tests/test_tpu_lowering.py.
 """
 
 import json
@@ -19,7 +15,6 @@ import numpy as np
 import pytest
 
 from progen_tpu.ops.pallas_layers import (
-    LAYER_PALLAS_OK,
     fused_norm_shift,
     fused_sgu_mix_gate,
     layer_policy_decision,
@@ -29,12 +24,6 @@ from progen_tpu.ops.pallas_layers import (
     safe_layer_block,
     sgu_mix_gate,
     sgu_mix_gate_reference,
-)
-
-pytestmark = pytest.mark.skipif(
-    not LAYER_PALLAS_OK,
-    reason="installed jax lacks pltpu compiler-params API; models fall "
-    "back to the XLA references these tests compare against",
 )
 
 B, N, D = 2, 64, 32
@@ -216,15 +205,56 @@ class TestLayerPolicy:
             )
 
     def test_safe_layer_block_divides_and_caps(self):
-        assert safe_layer_block(256, 64, 32) == 64  # capped at n
-        assert safe_layer_block(48, 64, 32) == 32   # walks to a divisor
-        assert safe_layer_block(4, 64, 32) is None  # below sublane tile
+        pick = lambda *a: safe_layer_block("norm_shift", *a)
+        assert pick(256, 64, 32) == 64  # capped at n
+        assert pick(48, 64, 32) == 32   # walks to a divisor
+        assert pick(4, 64, 32) is None  # below sublane tile
+        # bf16 rows come in 16-row sublane tiles: a legal f32 tile of 8
+        # or 24 rows is not one
+        assert pick(24, 96, 32, jnp.bfloat16) == 16
+        assert pick(8, 64, 32, jnp.bfloat16) is None
+        with pytest.raises(ValueError):
+            safe_layer_block("attention", 256, 64, 32)
+
+    def test_sgu_tile_is_lane_aligned_or_whole(self):
+        # the SGU's row tile is the lane dim of its (bn, bn) weight
+        # tile: Mosaic wants a multiple of 128 there, or the whole axis
+        pick = lambda *a: safe_layer_block("sgu_mix", *a)
+        assert pick(256, 64, 32) == 64      # the whole sequence
+        assert pick(48, 64, 32) is None     # a 32-row weight tile: no
+        assert pick(200, 256, 32) == 128
+        assert pick(256, 1024, 1024) == 256
+
+    def test_safe_layer_block_fits_scoped_vmem_at_large_width(self):
+        # measured on a v5e chip (PR 21): at ProGen-large's gate width
+        # Mosaic refuses the 256-row tile ("Scoped allocation with size
+        # 17.82M and limit 16.00M") and compiles the 128-row one; tiny's
+        # width keeps 256
+        bf16 = jnp.bfloat16
+        assert safe_layer_block("sgu_mix", 256, 1024, 3584, bf16) == 128
+        assert safe_layer_block("sgu_mix", 256, 1024, 1024, bf16) == 256
+        assert safe_layer_block("norm_shift", 256, 1024, 1792, bf16) == 256
+
+    def test_no_legal_tile_is_an_error_not_a_fallback(self):
+        # the config asked for the kernel: it gets the kernel or an error
+        x = jnp.zeros((1, 12, 32), jnp.float32)
+        with pytest.raises(ValueError, match="no legal row tile"):
+            norm_shift(x, jnp.ones((32,)), EPS, "float32",
+                       block_override=4, interpret=True)
 
     def test_dispatch_override_matches_reference(self):
-        x, gate, w, bias, scale = _inputs(9)
+        # two 128-row tiles: the smallest multi-tile SGU shape Mosaic
+        # would accept, so the dispatcher's tile picker lets it through
+        n, d = 256, 32
+        kx, kg, kw = jax.random.split(jax.random.PRNGKey(9), 3)
+        x = jax.random.normal(kx, (B, n, d))
+        gate = jax.random.normal(kg, (B, n, d))
+        w = jax.random.normal(kw, (n, n)) / n
+        bias = jnp.ones((n, 1))
+        scale = jnp.full((d,), 1.1)
         out = sgu_mix_gate(
             x, gate, w, bias, scale, EPS, "float32",
-            block_override=16, interpret=True,
+            block_override=128, interpret=True,
         )
         ref = sgu_mix_gate_reference(
             x, gate, w, bias, scale, EPS, "float32"
@@ -241,9 +271,9 @@ class TestLayerPolicy:
 
 class TestModelFlag:
     CFG = dict(
-        num_tokens=32, dim=32, seq_len=32, depth=2, window_size=8,
+        num_tokens=32, dim=32, seq_len=256, depth=2, window_size=64,
         global_mlp_depth=1, heads=2, dim_head=16, ff_mult=2,
-        dtype="float32", pallas_layer_block=16,
+        dtype="float32", pallas_layer_block=128,  # two tiles a sequence
     )
 
     def _init_and_apply(self, fused):

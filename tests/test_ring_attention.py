@@ -6,7 +6,6 @@ import numpy as np
 import pytest
 
 from progen_tpu.ops.attention import local_attention
-from progen_tpu.ops.pallas_attention import PALLAS_API_OK
 from progen_tpu.parallel.partition import make_mesh
 from progen_tpu.parallel.ring_attention import ring_local_attention
 
@@ -87,13 +86,6 @@ class TestRingWithPallas:
     """use_pallas=True: each shard runs the halo-aware measured kernel
     (pallas_local_attention_halo) instead of the XLA dense path — the
     long-context multi-chip composition of the two flagship features."""
-
-    pytestmark = pytest.mark.skipif(
-        not PALLAS_API_OK,
-        reason="installed jax predates the Pallas kernel API family; "
-        "use_pallas falls back to the XLA halo path, so the kernel "
-        "this class targets never runs",
-    )
 
     def _policy(self, monkeypatch, tmp_path, fwd="pallas", bwd="kv"):
         import json

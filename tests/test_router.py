@@ -133,6 +133,45 @@ def telemetry_records():
     spans.configure()
 
 
+class TestSpawnedReplicaChips:
+    """--spawn on a chip host: one chip per replica process, decided
+    from the environment and device files — never by asking jax."""
+
+    def test_no_chips_inherits_parent_env(self, monkeypatch):
+        from progen_tpu.cli import router as cli_router
+
+        monkeypatch.delenv("TPU_VISIBLE_CHIPS", raising=False)
+        monkeypatch.setattr("glob.glob", lambda pat: [])
+        assert cli_router._host_chips() == []
+        assert cli_router._replica_env(3, []) is None
+
+    def test_device_files_are_counted(self, monkeypatch):
+        from progen_tpu.cli import router as cli_router
+
+        monkeypatch.delenv("TPU_VISIBLE_CHIPS", raising=False)
+        files = {
+            "/dev/accel[0-9]*": [],
+            "/dev/vfio/*": ["/dev/vfio/0", "/dev/vfio/1", "/dev/vfio/2",
+                            "/dev/vfio/3", "/dev/vfio/vfio"],
+        }
+        monkeypatch.setattr("glob.glob", lambda pat: files[pat])
+        assert cli_router._host_chips() == ["0", "1", "2", "3"]
+
+    def test_each_replica_gets_its_own_chip(self, monkeypatch):
+        import click
+
+        from progen_tpu.cli import router as cli_router
+
+        monkeypatch.setenv("TPU_VISIBLE_CHIPS", "2,3")  # a pinned subset
+        chips = cli_router._host_chips()
+        assert chips == ["2", "3"]
+        envs = [cli_router._replica_env(i, chips) for i in range(2)]
+        assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["2", "3"]
+        assert all(e["TPU_PROCESS_BOUNDS"] == "1,1,1" for e in envs)
+        with pytest.raises(click.ClickException, match="one process per"):
+            cli_router._replica_env(2, chips)
+
+
 class TestSpecParsing:
     def test_bare_path(self):
         s = parse_replica_spec("/tmp/r0.sock")

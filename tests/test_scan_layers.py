@@ -98,9 +98,7 @@ class TestScanLayers:
             float(m_scan["loss"]), float(m_unrolled["loss"]), rtol=1e-6
         )
         got = unstack_params(s_scan.params, TINY_SCAN)
-        # pre-0.7 runtimes lower the layer scan with a slightly different
-        # reduction order (worst element ~2.4e-6); target runtimes hold 1e-6
-        atol = 1e-6 if hasattr(jax.lax, "pcast") else 5e-6
+        atol = 1e-6
         for (ka, a), (kb, b) in zip(
             jax.tree_util.tree_flatten_with_path(s_unrolled.params)[0],
             jax.tree_util.tree_flatten_with_path(got)[0],

@@ -35,16 +35,6 @@ TINY = ProGenConfig(
     dtype="float32",
 )
 
-# the bit-parity assertions below hold on the jax>=0.7 runtimes this repo
-# targets; the older GSPMD partitioner reassociates reductions differently
-# on the host-platform CPU mesh (0.6% loss drift — far past any honest
-# tolerance), so the parity claim is unverifiable there, not merely loose
-_gspmd_parity_skip = pytest.mark.skipif(
-    not hasattr(jax.lax, "pcast"),
-    reason="pre-0.7 GSPMD on the virtual-CPU mesh diverges numerically "
-    "from the single-device step; parity is asserted on target runtimes",
-)
-
 
 def synthetic_batch(key, shape, vocab=32):
     """Token sequences with trailing padding, so the EOS mask matters."""
@@ -160,8 +150,6 @@ class TestTrainStep:
 
 
 class TestPjitParity:
-    pytestmark = _gspmd_parity_skip
-
     def test_seq_parallel_step_matches_single_device(self):
         """Sequence parallelism = mesh seq axis: shard activations' sequence
         dim + SGU spatial rows over 4 devices; results must equal the
@@ -232,8 +220,6 @@ class TestPjitParity:
 
 
 class TestBlockedSguParity:
-    pytestmark = _gspmd_parity_skip
-
     def test_blocked_sgu_seq_parallel_matches_single_device(self):
         """The long8k recipe combination — block-triangular SGU mix on a
         sequence-parallel mesh — must reproduce the single-device dense-SGU
