@@ -274,7 +274,7 @@ def _cache_entries(cache_dir: str) -> int:
 
 
 def main() -> int:
-    load_env_file()  # the children's LIBTPU_INIT_ARGS and compile-cache dir
+    load_env_file(compile_counters=False)  # the children's LIBTPU_INIT_ARGS and compile-cache dir; no jax here
     cache_dir = os.environ["JAX_COMPILATION_CACHE_DIR"]
     cache_before = _cache_entries(cache_dir)
     t_start = time.monotonic()

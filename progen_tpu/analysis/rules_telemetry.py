@@ -22,9 +22,10 @@ once; both rules and the generated README reference section follow.
 Beyond the registry, three bespoke checks survive here because they
 are not per-``ev`` grammars:
 
-  * ``span(...)`` / ``.span(...)`` names must be string literals (a
-    bare name is allowed only when the enclosing function forwards its
-    own parameter — the wrapper pattern ``spans.span`` itself uses);
+  * ``span(...)`` / ``.span(...)`` and ``stage(...)`` / ``.stage(...)``
+    names must be string literals (a bare name is allowed only when
+    the enclosing function forwards its own parameter — the wrapper
+    pattern ``spans.span`` itself uses);
   * string-literal metric names fed to the registry (``.inc``,
     ``.set_gauge``, ``.observe``, ``.set_gauges`` keys) must satisfy
     the Prometheus name rules the renderer enforces
@@ -75,8 +76,8 @@ class TelemetryHygieneRule(Rule):
         self.generic_visit(node)
         cname = call_name(node)
         tail = cname.rsplit(".", 1)[-1] if cname else ""
-        if tail == "span" and node.args:
-            self._check_span_name(node)
+        if tail in ("span", "stage") and node.args:
+            self._check_span_name(node, tail)
         if tail in ("emit", "log_event"):
             for arg in node.args:
                 if isinstance(arg, ast.Dict):
@@ -168,7 +169,7 @@ class TelemetryHygieneRule(Rule):
 
     # ----- bespoke checks (not per-ev grammars) ---------------------------
 
-    def _check_span_name(self, node: ast.Call) -> None:
+    def _check_span_name(self, node: ast.Call, what: str) -> None:
         name_arg = node.args[0]
         if _str_const(name_arg):
             return
@@ -181,9 +182,10 @@ class TelemetryHygieneRule(Rule):
         )
         self.report(
             name_arg,
-            f"span name is {kind} — span names must be string literals "
-            f"so the trace/summarize tooling groups on a bounded, "
-            f"greppable set; put varying data in span attrs instead",
+            f"{what} name is {kind} — {what} names must be string "
+            f"literals so the trace/summarize tooling groups on a "
+            f"bounded, greppable set; put varying data in span attrs "
+            f"instead",
         )
 
     def _check_prom_name(self, node, name: str) -> None:

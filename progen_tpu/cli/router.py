@@ -56,7 +56,7 @@ from __future__ import annotations
 
 from progen_tpu.utils.env import load_env_file
 
-load_env_file()  # env flags before any heavy import (ref serve.py)
+load_env_file(compile_counters=False)  # env flags only: this tool stays jax-free
 
 import json
 import os

@@ -50,7 +50,7 @@ from progen_tpu.sampling import (
     _validate_knobs,
     gumbel_step_dynamic,
 )
-from progen_tpu.telemetry.spans import span as _span
+from progen_tpu.telemetry.spans import span as _span, stage as _stage
 
 logger = logging.getLogger(__name__)
 
@@ -637,21 +637,22 @@ class ServeEngine:
         monolithic and chunked admission paths — both must build
         byte-identical operands or the bit-parity contract between them
         is fiction. Returns (row, start, key, parity, trow, frow)."""
-        self.validate(prime, length, add_bos=add_bos,
-                      temperature=temperature, top_p=top_p, top_k=top_k,
-                      template=template, frozen=frozen)
-        seq, start = _prepare_seq(self.model, prime, length, add_bos)
-        row = np.zeros((self.max_len,), np.int32)
-        row[: int(seq.shape[0])] = np.asarray(seq)
-        trow = np.zeros((self.max_len,), np.int32)
-        frow = np.zeros((self.max_len,), bool)
-        if template is not None:
-            trow[:length] = np.asarray(template, np.int32).reshape(-1)
-            frow[:length] = np.asarray(frozen, bool).reshape(-1)
-        if key is None:
-            key = jax.random.PRNGKey(seed)
-        parity = temperature == 1.0 and top_p is None
-        return row, int(start), key, parity, trow, frow
+        with _stage("serve/prepare"):
+            self.validate(prime, length, add_bos=add_bos,
+                          temperature=temperature, top_p=top_p, top_k=top_k,
+                          template=template, frozen=frozen)
+            seq, start = _prepare_seq(self.model, prime, length, add_bos)
+            row = np.zeros((self.max_len,), np.int32)
+            row[: int(seq.shape[0])] = np.asarray(seq)
+            trow = np.zeros((self.max_len,), np.int32)
+            frow = np.zeros((self.max_len,), bool)
+            if template is not None:
+                trow[:length] = np.asarray(template, np.int32).reshape(-1)
+                frow[:length] = np.asarray(frozen, bool).reshape(-1)
+            if key is None:
+                key = jax.random.PRNGKey(seed)
+            parity = temperature == 1.0 and top_p is None
+            return row, int(start), key, parity, trow, frow
 
     def prefill(self, slot: int, prime, length: int, *,
                 top_k=25, add_bos: bool = False, temperature: float = 1.0,
@@ -755,38 +756,41 @@ class ServeEngine:
                    request_id=pending.request_id,
                    lo=int(pending.pos), hi=int(hi)):
             if hi > pending.pos:
-                if self.quantize_int8:
-                    pending.cache = _prefill_chunk_q(
-                        self.model, self._q_params, self._q_scales,
-                        pending.cache, pending.row,
-                        jnp.int32(pending.pos), jnp.int32(hi),
-                    )
-                else:
-                    pending.cache = _prefill_chunk(
-                        self.model, self.params, pending.cache,
-                        pending.row, jnp.int32(pending.pos),
-                        jnp.int32(hi),
-                    )
+                with _stage("serve/prefill_dispatch"):
+                    if self.quantize_int8:
+                        pending.cache = _prefill_chunk_q(
+                            self.model, self._q_params, self._q_scales,
+                            pending.cache, pending.row,
+                            jnp.int32(pending.pos), jnp.int32(hi),
+                        )
+                    else:
+                        pending.cache = _prefill_chunk(
+                            self.model, self.params, pending.cache,
+                            pending.row, jnp.int32(pending.pos),
+                            jnp.int32(hi),
+                        )
                 pending.pos = int(hi)
                 if self._prefix_cache is not None:
-                    self._prefix_cache.insert(
-                        np.asarray(pending.row), pending.pos,
-                        pending.cache,
-                    )
+                    with _stage("serve/prefix_insert"):
+                        self._prefix_cache.insert(
+                            np.asarray(pending.row), pending.pos,
+                            pending.cache,
+                        )
             if pending.pos >= feed_len:
-                tail = (
-                    jnp.int32(pending.slot), pending.row,
-                    jnp.int32(pending.start), jnp.int32(pending.length),
-                    pending.key,
-                    jnp.float32(pending.temperature),
-                    jnp.float32(pending.top_p_val),
-                    jnp.int32(pending.top_k_val),
-                    jnp.asarray(pending.parity),
-                    pending.trow, pending.frow,
-                )
-                self.slots = _prefill_finish(
-                    self.slots, pending.cache, *tail
-                )
+                with _stage("serve/prefill_finish"):
+                    tail = (
+                        jnp.int32(pending.slot), pending.row,
+                        jnp.int32(pending.start), jnp.int32(pending.length),
+                        pending.key,
+                        jnp.float32(pending.temperature),
+                        jnp.float32(pending.top_p_val),
+                        jnp.int32(pending.top_k_val),
+                        jnp.asarray(pending.parity),
+                        pending.trow, pending.frow,
+                    )
+                    self.slots = _prefill_finish(
+                        self.slots, pending.cache, *tail
+                    )
                 self._targets[pending.slot] = int(pending.length)
                 pending.done = True
         return pending.done
@@ -797,19 +801,21 @@ class ServeEngine:
         """One token for every live slot. Returns host arrays
         (sampled, was_live, finished), each (max_slots,) — ``sampled[i]``
         is meaningful only where ``was_live[i]``."""
-        if self.quantize_int8:
-            self.slots, sampled, was_live, finished = _decode_step_q(
-                self.model, self._q_params, self._q_scales, self.slots
+        with _stage("serve/decode_dispatch"):
+            if self.quantize_int8:
+                self.slots, sampled, was_live, finished = _decode_step_q(
+                    self.model, self._q_params, self._q_scales, self.slots
+                )
+            else:
+                self.slots, sampled, was_live, finished = _decode_step(
+                    self.model, self.params, self.slots
+                )
+        with _stage("serve/decode_fetch"):
+            return (
+                np.asarray(sampled),
+                np.asarray(was_live),
+                np.asarray(finished),
             )
-        else:
-            self.slots, sampled, was_live, finished = _decode_step(
-                self.model, self.params, self.slots
-            )
-        return (
-            np.asarray(sampled),
-            np.asarray(was_live),
-            np.asarray(finished),
-        )
 
     def collect(self, slot: int) -> np.ndarray:
         """The finished request's (target,) token buffer with the

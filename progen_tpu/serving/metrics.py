@@ -11,6 +11,12 @@ token counts and wall-clock time around the prefill/decode calls, and
 ``snapshot()`` divides. That makes decode_tokens_per_s a true
 steady-state number (tokens that actually advanced / time the device
 actually spent), not a gauge that depends on when you look.
+``prefill_time_s`` is different: under chunked admission it is the HOST
+time inside ``engine.advance_prefill`` — the chunk is dispatched and the
+call returns before the device has run it — so it, and
+``prefill_tokens_per_s`` with it, says what admission costs the serving
+loop's thread, not how fast the device prefills (measured on a v5e,
+PERF.md: ≈ 0.5 ms a token of host time against 4.3 ms on the device).
 
 Latency lands in three reservoir-quantile families the scheduler
 observes: ``ttft_s`` (submit → first token), ``itl_s`` (inter-token
@@ -29,6 +35,23 @@ from progen_tpu.telemetry.registry import (  # noqa: F401 — re-exported
     _RESERVOIR_CAP,
     _Timing,
 )
+
+
+# `# HELP` text for the names whose meaning the name does not carry
+HELP = {
+    "prefill_time_s": (
+        "Host seconds inside engine.prefill / advance_prefill: dispatch "
+        "of the prefill programs, not the device's prefill work"
+    ),
+    "prefill_tokens_per_s": (
+        "prefill_tokens over prefill_time_s: prime tokens per second of "
+        "HOST time in admission, not the device's prefill rate"
+    ),
+    "xla_compile_count": (
+        "XLA compile-or-load events of the whole process (jax.monitoring), "
+        "those the decode/prefill jit-cache counts miss among them"
+    ),
+}
 
 
 class ServingMetrics:
@@ -109,6 +132,7 @@ class ServingMetrics:
             },
             "gauges": dict(self.gauges),
             "derived": derived,
+            "help": HELP,
             "timings": {
                 name: {
                     "sum": t.sum,

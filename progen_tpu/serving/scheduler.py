@@ -50,7 +50,8 @@ import numpy as np
 from progen_tpu.resilience.chaos import maybe_inject
 from progen_tpu.serving.engine import ServeEngine
 from progen_tpu.serving.metrics import ServingMetrics
-from progen_tpu.telemetry.spans import get_telemetry
+from progen_tpu.telemetry import compiles
+from progen_tpu.telemetry.spans import get_telemetry, stage
 
 REJECT_QUEUE_FULL = "queue_full"
 REJECT_DEADLINE = "deadline_exceeded"
@@ -209,6 +210,13 @@ class Scheduler:
         self.metrics.set_gauge(
             "prefill_compile_count", self.engine.prefill_compile_count()
         )
+        # every XLA compile-or-load of the process, the ones the two
+        # jit-cache counts above miss among them (an eager pad at a new
+        # prompt shape, the embed twin); counted from load_env_file() on
+        if compiles.installed():
+            self.metrics.set_gauge(
+                "xla_compile_count", compiles.backend_compiles()
+            )
 
     def _publish_prefix_gauges(self) -> None:
         """Prefix-cache health on the metrics registry (the raw
@@ -234,6 +242,9 @@ class Scheduler:
         ``trace`` is the cross-process trace context — stamped as
         ``trace_id`` (the exact spelling PGL006 enforces) so the stitch
         journey renderer can reattach this track to its router hop."""
+        tel = get_telemetry()
+        if not tel.recording:
+            return
         rec = {
             "ev": "req", "ph": ph, "name": name, "req": rid,
             "ts": time.time() if ts is None else ts,
@@ -242,7 +253,7 @@ class Scheduler:
             rec["trace_id"] = trace
         if attrs:
             rec.update(attrs)
-        get_telemetry().emit(rec)
+        tel.emit(rec)
 
     def _emit_slots(self) -> None:
         """Slot-occupancy counter sample, on change only. Counts
@@ -259,10 +270,12 @@ class Scheduler:
         self.metrics.set_gauge(
             "slots_free", self.engine.max_slots - n
         )
-        get_telemetry().emit({
-            "ev": "slots", "ts": time.time(), "in_use": n,
-            "free": self.engine.max_slots - n,
-        })
+        tel = get_telemetry()
+        if tel.recording:
+            tel.emit({
+                "ev": "slots", "ts": time.time(), "in_use": n,
+                "free": self.engine.max_slots - n,
+            })
 
     def _reject_traced(self, rid: str, reason: str) -> None:
         """Submit-time rejects never open an async track (nothing was
@@ -325,54 +338,55 @@ class Scheduler:
         """(accepted, reason). ``reason`` is None on accept,
         ``"queue_full"`` under backpressure, or ``"invalid: ..."`` when
         the engine can never serve the request."""
-        self.metrics.inc("requests_submitted")
-        try:
-            if req.kind == "embed":
-                # embeds run one full forward, no decode slot: the only
-                # bound is the model's context window
-                n = len(np.asarray(req.prime).reshape(-1))
-                n += 1 if req.add_bos else 0
-                if not 1 <= n <= self.engine.model.config.seq_len:
-                    raise ValueError(
-                        f"embed prime must be 1..seq_len="
-                        f"{self.engine.model.config.seq_len} tokens, got {n}"
+        with stage("serve/submit"):
+            self.metrics.inc("requests_submitted")
+            try:
+                if req.kind == "embed":
+                    # embeds run one full forward, no decode slot: the only
+                    # bound is the model's context window
+                    n = len(np.asarray(req.prime).reshape(-1))
+                    n += 1 if req.add_bos else 0
+                    if not 1 <= n <= self.engine.model.config.seq_len:
+                        raise ValueError(
+                            f"embed prime must be 1..seq_len="
+                            f"{self.engine.model.config.seq_len} tokens, got {n}"
+                        )
+                elif req.kind == "generate":
+                    self.engine.validate(
+                        req.prime, req.length, add_bos=req.add_bos,
+                        temperature=req.temperature, top_p=req.top_p,
+                        top_k=req.top_k, template=req.template,
+                        frozen=req.frozen,
                     )
-            elif req.kind == "generate":
-                self.engine.validate(
-                    req.prime, req.length, add_bos=req.add_bos,
-                    temperature=req.temperature, top_p=req.top_p,
-                    top_k=req.top_k, template=req.template,
-                    frozen=req.frozen,
-                )
-            else:
-                raise ValueError(f"unknown request kind {req.kind!r}")
-        except ValueError as e:
-            self.metrics.inc("requests_rejected")
-            self.metrics.inc("rejected_invalid")
-            self._reject_traced(req.id, "invalid")
-            return False, f"invalid: {e}"
-        if req.deadline_s is not None and req.deadline_s <= 0:
-            self.metrics.inc("requests_rejected")
-            self.metrics.inc("rejected_invalid")
-            self._reject_traced(req.id, "invalid")
-            return False, f"invalid: deadline_s must be > 0, got {req.deadline_s}"
-        if len(self._queue) >= self.max_queue:
-            self.metrics.inc("requests_rejected")
-            self.metrics.inc("rejected_queue_full")
-            self._reject_traced(req.id, REJECT_QUEUE_FULL)
-            return False, REJECT_QUEUE_FULL
-        self._queue.append((req, self._clock()))
-        self.metrics.set_gauge("queue_depth", len(self._queue))
-        now = time.time()
-        self._req_event("b", req.id, "request", ts=now,
-                        trace=req.trace_id, length=int(req.length))
-        self._req_event("b", req.id, "queued", ts=now,
-                        trace=req.trace_id)
-        if self.journal is not None:
-            # durable before acknowledged: once the caller sees True,
-            # the request survives any kill via --replay
-            self.journal.accept(req)
-        return True, None
+                else:
+                    raise ValueError(f"unknown request kind {req.kind!r}")
+            except ValueError as e:
+                self.metrics.inc("requests_rejected")
+                self.metrics.inc("rejected_invalid")
+                self._reject_traced(req.id, "invalid")
+                return False, f"invalid: {e}"
+            if req.deadline_s is not None and req.deadline_s <= 0:
+                self.metrics.inc("requests_rejected")
+                self.metrics.inc("rejected_invalid")
+                self._reject_traced(req.id, "invalid")
+                return False, f"invalid: deadline_s must be > 0, got {req.deadline_s}"
+            if len(self._queue) >= self.max_queue:
+                self.metrics.inc("requests_rejected")
+                self.metrics.inc("rejected_queue_full")
+                self._reject_traced(req.id, REJECT_QUEUE_FULL)
+                return False, REJECT_QUEUE_FULL
+            self._queue.append((req, self._clock()))
+            self.metrics.set_gauge("queue_depth", len(self._queue))
+            now = time.time()
+            self._req_event("b", req.id, "request", ts=now,
+                            trace=req.trace_id, length=int(req.length))
+            self._req_event("b", req.id, "queued", ts=now,
+                            trace=req.trace_id)
+            if self.journal is not None:
+                # durable before acknowledged: once the caller sees True,
+                # the request survives any kill via --replay
+                self.journal.accept(req)
+            return True, None
 
     # ----- the loop -------------------------------------------------------
 
@@ -510,54 +524,55 @@ class Scheduler:
         admission is in flight (FIFO: later arrivals queue behind the
         head); on the legacy inline path this loop runs whole prefills
         until the pool or the queue is empty, exactly as before."""
-        while self._pending is None and self._queue:
-            if self._queue[0][0].kind == "embed":
+        with stage("serve/admit"):
+            while self._pending is None and self._queue:
+                if self._queue[0][0].kind == "embed":
+                    req, t_submit = self._queue.popleft()
+                    self._serve_embed(req, t_submit)
+                    continue
+                slot = self.engine.acquire()
+                if slot is None:
+                    break
                 req, t_submit = self._queue.popleft()
-                self._serve_embed(req, t_submit)
-                continue
-            slot = self.engine.acquire()
-            if slot is None:
-                break
-            req, t_submit = self._queue.popleft()
-            w0 = time.time()
-            self._req_event("e", req.id, "queued", ts=w0,
-                            trace=req.trace_id)
-            self._req_event("b", req.id, "prefill", ts=w0,
-                            trace=req.trace_id, slot=slot)
-            if self._use_chunked:
-                # no device work yet: the prime is fed chunk-at-a-time
-                # by _pump_admissions between decode steps
-                pp = self.engine.begin_prefill(
+                w0 = time.time()
+                self._req_event("e", req.id, "queued", ts=w0,
+                                trace=req.trace_id)
+                self._req_event("b", req.id, "prefill", ts=w0,
+                                trace=req.trace_id, slot=slot)
+                if self._use_chunked:
+                    # no device work yet: the prime is fed chunk-at-a-time
+                    # by _pump_admissions between decode steps
+                    pp = self.engine.begin_prefill(
+                        slot, req.prime, req.length, top_k=req.top_k,
+                        add_bos=req.add_bos, temperature=req.temperature,
+                        top_p=req.top_p, key=req.key, seed=req.seed,
+                        request_id=req.id, template=req.template,
+                        frozen=req.frozen,
+                    )
+                    self._pending = _PendingAdmission(req, pp, t_submit)
+                    continue  # loop condition ends admission for this step
+                t0 = self._clock()
+                start = self.engine.prefill(
                     slot, req.prime, req.length, top_k=req.top_k,
                     add_bos=req.add_bos, temperature=req.temperature,
                     top_p=req.top_p, key=req.key, seed=req.seed,
                     request_id=req.id, template=req.template,
                     frozen=req.frozen,
                 )
-                self._pending = _PendingAdmission(req, pp, t_submit)
-                continue  # loop condition ends admission for this step
-            t0 = self._clock()
-            start = self.engine.prefill(
-                slot, req.prime, req.length, top_k=req.top_k,
-                add_bos=req.add_bos, temperature=req.temperature,
-                top_p=req.top_p, key=req.key, seed=req.seed,
-                request_id=req.id, template=req.template,
-                frozen=req.frozen,
-            )
-            t1 = self._clock()
-            w1 = time.time()
-            self._req_event("e", req.id, "prefill", ts=w1,
-                            trace=req.trace_id)
-            self._req_event("b", req.id, "decode", ts=w1,
-                            trace=req.trace_id, slot=slot)
-            self._active[slot] = _Active(req, slot, start, t_submit, t1)
-            self.metrics.inc("requests_admitted")
-            # start-1 prime tokens actually ran through the model
-            self.metrics.inc("prefill_tokens", max(start - 1, 0))
-            self.metrics.add_time("prefill_time_s", t1 - t0)
-        self.metrics.set_gauge("queue_depth", len(self._queue))
-        self.metrics.set_gauge("active_slots", len(self._active))
-        self._emit_slots()
+                t1 = self._clock()
+                w1 = time.time()
+                self._req_event("e", req.id, "prefill", ts=w1,
+                                trace=req.trace_id)
+                self._req_event("b", req.id, "decode", ts=w1,
+                                trace=req.trace_id, slot=slot)
+                self._active[slot] = _Active(req, slot, start, t_submit, t1)
+                self.metrics.inc("requests_admitted")
+                # start-1 prime tokens actually ran through the model
+                self.metrics.inc("prefill_tokens", max(start - 1, 0))
+                self.metrics.add_time("prefill_time_s", t1 - t0)
+            self.metrics.set_gauge("queue_depth", len(self._queue))
+            self.metrics.set_gauge("active_slots", len(self._active))
+            self._emit_slots()
 
     def _activate(self, pa: _PendingAdmission) -> None:
         """A pending prefill finished its last chunk: the slot is live
@@ -620,70 +635,74 @@ class Scheduler:
         slot order, stable) and any requests that finished. Expired
         queued requests are shed first (check ``pop_expired()``) so a
         dead deadline never consumes a freed slot."""
-        self._expire_queued(self._clock())
-        self._pump_admissions()
-        embed_done, self._embed_done = self._embed_done, []
-        if not self._active:
-            return [], embed_done
-        # chaos site (PROGEN_CHAOS="serve/decode:kill@N"): decode has no
-        # span of its own (per-token span records would swamp the
-        # trace), so the injector is called directly, like the
-        # retry-site labels in resilience/retry.py
-        maybe_inject("serve/decode")
-        t0 = self._clock()
-        sampled, was_live, finished = self.engine.decode_step()
-        t1 = self._clock()
-        now = t1
-        events: List[TokenEvent] = []
-        completions: List[Completion] = []
-        n_live = 0
-        for slot in sorted(self._active):
-            rec = self._active[slot]
-            if not was_live[slot]:
-                continue
-            n_live += 1
-            rec.n_generated += 1
-            if rec.first_token_t is None:
-                rec.first_token_t = now
-                self.metrics.observe("ttft_s", now - rec.t_submit,
-                                     trace_id=rec.req.trace_id)
-                self._req_event("n", rec.req.id, "first_token",
-                                trace=rec.req.trace_id)
-            else:
-                # inter-token latency: gap between consecutive tokens
-                # of THIS request (== decode-step period while the slot
-                # stays live, but attributed per request)
-                self.metrics.observe("itl_s", now - rec.last_token_t)
-            rec.last_token_t = now
-            done = bool(finished[slot])
-            events.append(
-                TokenEvent(
-                    rec.req.id,
-                    int(sampled[slot]),
-                    rec.start + rec.n_generated - 1,
-                    done,
-                )
-            )
-            if done:
-                completions.append(self._finish(slot, rec, now))
-        if self.journal is not None:
-            # watermarks are journaled BEFORE step() returns — a token a
-            # client ever saw is always in the journal, so replay can
-            # never emit a (request, index) twice
-            for ev in events:
-                self.journal.token(ev.request_id, ev.index, ev.token)
-            for c in completions:
-                self.journal.done(c.request_id, "completed",
-                                  c.n_generated)
-        self.metrics.inc("decode_steps")
-        self.metrics.inc("decode_tokens", n_live)
-        self.metrics.add_time("decode_time_s", t1 - t0)
-        self.metrics.set_gauge("active_slots", len(self._active))
-        # recompiles surface the step they happen, not at the next
-        # --metrics-every publish — a recompile storm is exactly when
-        # the console needs to see the count move
-        self._publish_compile_gauges()
-        return events, embed_done + completions
+        with stage("serve/step"):
+            self._expire_queued(self._clock())
+            self._pump_admissions()
+            embed_done, self._embed_done = self._embed_done, []
+            if not self._active:
+                return [], embed_done
+            # chaos site (PROGEN_CHAOS="serve/decode:kill@N"): decode is
+            # timed by stages (serve/decode*, in memory and in a profiler
+            # trace) but still writes no B/E records — a span per token
+            # would swamp events.jsonl — and only a span's entry fires the
+            # injector, so it is called directly, like the retry-site labels
+            # in resilience/retry.py
+            maybe_inject("serve/decode")
+            with stage("serve/decode") as decode:
+                sampled, was_live, finished = self.engine.decode_step()
+            now = self._clock()
+            events: List[TokenEvent] = []
+            completions: List[Completion] = []
+            n_live = 0
+            with stage("serve/emit"):
+                for slot in sorted(self._active):
+                    rec = self._active[slot]
+                    if not was_live[slot]:
+                        continue
+                    n_live += 1
+                    rec.n_generated += 1
+                    if rec.first_token_t is None:
+                        rec.first_token_t = now
+                        self.metrics.observe("ttft_s", now - rec.t_submit,
+                                             trace_id=rec.req.trace_id)
+                        self._req_event("n", rec.req.id, "first_token",
+                                        trace=rec.req.trace_id)
+                    else:
+                        # inter-token latency: gap between consecutive tokens
+                        # of THIS request (== decode-step period while the slot
+                        # stays live, but attributed per request)
+                        self.metrics.observe("itl_s", now - rec.last_token_t)
+                    rec.last_token_t = now
+                    done = bool(finished[slot])
+                    events.append(
+                        TokenEvent(
+                            rec.req.id,
+                            int(sampled[slot]),
+                            rec.start + rec.n_generated - 1,
+                            done,
+                        )
+                    )
+                    if done:
+                        completions.append(self._finish(slot, rec, now))
+            if self.journal is not None:
+                with stage("serve/journal"):
+                    # watermarks are journaled BEFORE step() returns — a token a
+                    # client ever saw is always in the journal, so replay can
+                    # never emit a (request, index) twice
+                    for ev in events:
+                        self.journal.token(ev.request_id, ev.index, ev.token)
+                    for c in completions:
+                        self.journal.done(c.request_id, "completed",
+                                          c.n_generated)
+            self.metrics.inc("decode_steps")
+            self.metrics.inc("decode_tokens", n_live)
+            self.metrics.add_time("decode_time_s", decode.dur)
+            self.metrics.set_gauge("active_slots", len(self._active))
+            # recompiles surface the step they happen, not at the next
+            # --metrics-every publish — a recompile storm is exactly when
+            # the console needs to see the count move
+            self._publish_compile_gauges()
+            return events, embed_done + completions
 
     def _finish(self, slot: int, rec: _Active, now: float) -> Completion:
         tokens = self.engine.collect(slot)

@@ -73,15 +73,21 @@ def prometheus_text(metrics, prefix: str = "progen_serve_") -> str:
     already-structured dict to Prometheus exposition text."""
     s = metrics.structured() if hasattr(metrics, "structured") else metrics
     lines = []
+    helps = s.get("help", {})  # raw name -> one line of `# HELP` text
+
+    def family(raw: str, n: str, kind: str) -> list:
+        head = [f"# HELP {n} {helps[raw]}"] if raw in helps else []
+        return head + [f"# TYPE {n} {kind}"]
+
     for raw, v in sorted(s.get("counters", {}).items()):
         n = _name(prefix, raw + "_total")
-        lines += [f"# TYPE {n} counter", f"{n} {_fmt(v)}"]
+        lines += family(raw, n, "counter") + [f"{n} {_fmt(v)}"]
     gauges = dict(s.get("gauges", {}))
     # derived throughputs are gauges too (true rates, not sampled)
     gauges.update(s.get("derived", {}))
     for raw, v in sorted(gauges.items()):
         n = _name(prefix, raw)
-        lines += [f"# TYPE {n} gauge", f"{n} {_fmt(v)}"]
+        lines += family(raw, n, "gauge") + [f"{n} {_fmt(v)}"]
     for raw, t in sorted(s.get("timings", {}).items()):
         base = raw[: -len("_s")] if raw.endswith("_s") else raw
         n = _name(prefix, base + "_seconds")

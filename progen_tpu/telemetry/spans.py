@@ -17,6 +17,17 @@ global ``Telemetry`` so deep callees (checkpoint.py, bench phases) can
 open spans without threading a handle through every signature. With no
 sink configured spans still maintain the in-memory recent/open ring
 (what the stall watchdog reports) at ~zero cost.
+
+``stage(name)`` is the span's hot-path sibling: no record, no sink, no
+hook, no ``named_scope`` — two clock reads, a
+``jax.profiler.TraceAnnotation`` (so the stage shows on the device's
+clock in any profiler trace, and costs nothing while no trace is being
+taken) and one tuple appended to an in-memory ring that a benchmark or
+a debugger reads afterwards (``Telemetry.stages``). Spans land in the
+same ring and the same trace, so a stage knows the span that caused it.
+Use ``span`` where a crash must leave a ``B`` without its ``E`` or a
+chaos rule must be able to fire; use ``stage`` wherever the call runs
+once per token or per batch.
 """
 
 from __future__ import annotations
@@ -29,7 +40,7 @@ import threading
 import time
 from collections import deque
 from pathlib import Path
-from typing import Callable, Optional
+from typing import Callable, List, Optional, Tuple
 
 _HOST_INDEX: Optional[int] = None
 
@@ -124,6 +135,64 @@ def _named_scope(name: str):
         return contextlib.nullcontext()
 
 
+# bound once: the stage's budget is under two microseconds
+_perf_counter = time.perf_counter
+_get_ident = threading.get_ident
+
+
+# one completed stage or span in the ring:
+# (seq, parent_seq, name, t0, dur, thread_id)
+StageRecord = Tuple[int, Optional[int], str, float, float, int]
+
+# completed stages and spans the ring holds, oldest dropped first: a
+# 40 s serving window is ~1,400 steps of ~10 stages
+MAX_STAGES = 65536
+
+
+class _Stage:
+    """The context manager ``Telemetry.stage`` returns. A class with
+    slots, not a generator: the whole of enter + exit is budgeted at
+    under two microseconds. ``dur`` is the stage's seconds once it has
+    exited, for a caller that keeps a time ledger of its own."""
+
+    __slots__ = ("_tel", "name", "dur", "_seq", "_parent", "_ann", "_t0")
+
+    def __init__(self, tel: "Telemetry", name: str):
+        self._tel = tel
+        self.name = name
+
+    def __enter__(self) -> "_Stage":
+        tel = self._tel
+        try:
+            stack = tel._nesting.open
+        except AttributeError:
+            stack = tel._nesting.open = []
+        self._parent = stack[-1] if stack else None
+        self._seq = seq = next(tel._seq)
+        stack.append(seq)
+        # never import jax from here (see ``live_jax``); where it is
+        # already imported the annotation needs no backend
+        jax = sys.modules.get("jax")
+        if jax is None:
+            self._ann = None
+        else:
+            self._ann = ann = jax.profiler.TraceAnnotation(self.name)
+            ann.__enter__()
+        self._t0 = _perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        t0 = self._t0
+        self.dur = dur = _perf_counter() - t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        tel = self._tel
+        tel._nesting.open.pop()
+        tel._stages.append((self._seq, self._parent, self.name, t0, dur,
+                            _get_ident()))
+        return False
+
+
 class Telemetry:
     """Span emitter + in-memory recent/open span state.
 
@@ -138,9 +207,18 @@ class Telemetry:
         self._lock = threading.Lock()
         self._open: dict[int, dict] = {}
         self._recent: deque = deque(maxlen=max_recent)
+        self._stages: deque = deque(maxlen=MAX_STAGES)
+        self._nesting = threading.local()  # .open: seqs, innermost last
 
     def set_sink(self, sink: Optional[Callable[[dict], None]]) -> None:
         self._sink = sink
+
+    @property
+    def recording(self) -> bool:
+        """True when a record handed to ``emit`` would reach anyone: a
+        sink or an emit tap. Hot paths check it before BUILDING a
+        record."""
+        return self._sink is not None or bool(EMIT_TAPS)
 
     def emit(self, record: dict) -> None:
         # every record carries its host: under multi-process training the
@@ -163,36 +241,62 @@ class Telemetry:
 
     @contextlib.contextmanager
     def span(self, name: str, **attrs):
-        sid = next(self._seq)
-        thread = threading.current_thread()
-        begin = {
-            "ev": "B", "span": name, "id": sid, "ts": time.time(),
-            "tid": thread.ident, "thread": thread.name,
-        }
-        if attrs:
-            begin.update(attrs)
-        with self._lock:
-            self._open[sid] = begin
-        self.emit(begin)
-        t0 = time.perf_counter()
-        try:
-            for hook in SPAN_ENTRY_HOOKS:
-                hook(name)
-            with _named_scope(name):
-                yield
-        finally:
-            dur = time.perf_counter() - t0
-            end = {
-                "ev": "E", "span": name, "id": sid,
-                "ts": time.time(), "dur_s": round(dur, 6),
+        # a span is also a stage: it joins the ring under the id its
+        # records carry, shows in a profiler trace under its literal
+        # name (attrs would make every call a name of its own there)
+        # and is the parent of the stages opened inside it
+        with _Stage(self, name) as st:
+            sid = st._seq
+            thread = threading.current_thread()
+            begin = {
+                "ev": "B", "span": name, "id": sid, "ts": time.time(),
                 "tid": thread.ident, "thread": thread.name,
             }
             if attrs:
-                end.update(attrs)
+                begin.update(attrs)
             with self._lock:
-                self._open.pop(sid, None)
-                self._recent.append(end)
-            self.emit(end)
+                self._open[sid] = begin
+            self.emit(begin)
+            t0 = time.perf_counter()
+            try:
+                for hook in SPAN_ENTRY_HOOKS:
+                    hook(name)
+                with _named_scope(name):
+                    yield
+            finally:
+                dur = time.perf_counter() - t0
+                end = {
+                    "ev": "E", "span": name, "id": sid,
+                    "ts": time.time(), "dur_s": round(dur, 6),
+                    "tid": thread.ident, "thread": thread.name,
+                }
+                if attrs:
+                    end.update(attrs)
+                with self._lock:
+                    self._open.pop(sid, None)
+                    self._recent.append(end)
+                self.emit(end)
+
+    # ----- stages: the hot-path ring ---------------------------------------
+
+    def stage(self, name: str) -> _Stage:
+        """Time a hot-path region: on exit one ``(seq, parent_seq, name,
+        t0, dur, thread_id)`` tuple joins the ring (``t0`` on
+        ``time.perf_counter``), and while a ``jax.profiler`` trace is
+        being taken the region shows in it under ``name``. Nothing is
+        written anywhere else."""
+        return _Stage(self, name)
+
+    def stages(self, since: Optional[float] = None,
+               until: Optional[float] = None) -> List[StageRecord]:
+        """The ring's tuples whose ``t0`` lies in [since, until) on the
+        ``perf_counter`` clock, in order of completion (a child before
+        its parent); spans are among them."""
+        return [
+            r for r in list(self._stages)
+            if (since is None or r[3] >= since)
+            and (until is None or r[3] < until)
+        ]
 
     # ----- watchdog-facing state ------------------------------------------
 
@@ -229,6 +333,11 @@ def configure(sink: Optional[Callable[[dict], None]] = None,
 def span(name: str, **attrs):
     """Module-level span on the process-global Telemetry."""
     return _GLOBAL.span(name, **attrs)
+
+
+def stage(name: str) -> _Stage:
+    """Module-level stage on the process-global Telemetry."""
+    return _Stage(_GLOBAL, name)
 
 
 def step_print(step, msg: str) -> None:

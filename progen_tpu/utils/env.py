@@ -4,8 +4,13 @@ flags). python-dotenv is not in this image, and the needed subset is 10
 lines: KEY=VALUE lines, ``#`` comments, optional ``export`` prefix,
 existing environment wins (dotenv's default override=False).
 
-Jax-free on purpose: the CLIs call this BEFORE importing jax, and
-``chip_smoke.py``'s parent must never initialise a backend.
+The CLIs call this BEFORE they import jax. It is also where a process
+that will compile says so: once the environment is set (the compile
+cache's directory with it) the compile counters start listening
+(``telemetry.compiles``), so they cover every compile the process makes,
+the model's ``init`` included. That imports jax — never a backend — and
+a caller that must stay jax-free (the router, the deploy controller,
+``chip_smoke.py``'s parent) passes ``compile_counters=False``.
 """
 
 from __future__ import annotations
@@ -21,11 +26,20 @@ from typing import Optional
 REPO_ENV = Path(__file__).resolve().parents[2] / ".env"
 
 
-def load_env_file(path: Optional[str] = None) -> dict:
+def load_env_file(path: Optional[str] = None, *,
+                  compile_counters: bool = True) -> dict:
     """Load KEY=VALUE pairs into os.environ (existing keys win). Returns
     the parsed mapping; missing file -> empty dict, like load_dotenv.
     ``path=None`` loads the checkout's ``.env`` (``REPO_ENV``)."""
-    p = Path(path) if path is not None else REPO_ENV
+    parsed = _load(Path(path) if path is not None else REPO_ENV)
+    if compile_counters:
+        from progen_tpu.telemetry import compiles
+
+        compiles.install()
+    return parsed
+
+
+def _load(p: Path) -> dict:
     if not p.exists():
         return {}
     parsed = {}

@@ -34,6 +34,7 @@ import numpy as np
 
 from progen_tpu.resilience.chaos import maybe_inject
 from progen_tpu.telemetry import get_telemetry, prometheus_text, write_prometheus
+from progen_tpu.telemetry.spans import stage
 from progen_tpu.telemetry.trace import iter_jsonl
 
 SCORE_OPS = ("start", "resume", "batch", "skip", "done")
@@ -266,40 +267,42 @@ def run_batch_score(
         n = len(batch)
         rows = [raw for _, raw in batch]
         rows += [b""] * (batch_size - n)  # pad rows: all-zero, dropped
-        t = time.monotonic()
-        data = collate(rows, bucket)
-        times["data"] += time.monotonic() - t
+        with stage("score/collate") as st:
+            data = collate(rows, bucket)
+        times["data"] += st.dur
 
-        t = time.monotonic()
-        (nll, lp, mask), first = step_fn(params, data)
-        nll = np.asarray(nll)
-        lp = np.asarray(lp)
-        mask = np.asarray(mask)
-        dt = time.monotonic() - t
+        with stage("score/step") as st_step:
+            (nll, lp, mask), first = step_fn(params, data)
+        with stage("score/fetch") as st_fetch:
+            nll = np.asarray(nll)
+            lp = np.asarray(lp)
+            mask = np.asarray(mask)
+        dt = st_step.dur + st_fetch.dur
         times["compile" if first else "step"] += dt
 
-        t = time.monotonic()
-        for i, (rid, _) in enumerate(batch):
-            rec = {
-                "id": rid,
-                "seq_index": stats["n_resumed"] + stats["n_scored"],
-                "n_tokens": int(mask[i].sum()),
-                "nll": float(nll[i]),
-                "ppl": float(np.exp(nll[i])),
-            }
-            if logprobs:
-                rec["logprobs"] = [float(x) for x in lp[i][mask[i]]]
-            writer.write(rec)
-            seen.add(rid)
-            stats["n_scored"] += 1
-            stats["tokens"] += rec["n_tokens"]
-        writer.flush()
-        times["write"] += time.monotonic() - t
+        with stage("score/write") as st:
+            for i, (rid, _) in enumerate(batch):
+                rec = {
+                    "id": rid,
+                    "seq_index": stats["n_resumed"] + stats["n_scored"],
+                    "n_tokens": int(mask[i].sum()),
+                    "nll": float(nll[i]),
+                    "ppl": float(np.exp(nll[i])),
+                }
+                if logprobs:
+                    rec["logprobs"] = [float(x) for x in lp[i][mask[i]]]
+                writer.write(rec)
+                seen.add(rid)
+                stats["n_scored"] += 1
+                stats["tokens"] += rec["n_tokens"]
+            writer.flush()
+        times["write"] += st.dur
         stats["batches"] += 1
-        journal.emit(
-            {"ev": "score", "op": "batch", "bucket": bucket, "n": n,
-             "scored": stats["n_scored"], "step_s": round(dt, 6)}
-        )
+        with stage("score/journal"):
+            journal.emit(
+                {"ev": "score", "op": "batch", "bucket": bucket, "n": n,
+                 "scored": stats["n_scored"], "step_s": round(dt, 6)}
+            )
         if metrics is not None:
             metrics.inc("sequences_scored", n)
             metrics.inc("tokens_scored", int(mask[:n].sum()))

@@ -58,6 +58,21 @@ class TestFixtureCorpus:
         findings = lint_file(path)
         assert findings == [], [f.render() for f in findings]
 
+    def test_stage_names_are_held_to_the_span_rule(self):
+        """PGL006 checks ``stage(`` as it checks ``span(``: a computed
+        name fails, a literal or a forwarded parameter passes."""
+        findings = lint_file(FIXTURES / "pgl006_stage_tp.py")
+        assert [f.rule for f in findings] == ["PGL006", "PGL006"], [
+            f.render() for f in findings
+        ]
+        assert "f-string" in findings[0].message
+        assert "non-literal" in findings[1].message
+        assert all("stage name" in f.message for f in findings)
+
+    def test_literal_stage_names_pass(self):
+        findings = lint_file(FIXTURES / "pgl006_stage_tn.py")
+        assert findings == [], [f.render() for f in findings]
+
     def test_every_rule_has_fixtures(self):
         ids = {r.id for r in RULES} | {r.id for r in PROJECT_RULES}
         assert ids == set(EXPECTED_TP)

@@ -372,3 +372,102 @@ class TestOccupancyMidChunk:
         m = sched.metrics.snapshot()
         assert m["slot_occupancy"] == 0
         assert m["slots_free"] == 2
+
+
+class TestStages:
+    """The program's own timing of a step (telemetry.spans.stage): one
+    step that admits, prefills a whole prime in one chunk, activates and
+    decodes passes every stage of the scheduler's and the engine's
+    tables, nested as the code nests, without changing a token."""
+
+    PARENT = {
+        "serve/admit": "serve/step",
+        "serve/prepare": "serve/admit",
+        "serve/prefill_chunk": "serve/step",
+        "serve/prefill_dispatch": "serve/prefill_chunk",
+        "serve/prefix_insert": "serve/prefill_chunk",
+        "serve/prefill_finish": "serve/prefill_chunk",
+        "serve/decode": "serve/step",
+        "serve/decode_dispatch": "serve/decode",
+        "serve/decode_fetch": "serve/decode",
+        "serve/emit": "serve/step",
+        "serve/journal": "serve/step",
+    }
+
+    def test_one_step_with_a_chunk_passes_every_stage_once(
+        self, model_and_params, tmp_path
+    ):
+        import time
+
+        from progen_tpu.serving.journal import RequestJournal
+        from progen_tpu.telemetry.spans import get_telemetry
+
+        model, params = model_and_params
+        engine = ServeEngine(model, params, max_slots=2, max_len=32)
+        journal = RequestJournal(tmp_path / "journal.jsonl")
+        sched = Scheduler(engine, max_queue=4, prefill_chunk=16,
+                          prefix_cache=PrefixCache(max_bytes=1 << 24),
+                          journal=journal)
+        first = Request(id="first", prime=np.array([5, 6, 7]), length=30,
+                        key=jax.random.PRNGKey(1))
+        assert sched.submit(first)[0]
+        sched.step()  # "first" is decoding from here on
+        assert sched.active_ids == ["first"]
+
+        tel = get_telemetry()
+        t_submit = time.perf_counter()
+        second = Request(id="second", prime=np.array([9, 8, 7, 6]),
+                         length=12, key=jax.random.PRNGKey(2))
+        assert sched.submit(second)[0]
+        t_step = time.perf_counter()
+        events, _ = sched.step()
+        t_end = time.perf_counter()
+        assert {e.request_id for e in events} == {"first", "second"}
+
+        submits = [r for r in tel.stages(since=t_submit, until=t_step)
+                   if r[2] == "serve/submit"]
+        assert len(submits) == 1
+        assert submits[0][1] is None  # submit() runs outside any step
+
+        recs = tel.stages(since=t_step, until=t_end)
+        by_name = {}
+        for r in recs:
+            by_name.setdefault(r[2], []).append(r)
+        assert set(by_name) == set(self.PARENT) | {"serve/step"}
+        # admission runs again after the activation: twice in this step,
+        # once with work
+        assert len(by_name.pop("serve/admit")) == 2
+        assert all(len(v) == 1 for v in by_name.values()), {
+            k: len(v) for k, v in by_name.items()
+        }
+        by_seq = {r[0]: r for r in recs}
+        for seq, parent, name, t0, dur, _ in recs:
+            if name == "serve/step":
+                assert parent is None
+                continue
+            p = by_seq[parent]
+            assert p[2] == self.PARENT[name], (name, p[2])
+            assert p[3] <= t0 and t0 + dur <= p[3] + p[4] + 1e-9
+        # the scheduler's decode_time_s is the serve/decode stage's seconds
+        decode_s = sched.metrics.structured()["counters"]["decode_time_s"]
+        first_step = [r for r in tel.stages(until=t_submit)
+                      if r[2] == "serve/decode"][-1]
+        assert decode_s == pytest.approx(first_step[4] + by_name["serve/decode"][0][4])
+        journal.close()
+
+    def test_stages_change_no_token(self, model_and_params):
+        """The stream a staged engine serves is the standalone
+        decoder's, token for token (chunked, prefix cache on)."""
+        from progen_tpu.sampling import sample_fast
+
+        model, params = model_and_params
+        reqs = _requests(4)
+        got, _, _ = _run(model, params, reqs, prefill_chunk=3,
+                         prefix_cache=PrefixCache(max_bytes=1 << 24))
+        for req in reqs:
+            want = np.asarray(sample_fast(
+                req.key, model, params, jnp.asarray(req.prime), req.length,
+                top_k=req.top_k, add_bos=req.add_bos,
+                temperature=req.temperature, top_p=req.top_p,
+            ))
+            np.testing.assert_array_equal(got[req.id], want, err_msg=req.id)

@@ -484,3 +484,67 @@ class TestRequestTracing:
             if ln.startswith("progen_serve_itl_seconds_count")
         ]
         assert count and float(count[0].split()[1]) > 0
+
+
+class TestTelemetryHooks:
+    def test_no_record_is_built_without_a_sink_or_a_tap(
+        self, model_and_params, monkeypatch
+    ):
+        """_req_event and _emit_slots keep their docstring's promise: a
+        process that records nothing pays for no record. Whether a record
+        was built shows in whether ``emit`` was reached."""
+        from progen_tpu.telemetry import spans
+
+        model, params = model_and_params
+        engine = ServeEngine(model, params, max_slots=1, max_len=32)
+        sched = Scheduler(engine, max_queue=2)
+        tel = spans.get_telemetry()
+        reached = []
+        monkeypatch.setattr(tel, "emit", reached.append)
+        monkeypatch.setattr(spans, "EMIT_TAPS", [])
+        monkeypatch.setattr(tel, "_sink", None)
+        sched._req_event("n", "r0", "first_token")
+        sched._last_slots_emitted = None
+        sched._emit_slots()
+        assert reached == []
+        assert sched.metrics.snapshot()["slot_occupancy"] == 0  # gauges still set
+        monkeypatch.setattr(tel, "_sink", lambda rec: None)
+        sched._req_event("n", "r0", "first_token", trace="t-1")
+        sched._last_slots_emitted = None
+        sched._emit_slots()
+        assert [r["ev"] for r in reached] == ["req", "slots"]
+        assert reached[0]["trace_id"] == "t-1" and reached[1]["in_use"] == 0
+
+    def test_process_compile_count_is_a_gauge_beside_the_two_jit_counts(
+        self, model_and_params
+    ):
+        from progen_tpu.telemetry import compiles
+
+        compiles.install()  # a serving process: load_env_file() does it
+        model, params = model_and_params
+        engine = ServeEngine(model, params, max_slots=1, max_len=32)
+        sched = Scheduler(engine, max_queue=2)
+        m = sched.metrics.snapshot()
+        assert {"decode_compile_count", "prefill_compile_count",
+                "xla_compile_count"} <= set(m)
+        # a compile neither jit-cache count sees: an eager op at a new shape
+        before = m["xla_compile_count"]
+        (jnp.arange(7919) * 2).block_until_ready()
+        assert sched.submit(_req(0, length=6))[0]
+        sched.run_to_completion(max_steps=100)
+        after = sched.metrics.snapshot()["xla_compile_count"]
+        assert after == compiles.backend_compiles() > before
+
+    def test_no_process_compile_gauge_where_nothing_counts(
+        self, model_and_params, monkeypatch
+    ):
+        """A process that never called load_env_file() has no count to
+        show: the gauge is absent, not 0."""
+        from progen_tpu.telemetry import compiles
+
+        monkeypatch.setattr(compiles, "_installed", False)
+        model, params = model_and_params
+        engine = ServeEngine(model, params, max_slots=1, max_len=32)
+        m = Scheduler(engine, max_queue=2).metrics.snapshot()
+        assert "xla_compile_count" not in m
+        assert "decode_compile_count" in m

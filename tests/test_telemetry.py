@@ -596,3 +596,342 @@ def test_metrics_registry_thread_safe_inc():
     for t in threads:
         t.join()
     assert reg.snapshot()["n"] == 8000
+
+
+# --------------------------------------------------------------- stages
+
+
+def test_stage_appends_one_tuple_with_its_parent_under_nesting():
+    tel = Telemetry()
+    t_before = time.perf_counter()
+    with tel.span("serve/prefill_chunk", request_id="r7"):
+        with tel.stage("serve/prefill_dispatch"):
+            with tel.stage("serve/prefix_insert"):
+                pass
+        with tel.stage("serve/prefill_finish"):
+            pass
+    t_after = time.perf_counter()
+    recs = tel.stages()
+    # one tuple each, in order of completion: a child before its parent
+    assert [r[2] for r in recs] == [
+        "serve/prefix_insert", "serve/prefill_dispatch",
+        "serve/prefill_finish", "serve/prefill_chunk",
+    ]
+    by_name = {r[2]: r for r in recs}
+    chunk = by_name["serve/prefill_chunk"]
+    assert chunk[1] is None and len(chunk) == 6  # attrs stay in the records
+    assert by_name["serve/prefill_dispatch"][1] == chunk[0]
+    assert by_name["serve/prefill_finish"][1] == chunk[0]
+    assert by_name["serve/prefix_insert"][1] == by_name["serve/prefill_dispatch"][0]
+    for seq, parent, name, t0, dur, tid in recs:
+        assert t_before <= t0 <= t0 + dur <= t_after
+        assert tid == threading.get_ident()
+    assert len({r[0] for r in recs}) == 4  # seqs are unique
+
+
+def test_stage_dur_can_be_read_once_it_has_exited():
+    tel = Telemetry()
+    with tel.stage("serve/decode") as st:
+        assert not hasattr(st, "dur")  # no reading while it is open
+    assert st.dur == tel.stages()[-1][4] >= 0.0
+
+
+def test_stage_parents_are_per_thread():
+    tel = Telemetry()
+    inside = threading.Event()
+    release = threading.Event()
+
+    def other():
+        with tel.stage("bg/outer"):
+            inside.set()
+            assert release.wait(timeout=10)
+            with tel.stage("bg/inner"):
+                pass
+
+    t = threading.Thread(target=other)
+    t.start()
+    assert inside.wait(timeout=10)
+    with tel.stage("main/outer"):  # opened while bg/outer is open
+        with tel.stage("main/inner"):
+            pass
+    release.set()
+    t.join(timeout=10)
+    assert not t.is_alive()
+    by_name = {r[2]: r for r in tel.stages()}
+    assert by_name["main/outer"][1] is None and by_name["bg/outer"][1] is None
+    assert by_name["main/inner"][1] == by_name["main/outer"][0]
+    assert by_name["bg/inner"][1] == by_name["bg/outer"][0]
+    assert by_name["bg/inner"][5] == by_name["bg/outer"][5] != by_name["main/outer"][5]
+
+
+def test_stage_is_recorded_and_unwound_under_an_exception():
+    tel = Telemetry()
+    with pytest.raises(RuntimeError):
+        with tel.stage("serve/step"):
+            with tel.stage("serve/decode"):
+                raise RuntimeError("boom")
+    assert [r[2] for r in tel.stages()] == ["serve/decode", "serve/step"]
+    with tel.stage("serve/step"):  # the stack unwound: a root again
+        pass
+    assert tel.stages()[-1][1] is None
+
+
+def test_stage_writes_nothing_to_a_sink_or_a_tap():
+    from progen_tpu.telemetry import spans
+
+    seen, tapped = [], []
+    tel = Telemetry(sink=seen.append)
+    tap = tapped.append
+    spans.EMIT_TAPS.append(tap)
+    try:
+        with tel.stage("serve/decode"):
+            pass
+    finally:
+        spans.EMIT_TAPS.remove(tap)
+    assert seen == [] and tapped == []
+    assert tel.open_spans() == [] and tel.recent_spans() == []
+    assert [r[2] for r in tel.stages()] == ["serve/decode"]
+
+
+def test_span_still_writes_its_pair_and_now_lands_in_the_ring():
+    seen = []
+    tel = Telemetry(sink=seen.append)
+    with tel.span("ckpt/save", step=3):
+        with tel.stage("ckpt/write"):
+            pass
+    assert [(r["ev"], r["span"]) for r in seen] == [("B", "ckpt/save"),
+                                                   ("E", "ckpt/save")]
+    assert seen[0]["step"] == 3 and "dur_s" in seen[1]
+    ring = {r[2]: r for r in tel.stages()}
+    assert ring["ckpt/save"][0] == seen[0]["id"]  # one id in both places
+    assert ring["ckpt/write"][1] == seen[0]["id"]
+
+
+def test_stages_filter_on_the_perf_counter_clock():
+    tel = Telemetry()
+    with tel.stage("a/first"):
+        pass
+    cut = time.perf_counter()
+    with tel.stage("a/second"):
+        pass
+    end = time.perf_counter()
+    assert [r[2] for r in tel.stages(until=cut)] == ["a/first"]
+    assert [r[2] for r in tel.stages(since=cut)] == ["a/second"]
+    assert [r[2] for r in tel.stages(since=cut, until=end)] == ["a/second"]
+    assert tel.stages(since=end) == []
+    assert len(tel.stages()) == 2
+
+
+def test_stage_ring_is_bounded(monkeypatch):
+    from progen_tpu.telemetry import spans
+
+    monkeypatch.setattr(spans, "MAX_STAGES", 8)
+    tel = Telemetry()
+    for _ in range(20):
+        with tel.stage("hot/loop"):
+            pass
+    recs = tel.stages()
+    assert len(recs) == 8
+    assert [r[0] for r in recs] == list(range(12, 20))  # the newest survive
+    from progen_tpu.telemetry.spans import get_telemetry
+
+    assert get_telemetry()._stages.maxlen == 65536
+
+
+def test_module_level_stage_uses_the_process_ring():
+    from progen_tpu.telemetry.spans import get_telemetry, stage
+
+    t0 = time.perf_counter()
+    with stage("test/module_level"):
+        pass
+    mine = [r for r in get_telemetry().stages(since=t0)
+            if r[2] == "test/module_level"]
+    assert len(mine) == 1 and mine[0][5] == threading.get_ident()
+
+
+def test_recording_is_true_only_with_a_sink_or_a_tap():
+    from progen_tpu.telemetry import spans
+
+    tel = Telemetry()
+    taps, spans.EMIT_TAPS[:] = list(spans.EMIT_TAPS), []
+    try:
+        assert not tel.recording
+        spans.EMIT_TAPS.append(lambda rec: None)
+        assert tel.recording
+        spans.EMIT_TAPS.clear()
+        tel.set_sink(lambda rec: None)
+        assert tel.recording
+    finally:
+        spans.EMIT_TAPS[:] = taps
+
+
+def test_stage_changes_no_traced_program_but_span_labels_it():
+    """A stage enters no named_scope: ops traced under it carry the same
+    name stacks as without it, where a span puts its name on them."""
+    import contextlib
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    tel = Telemetry()
+
+    def op_names(region):
+        def f(x):  # a fresh function each time: jit caches traces by it
+            with region():
+                return jnp.tanh(x) * 2.0
+
+        text = jax.jit(f).lower(jnp.ones((4,))).as_text(debug_info=True)
+        # the ops' name stacks; file:line locations differ by call site
+        return re.findall(r'loc\("(jit\(f\)[^"]*)"', text)
+
+    plain = op_names(contextlib.nullcontext)
+    staged = op_names(lambda: tel.stage("serve/decode_dispatch"))
+    spanned = op_names(lambda: tel.span("serve/prefill"))
+    assert plain and staged == plain
+    assert any("serve/prefill" in n for n in spanned)
+    assert not any("serve/" in n for n in plain)
+
+
+def test_stages_and_spans_show_in_a_profiler_trace(tmp_path):
+    """A few Scheduler.step()s of the tiny model under jax.profiler: the
+    program's stages stand on the host plane under their literal names,
+    where the benchmark's trace reader finds them."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from flax.core import meta
+
+    from benchmark import xplane
+    from progen_tpu.config import ProGenConfig
+    from progen_tpu.models.progen import ProGen
+    from progen_tpu.serving import Request, Scheduler, ServeEngine
+
+    cfg = ProGenConfig(num_tokens=32, dim=32, seq_len=32, depth=2,
+                       window_size=8, global_mlp_depth=1, heads=2,
+                       dim_head=16, ff_mult=2, dtype="float32")
+    model = ProGen(cfg)
+    params = meta.unbox(model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, cfg.seq_len), jnp.int32)
+    ))["params"]
+    engine = ServeEngine(model, params, max_slots=2, max_len=32)
+    sched = Scheduler(engine, max_queue=4, prefill_chunk=2)
+    for i in range(2):
+        assert sched.submit(Request(id=f"p{i}", prime=np.array([3, 4, 5]),
+                                    length=12, key=jax.random.PRNGKey(i)))[0]
+    sched.step()  # compiles outside the trace
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path / "trace"), profiler_options=opts)
+    try:
+        for _ in range(4):
+            sched.step()
+    finally:
+        jax.profiler.stop_trace()
+    sched.run_to_completion(max_steps=200)
+    path = xplane.find_xplane(str(tmp_path / "trace"))
+    assert path is not None
+    names = {"serve/step", "serve/decode", "serve/decode_fetch",
+             "serve/emit", "serve/prefill_chunk"}
+    host = xplane.load(path, names)["host"]
+    count = {n: sum(1 for e in host if e[0] == n) for n in names}
+    assert count["serve/step"] == count["serve/decode"] == 4
+    assert count["serve/decode_fetch"] == count["serve/emit"] == 4
+    assert count["serve/prefill_chunk"] >= 1  # the span is there too
+    # children inside parents on the profiler's clock
+    steps = [e for e in host if e[0] == "serve/step"]
+    for name, s, d in host:
+        if name == "serve/decode_fetch":
+            assert any(ps <= s and s + d <= ps + pd for _, ps, pd in steps)
+
+
+# ------------------------------------------------------------- compiles
+
+
+def test_compiles_install_is_idempotent_and_counts_a_fresh_jit():
+    import jax
+    import jax.numpy as jnp
+    from jax._src import monitoring
+
+    from progen_tpu.telemetry import compiles
+
+    compiles.install()
+    n_dur = len(monitoring.get_event_duration_listeners())
+    n_ev = len(monitoring.get_event_listeners())
+    compiles.install()
+    assert len(monitoring.get_event_duration_listeners()) == n_dur
+    assert len(monitoring.get_event_listeners()) == n_ev
+    assert monitoring.get_event_duration_listeners().count(compiles._on_duration) == 1
+
+    before = compiles.snapshot()["backend_compile"]
+    t_mid = time.perf_counter()
+    # progen: ignore[PGL004] - a fresh compile is the point
+    jax.jit(lambda x: x * 3.0 + 1.25)(jnp.ones((5,))).block_until_ready()
+    after = compiles.snapshot()["backend_compile"]
+    assert after["count"] > before["count"]
+    assert after["seconds"] > before["seconds"]
+    assert compiles.backend_compiles() == after["count"]
+    assert set(compiles.snapshot()) == {"backend_compile", "cache_misses"}
+    # by time: the fresh compile lies after t_mid, so a reading up to
+    # t_mid leaves it out
+    early = compiles.snapshot(until=t_mid)["backend_compile"]
+    assert early["count"] == before["count"]
+    assert abs(early["seconds"] - before["seconds"]) < 1e-9
+    assert all(t < t_mid for t, _ in early["recent"])
+
+
+def test_load_env_file_starts_the_compile_counters_unless_told_not_to(tmp_path):
+    import subprocess
+    import sys
+
+    env_file = tmp_path / ".env"
+    env_file.write_text("PROGEN_TEST_ENV_KEY=1\n")
+    code = (
+        "import sys\n"
+        "from progen_tpu.utils.env import load_env_file\n"
+        f"load_env_file({str(env_file)!r}, compile_counters=False)\n"
+        "assert 'jax' not in sys.modules\n"
+        "assert 'progen_tpu.telemetry.compiles' not in sys.modules\n"
+        f"load_env_file({str(env_file)!r})\n"
+        "from progen_tpu.telemetry import compiles\n"
+        "assert compiles._installed\n"
+        "from jax._src import monitoring, xla_bridge\n"
+        "assert compiles._on_duration in monitoring.get_event_duration_listeners()\n"
+        "assert not xla_bridge.backends_are_initialized()\n"
+    )
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True)
+    assert proc.returncode == 0, proc.stderr.decode()
+
+
+def test_compiles_ignores_other_events():
+    from progen_tpu.telemetry import compiles
+
+    before = compiles.snapshot()
+    compiles._on_duration("/jax/core/compile/jaxpr_trace_duration", 1.0)
+    compiles._on_event("/jax/some/other/event")
+    after = compiles.snapshot()
+    assert {k: v["count"] for k, v in after.items()} == {
+        k: v["count"] for k, v in before.items()
+    }
+
+
+def test_prometheus_help_says_what_prefill_time_measures():
+    from progen_tpu.serving.metrics import ServingMetrics
+
+    m = ServingMetrics()
+    m.add_time("prefill_time_s", 0.25)
+    m.inc("prefill_tokens", 16)
+    m.set_gauge("xla_compile_count", 3)
+    m.set_gauge("queue_depth", 0)
+    lines = prometheus_text(m).splitlines()
+    i = lines.index("# TYPE progen_serve_prefill_time_s_total counter")
+    assert lines[i - 1].startswith("# HELP progen_serve_prefill_time_s_total Host seconds")
+    assert "not the device" in lines[i - 1]
+    assert any(l.startswith("# HELP progen_serve_prefill_tokens_per_s ") for l in lines)
+    assert any(l.startswith("# HELP progen_serve_xla_compile_count ") for l in lines)
+    # names without help text keep the bare TYPE line, and samples parse
+    assert not any(l.startswith("# HELP progen_serve_queue_depth") for l in lines)
+    from progen_tpu.telemetry.slo import parse_prom_text
+
+    assert parse_prom_text("\n".join(lines))["prefill_time_s"] == 0.25

@@ -398,6 +398,51 @@ class TestSharedScorer:
         assert summary["n_scored"] == 1 and summary["n_skipped"] == 1
 
 
+class TestScoreStages:
+    def test_a_batch_yields_the_four_stages_and_the_summary_it_had(
+        self, byte_model, tmp_path
+    ):
+        """One batch = one score/collate, score/step, score/fetch,
+        score/write and score/journal, in that order and not nested; the
+        summary keeps its keys and its ``times`` their meaning (the
+        journal line is no part of ``write``), fed from the same clock
+        reads."""
+        import time
+
+        from progen_tpu.telemetry.spans import get_telemetry
+        from progen_tpu.workloads import run_batch_score
+
+        model, params = byte_model
+        rng = np.random.default_rng(5)
+        records = [(f"t{i}", ("# " + _aa_seq(rng, 12)).encode())
+                   for i in range(8)]
+        t0 = time.perf_counter()
+        summary = run_batch_score(model, params, records,
+                                  str(tmp_path / "s"), batch_size=4,
+                                  resume=False)
+        recs = [r for r in get_telemetry().stages(since=t0)
+                if r[2].startswith("score/")]
+        assert [r[2] for r in recs] == [
+            "score/collate", "score/step", "score/fetch", "score/write",
+            "score/journal",
+        ] * 2
+        assert all(r[1] is None for r in recs)
+        assert set(summary) == {
+            "n_scored", "n_skipped", "n_resumed", "tokens", "batches",
+            "elapsed_s", "goodput_pct", "times", "stopped_early",
+        }
+        assert set(summary["times"]) == {"data", "step", "compile", "write"}
+        assert summary["batches"] == 2 and summary["n_scored"] == 8
+        total = {}
+        for r in recs:
+            total[r[2]] = total.get(r[2], 0.0) + r[4]
+        times = summary["times"]  # rounded to the millisecond
+        assert abs(times["data"] - total["score/collate"]) < 1e-3
+        assert abs(times["write"] - total["score/write"]) < 1e-3
+        assert abs(times["step"] + times["compile"]
+                   - total["score/step"] - total["score/fetch"]) < 2e-3
+
+
 class TestBatchScoreResume:
     def _records(self, n=12):
         rng = np.random.default_rng(3)
