@@ -15,6 +15,8 @@ scale-only (create_offset=False in the reference, progen.py:22).
 
 from __future__ import annotations
 
+from typing import NamedTuple, Optional
+
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
@@ -31,18 +33,62 @@ def _dense_init():
     return nn.initializers.lecun_normal()
 
 
-def _cached_shift(module: nn.Module, x: jnp.ndarray) -> jnp.ndarray:
-    """Token-shift for one-token decode: the shifted-in half comes from a
-    cache variable holding the previous position's post-LN features (shared
-    by the attention and feed-forward blocks)."""
+class DecodeRows(NamedTuple):
+    """Where the T rows of one decode-mode call sit. A call feeds the
+    block of T consecutive positions, aligned to a multiple of T, that
+    holds the cache's position counter; T = 1 is the one-token decode
+    step. ``live`` marks the rows that are fed — they alone write cache
+    leaves, and no live row reads anything a dead row computed — or is
+    None when every row is (which is how T = 1 stays free of masks)."""
+
+    pos: jnp.ndarray  # (T,) int32 absolute position of each row
+    live: Optional[jnp.ndarray]  # (T,) bool, a contiguous run; None = all
+
+
+def _write_rows(buf, new, start, axis, rows: DecodeRows):
+    """Write the block's T rows into ``buf`` at ``start`` along ``axis``,
+    keeping what the buffer holds wherever a row is not live."""
+    t = rows.pos.shape[0]
+    if rows.live is not None:
+        shape = [1] * new.ndim
+        shape[axis] = t
+        old = jax.lax.dynamic_slice_in_dim(buf, start, t, axis=axis)
+        new = jnp.where(rows.live.reshape(shape), new, old)
+    return jax.lax.dynamic_update_slice_in_dim(buf, new, start, axis=axis)
+
+
+def _cached_shift(module: nn.Module, x: jnp.ndarray,
+                  rows: DecodeRows) -> jnp.ndarray:
+    """Token-shift in decode mode: row r takes row r - 1's shifted half,
+    and the first row fed takes the cache variable that holds the
+    post-LN features of the last position fed before this call (shared
+    by the attention and feed-forward blocks), which the last row fed
+    then replaces."""
     split = x.shape[-1] - x.shape[-1] // 2
     st = module.variable(
         "cache", "shift_state",
         lambda: jnp.zeros((x.shape[0], 1, split), x.dtype),
     )
     shifted = shift_tokens(x, shift_state=st.value)
+    if rows.live is None:
+        last = x[:, -1:, :split]
+    else:
+        # a resume mid-block reads the state too, not what a dead row
+        # recomputed
+        first = jnp.argmax(rows.live)
+        n_live = jnp.sum(rows.live.astype(jnp.int32))
+        at_first = (jnp.arange(x.shape[1]) == first)[None, :, None]
+        shifted = jnp.concatenate(
+            (jnp.where(at_first, st.value, shifted[..., :split]),
+             shifted[..., split:]),
+            axis=-1,
+        )
+        last = jax.lax.dynamic_slice_in_dim(
+            x[..., :split], jnp.maximum(first + n_live - 1, 0), 1, axis=1
+        )
+        last = jnp.where(n_live > 0, last, st.value)
     if not module.is_initializing():
-        st.value = x[..., :split]
+        st.value = last
     return shifted
 
 
@@ -104,7 +150,8 @@ def _fused_layer_ok(c: ProGenConfig) -> bool:
     return c.use_fused_layer_kernels and not c.decode
 
 
-def _norm_shift_head(module: nn.Module, x: jnp.ndarray) -> jnp.ndarray:
+def _norm_shift_head(module: nn.Module, x: jnp.ndarray,
+                     rows: Optional[DecodeRows] = None) -> jnp.ndarray:
     """The pre-LN + token-shift head shared by the attention and FF
     blocks. With config.use_fused_layer_kernels the two ops run as ONE
     policy-dispatched Pallas pass (ops/pallas_layers.py); the norm's
@@ -123,16 +170,17 @@ def _norm_shift_head(module: nn.Module, x: jnp.ndarray) -> jnp.ndarray:
         )
     x = norm(x)
     if c.shift_tokens:
-        x = _cached_shift(module, x) if c.decode else shift_tokens(x)
+        x = _cached_shift(module, x, rows) if c.decode else shift_tokens(x)
     return x
 
 
 class LocalAttentionBlock(nn.Module):
-    """Windowed attention block. In config.decode mode the sequence axis is
-    1 and a rolling 2-window K/V cache (flax 'cache' collection) replaces
-    the windowed reshape — O(2w·d) per emitted token instead of a full
-    forward (the reference samples with full-length forwards per token,
-    utils.py:116-117)."""
+    """Windowed attention block. In config.decode mode the sequence axis
+    holds the T positions of one block (``DecodeRows``; T = 1 for a
+    decode step) and a rolling 2-window K/V cache (flax 'cache'
+    collection) replaces the windowed reshape — O(2w·d) per position
+    instead of a full forward (the reference samples with full-length
+    forwards per token, utils.py:116-117)."""
 
     config: ProGenConfig
     # physical mesh, set by ProGen when built with one — enables the
@@ -140,12 +188,12 @@ class LocalAttentionBlock(nn.Module):
     mesh: object = None
 
     @nn.compact
-    def __call__(self, x, sin, cos, pos=None):
+    def __call__(self, x, sin, cos, rows: Optional[DecodeRows] = None):
         c = self.config
         b, n, _ = x.shape
         h, dh, w = c.heads, c.dim_head, c.window_size
 
-        x = _norm_shift_head(self, x)
+        x = _norm_shift_head(self, x, rows)
 
         qkv = nn.Dense(
             3 * c.inner_dim,
@@ -165,9 +213,9 @@ class LocalAttentionBlock(nn.Module):
         q, k, v = map(split_heads, (q, k, v))
 
         if c.decode:
-            # slice the current position's RoPE row from the full tables
-            sin = jax.lax.dynamic_slice_in_dim(sin, pos, 1, axis=0)
-            cos = jax.lax.dynamic_slice_in_dim(cos, pos, 1, axis=0)
+            # slice the block's RoPE rows from the full tables
+            sin = jax.lax.dynamic_slice_in_dim(sin, rows.pos[0], n, axis=0)
+            cos = jax.lax.dynamic_slice_in_dim(cos, rows.pos[0], n, axis=0)
 
         q = apply_rotary_pos_emb(q, sin, cos)
         k = apply_rotary_pos_emb(k, sin, cos)
@@ -178,7 +226,7 @@ class LocalAttentionBlock(nn.Module):
         # "attention_core" whether the step ran XLA, ring, or Pallas
         with jax.named_scope("attention_core"):
             if c.decode:
-                out = self._decode_attend(q, k, v, pos)  # (b, h, 1, dh)
+                out = self._decode_attend(q, k, v, rows)  # (b, h, T, dh)
             elif (
                 c.use_ring_attn
                 and self.mesh is not None
@@ -248,14 +296,21 @@ class LocalAttentionBlock(nn.Module):
             name="to_out",
         )(out)
 
-    def _decode_attend(self, q, k, v, pos):
-        """One-token attention against a rolling 2-window K/V ring buffer.
+    def _decode_attend(self, q, k, v, rows: DecodeRows):
+        """Attention of the block's T queries against a rolling 2-window
+        K/V ring buffer.
 
-        Slot ``p % 2w`` holds position p; visibility is recomputed per step
-        from the stored absolute positions. Window-0 queries' softmax is
-        diluted by exactly ``w`` phantom zero-score/zero-value keys via an
-        analytic denominator correction — the reference's zero-padded
-        previous window (progen.py:90-96) without materializing it.
+        Slot ``p % 2w`` holds position p; the block's keys are written
+        first, then every query row is masked by the stored absolute
+        positions against its own. Writing before attending is safe
+        because a block never straddles a window boundary (T divides w
+        and blocks are aligned): the slots it overwrites held window
+        k - 2, which no query of window k sees, and the rows after a
+        query in its own block are masked like any later position.
+        Window-0 queries' softmax is diluted by exactly ``w`` phantom
+        zero-score/zero-value keys via an analytic denominator
+        correction — the reference's zero-padded previous window
+        (progen.py:90-96) without materializing it.
         """
         c = self.config
         b, h, _, dh = q.shape
@@ -272,31 +327,26 @@ class LocalAttentionBlock(nn.Module):
             "cache", "slot_pos", lambda: jnp.full((ring,), -1, jnp.int32)
         )
 
-        slot = pos % ring
+        pos = rows.pos
+        slot = pos[0] % ring
         if not self.is_initializing():
-            ck.value = jax.lax.dynamic_update_slice_in_dim(
-                ck.value, k, slot, axis=2
-            )
-            cv.value = jax.lax.dynamic_update_slice_in_dim(
-                cv.value, v, slot, axis=2
-            )
-            cpos.value = jax.lax.dynamic_update_index_in_dim(
-                cpos.value, pos, slot, axis=0
-            )
+            ck.value = _write_rows(ck.value, k, slot, 2, rows)
+            cv.value = _write_rows(cv.value, v, slot, 2, rows)
+            cpos.value = _write_rows(cpos.value, pos, slot, 0, rows)
 
         slot_pos = cpos.value
         visible = (
             (slot_pos >= 0)
-            & (slot_pos <= pos)
-            & (pos // w - slot_pos // w <= 1)
-        )
+            & (slot_pos <= pos[:, None])
+            & (pos[:, None] // w - slot_pos // w <= 1)
+        )  # (T, ring)
         scores = jnp.einsum(
             "bhqd,bhkd->bhqk", q, ck.value,
             preferred_element_type=jnp.float32,
         ) * (dh ** -0.5)
-        scores = jnp.where(visible[None, None, None, :], scores, -1e10)
+        scores = jnp.where(visible[None, None], scores, -1e10)
 
-        first_window = (pos < w).astype(jnp.float32)
+        first_window = (pos < w).astype(jnp.float32)[:, None]  # (T, 1)
         # softmax with analytic phantom-key dilution: shift-invariant, so a
         # stable max including the phantoms' score 0 is fine
         m = jnp.maximum(
@@ -316,7 +366,7 @@ class SpatialGatingUnit(nn.Module):
     dim_out: int
 
     @nn.compact
-    def __call__(self, x, pos=None):
+    def __call__(self, x, rows: Optional[DecodeRows] = None):
         c = self.config
         n = c.seq_len
         assert c.decode or x.shape[-2] == n, (
@@ -358,28 +408,31 @@ class SpatialGatingUnit(nn.Module):
         with jax.named_scope("sgu_spatial_mix"):
             if c.decode:
                 # incremental spatial mix: keep the LayerNormed gate
-                # history and contract the current causal row of the
+                # history and contract the block's causal rows of the
                 # (n, n) matrix with it —
-                # out[pos] = sum_{j<=pos} W[pos, j] * gate[j] + b[pos]
-                b_sz, half = gate.shape[0], gate.shape[-1]
+                # out[p] = sum_{j<=p} W[p, j] * gate[j] + b[p]
+                b_sz, t, half = gate.shape
                 hist = self.variable(
                     "cache", "gate_history",
                     lambda: jnp.zeros((b_sz, n, half), jnp.float32),
                 )
+                pos = rows.pos
                 if not self.is_initializing():
-                    hist.value = jax.lax.dynamic_update_slice_in_dim(
-                        hist.value, gate.astype(jnp.float32), pos, axis=1
+                    hist.value = _write_rows(
+                        hist.value, gate.astype(jnp.float32), pos[0], 1,
+                        rows,
                     )
-                row = jax.lax.dynamic_index_in_dim(
-                    weights.astype(jnp.float32), pos, axis=0, keepdims=False
+                w_rows = jax.lax.dynamic_slice_in_dim(
+                    weights, pos[0], t, axis=0
+                ).astype(jnp.float32)
+                w_rows = jnp.where(
+                    jnp.arange(n) <= pos[:, None], w_rows, 0.0
                 )
-                row = jnp.where(jnp.arange(n) <= pos, row, 0.0)
-                mixed = jnp.einsum("bnd,n->bd", hist.value, row)
-                mixed = mixed + jax.lax.dynamic_index_in_dim(
-                    biases.astype(jnp.float32), pos, axis=0, keepdims=False
-                )
-                gate = mixed[:, None, :].astype(x.dtype)
-                x = x * gate
+                mixed = jnp.einsum("bnd,tn->btd", hist.value, w_rows)
+                mixed = mixed + jax.lax.dynamic_slice_in_dim(
+                    biases, pos[0], t, axis=0
+                ).astype(jnp.float32)
+                x = x * mixed.astype(x.dtype)
             elif fused:
                 from progen_tpu.ops.pallas_layers import sgu_mix_gate
 
@@ -412,14 +465,14 @@ class FeedForwardBlock(nn.Module):
     spatial_gate: bool = False
 
     @nn.compact
-    def __call__(self, x, pos=None):
+    def __call__(self, x, rows: Optional[DecodeRows] = None):
         c = self.config
         assert not (self.glu and self.spatial_gate), (
             "glu and sgu cannot be turned on at the same time"
         )
         hidden = c.dim * c.ff_mult * (2 if self.glu else 1)
 
-        x = _norm_shift_head(self, x)
+        x = _norm_shift_head(self, x, rows)
 
         x = nn.Dense(
             hidden,
@@ -438,7 +491,7 @@ class FeedForwardBlock(nn.Module):
                 x = jax.nn.gelu(x)
 
         if self.spatial_gate:
-            x = SpatialGatingUnit(c, dim_out=hidden // 2, name="sgu")(x, pos)
+            x = SpatialGatingUnit(c, dim_out=hidden // 2, name="sgu")(x, rows)
 
         x = nn.with_logical_constraint(x, ("batch", "seq_act", "mlp_act"))
         return nn.Dense(

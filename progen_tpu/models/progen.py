@@ -30,6 +30,7 @@ from flax import linen as nn
 
 from progen_tpu.config import ProGenConfig
 from progen_tpu.models.layers import (
+    DecodeRows,
     FeedForwardBlock,
     LocalAttentionBlock,
     ScaleNorm,
@@ -59,9 +60,10 @@ class UniformBlock(nn.Module):
 def decode_model(model: "ProGen") -> "ProGen":
     """The decode-mode twin of a full-forward model: same weight tree
     (scan-stacked layouts convert via ``unstack_params`` — decode is always
-    unrolled because its per-layer caches are), one token per call, state
-    in a flax 'cache' collection (rolling 2-window K/V ring, token-shift
-    states, SGU gate history, and a position counter — all allocated
+    unrolled because its per-layer caches are), one token or one aligned
+    block of positions per call (``ProGen.__call__``), state in a flax
+    'cache' collection (rolling 2-window K/V ring, token-shift states,
+    SGU gate history, and a position counter — all allocated
     batch-shaped by ``init``, which is the cache-shape hook the sampling
     and serving layers build their buffers from)."""
     import dataclasses
@@ -124,11 +126,23 @@ class ProGen(nn.Module):
     mesh: object = None
 
     @nn.compact
-    def __call__(self, tokens: jnp.ndarray) -> jnp.ndarray:
+    def __call__(self, tokens: jnp.ndarray, feed_to=None) -> jnp.ndarray:
         """tokens: (batch, seq_len) integer array. Returns float32 logits of
-        shape (batch, seq_len, num_tokens)."""
+        shape (batch, seq_len, num_tokens).
+
+        In config.decode mode the sequence axis is T >= 1 positions fed
+        through the cache: the tokens of the block of T consecutive
+        positions, aligned to a multiple of T, that holds the cache's
+        position counter ``pos``. T must divide ``window_size`` (so no
+        block straddles a window). Without ``feed_to`` every row is fed
+        and ``pos`` must be at the block's start — trivially so at T = 1,
+        the decode step. With ``feed_to`` (a traced scalar) the rows at
+        positions ``pos <= p < feed_to`` are fed and the others are dead:
+        computed, since shapes are static, but writing nothing to any
+        cache leaf. ``pos`` advances by the number of rows fed."""
         c = self.config
         n = tokens.shape[-1]
+        assert c.decode or feed_to is None, "feed_to is for decode mode"
 
         x = nn.Embed(
             c.num_tokens,
@@ -143,15 +157,24 @@ class ProGen(nn.Module):
         x = nn.with_logical_constraint(x, ("batch", "seq_act", "embed_act"))
 
         if c.decode:
-            # one-token step: full-length RoPE tables (blocks slice their
-            # row), one shared position counter advanced per call
+            # full-length RoPE tables (blocks slice their rows), one
+            # shared position counter advanced per call
+            assert c.window_size % n == 0, (
+                f"a decode call feeds {n} positions, which must divide "
+                f"window_size={c.window_size}"
+            )
             pos_var = self.variable(
                 "cache", "pos", lambda: jnp.zeros((), jnp.int32)
             )
             pos = pos_var.value
+            at = (pos - pos % n if n > 1 else pos) + jnp.arange(n)
+            rows = DecodeRows(
+                at,
+                None if feed_to is None else (at >= pos) & (at < feed_to),
+            )
             sin, cos = fixed_pos_embedding(c.seq_len, c.dim_head)
         else:
-            pos = None
+            rows = None
             # RoPE tables are tiny; build in f32 once per trace (progen.py:227)
             sin, cos = fixed_pos_embedding(n, c.dim_head)
 
@@ -182,15 +205,18 @@ class ProGen(nn.Module):
             use_gmlp = (c.depth - i) <= c.global_mlp_depth
             use_glu = (not use_gmlp) and c.ff_glu
             x = x + attn_cls(c, mesh=self.mesh, name=f"attn{i}")(
-                x, sin, cos, pos
+                x, sin, cos, rows
             )
             x = x + ff_cls(
                 c, glu=use_glu, spatial_gate=use_gmlp, name=f"ff{i}"
-            )(x, pos)
+            )(x, rows)
             x = nn.with_logical_constraint(x, ("batch", "seq_act", "embed_act"))
 
         if c.decode and not self.is_initializing():
-            pos_var.value = pos + 1
+            pos_var.value = pos + (
+                n if rows.live is None
+                else jnp.sum(rows.live.astype(jnp.int32))
+            )
 
         x = ScaleNorm(c.layer_norm_epsilon, c.compute_dtype, c.params_dtype)(x)
         logits = nn.Dense(

@@ -454,6 +454,64 @@ def _decode_setup(model, params, batch: int):
     return dec_model, params, init_fn()
 
 
+# Rows of one prefill block, at most: a prompt goes through the cache in
+# blocks of this many positions per pass over the weights. On a v5e a
+# pass is bound by reading the weights up to about 240 rows (197 TFLOP/s
+# over 819 GB/s, two bytes a weight). Chip readings on ProGen-large
+# (PERF.md, PR 27): a block costs 4.8 ms at 64 rows and 5.2 ms at 128, a
+# 16-token chunk 16.1 ms at 16, 32 and 64 rows and 16.3 ms at 128.
+_FEED_ROWS = 128
+
+
+def feed_width(config) -> int:
+    """The prefill block width for a model: the largest divisor of its
+    ``window_size`` that is at most ``_FEED_ROWS``. Dividing the window
+    is what lets a block write its keys before attending (no block
+    straddles a window boundary, see ``_decode_attend``)."""
+    w = config.window_size
+    return max(d for d in range(1, min(w, _FEED_ROWS) + 1) if w % d == 0)
+
+
+def feed_block_count(width: int, lo: int, hi: int) -> int:
+    """Blocks ``feed_tokens`` runs for positions ``[lo, hi)``: the
+    aligned blocks of ``width`` that the range touches (host arithmetic,
+    the same the loop bounds below do on the device)."""
+    return -(-hi // width) - lo // width if hi > lo else 0
+
+
+def feed_tokens(model, params, cache, tokens, lo, hi):
+    """Feed positions ``[lo, hi)`` of ``tokens`` ((B, L); the cache's
+    ``pos`` must stand at ``lo``) through a decode cache in blocks of
+    ``feed_width`` positions per ``model.apply``: one pass over the
+    weights per block, not per token. The one prime feed of every
+    cached decoder — the serving engine's monolithic and chunked
+    prefills and ``sample_fast`` / ``sample_fast_batched`` — which is
+    what keeps their streams token-identical.
+
+    Blocks are aligned to absolute positions, so a position sits in the
+    same row of the same block however the prompt is split into calls,
+    reads the same cache rows and reduces over them in the same order:
+    the cache after ``[0, hi)`` is bit-equal under every split. Rows of
+    a block outside ``[lo, hi)`` are dead (``ProGen.__call__``).
+    ``lo``/``hi`` are traced loop bounds, so ONE compiled program serves
+    every chunk size and resume depth."""
+    t = feed_width(model.config)
+    last = tokens.shape[-1] - 1
+
+    def feed(blk, cache):
+        # rows past the buffer's end are dead: any token will do there
+        at = jnp.minimum(blk * t + jnp.arange(t), last)
+        _, mut = model.apply(
+            {"params": params, "cache": cache}, tokens[:, at], hi,
+            mutable=["cache"],
+        )
+        return mut["cache"]
+
+    return jax.lax.fori_loop(
+        lo // t, jnp.where(hi > lo, -(-hi // t), lo // t), feed, cache
+    )
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("model", "length", "top_k", "parity"),
@@ -476,11 +534,7 @@ def _decode_incremental_batched(
         )
         return logits[:, 0], mut["cache"]  # (B, vocab)
 
-    def prefill(p, cache):
-        _, cache = feed(seqs, p, cache)
-        return cache
-
-    cache = jax.lax.fori_loop(0, start_pos - 1, prefill, cache)
+    cache = feed_tokens(model, params, cache, seqs, 0, start_pos - 1)
 
     draw = jax.vmap(
         lambda k, l: _gumbel_topk_step(

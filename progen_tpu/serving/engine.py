@@ -11,6 +11,15 @@ OSDI '22). All shapes are functions of (max_slots, max_len) only, so an
 engine's whole lifetime re-executes exactly two compiled programs:
 one prefill, one decode step.
 
+A prompt goes through a batch-1 cache in BLOCKS of positions
+(``sampling.feed_tokens``): one ``model.apply`` of ``prefill_width``
+rows — the largest divisor of the model's ``window_size`` up to 128 — is
+one pass over the weights, so a chunk of admission costs about the same
+whether it carries one token or a whole block. Blocks are aligned to
+absolute positions: however a prompt is split (chunk budget, prefix-cache
+resume depth, journal replay), every position is computed in the same
+row of the same block, and the primed cache is bit-equal.
+
 Per-slot positions without touching the model: decode mode keeps a
 single scalar ``pos`` cache counter (progen.py), which a batch-B cache
 shares across rows — useless when rows start and finish at different
@@ -48,6 +57,9 @@ from progen_tpu.sampling import (
     _prepare_seq,
     _validate_infill,
     _validate_knobs,
+    feed_block_count,
+    feed_tokens,
+    feed_width,
     gumbel_step_dynamic,
 )
 from progen_tpu.telemetry.spans import span as _span, stage as _stage
@@ -83,25 +95,6 @@ class SlotBatch(NamedTuple):
     live: jnp.ndarray  # (S,) bool slot is decoding
     template: jnp.ndarray  # (S, L) int32 infill template (all-0 = off)
     frozen: jnp.ndarray  # (S, L) bool infill frozen-position mask
-
-
-def _feed_tokens(model, params, cache, tokens, lo, hi):
-    """Feed ``tokens[lo:hi]`` through a batch-1 cache one position at a
-    time. ``lo``/``hi`` are traced fori_loop bounds, so ONE compiled
-    program serves every (chunk size, resume depth) — the property both
-    the monolithic prefill and the budgeted chunk program below rely on
-    to keep ``prefill_compile_count`` flat. Shared verbatim by both so a
-    chunked prefill is bit-identical to the monolithic one: the loop
-    body lowers to the same HLO either way."""
-
-    def feed(p, cache):
-        tok = jax.lax.dynamic_slice(tokens, (p,), (1,))[None]
-        _, mut = model.apply(
-            {"params": params, "cache": cache}, tok, mutable=["cache"]
-        )
-        return mut["cache"]
-
-    return jax.lax.fori_loop(lo, hi, feed, cache)
 
 
 def _scatter_slot(
@@ -177,12 +170,15 @@ def _prefill_impl(
     frozen,
 ):
     """Admit one request into ``slot``: run the prime through a FRESH
-    batch-1 cache (positions 0..start-2; a dynamic-bound fori_loop, so
-    one compile serves every prime length) and scatter the cache + all
+    batch-1 cache (positions 0..start-2 — the chunk program's block loop
+    with the whole prime as its one chunk; its bounds are traced, so one
+    compile serves every prime length) and scatter the cache + all
     per-slot state into the pool. ``slot``/``start``/``target`` are
     traced, keeping this a single compiled program. Un-jitted body shared
     by the bf16 and int8 entry points below."""
-    cache1 = _feed_tokens(model, params, fresh_cache, tokens, 0, start - 1)
+    cache1 = feed_tokens(
+        model, params, fresh_cache, tokens[None], 0, start - 1
+    )
     return _scatter_slot(slots, cache1, slot, tokens, start, target, key,
                          temp, top_p, top_k, parity, template, frozen)
 
@@ -222,15 +218,18 @@ def _prefill_q(model, q_params, scales, slots, fresh_cache, slot, tokens,
 @functools.partial(jax.jit, static_argnames=("model",))
 def _prefill_chunk(model, params, cache, tokens, lo, hi):
     """One budgeted slice of a chunked prefill: feed ``tokens[lo:hi]``
-    through an in-progress batch-1 cache. ``lo``/``hi`` are TRACED, so
-    one compiled program serves every chunk size and resume depth (a
-    prefix-cache hit resumes at an arbitrary ``lo``). The cache is
+    through an in-progress batch-1 cache, a block of positions per pass
+    over the weights (a chunk inside one aligned block is one pass,
+    whatever its token count). ``lo``/``hi`` are TRACED, so one
+    compiled program serves every chunk size and resume depth (a
+    prefix-cache hit resumes at an arbitrary ``lo``, mid-block: the rows
+    before it are dead). The cache is
     deliberately NOT donated: the first chunk feeds the engine's
     reusable ``fresh_cache`` zero template, and every chunk's input may
     be a live prefix-cache snapshot — donation would invalidate both.
     Batch-1 caches are small; the transient double-buffer is the price
     of snapshot reuse."""
-    return _feed_tokens(model, params, cache, tokens, lo, hi)
+    return feed_tokens(model, params, cache, tokens[None], lo, hi)
 
 
 @functools.partial(jax.jit, static_argnames=("model",))
@@ -239,7 +238,7 @@ def _prefill_chunk_q(model, q_params, scales, cache, tokens, lo, hi):
     params = dequantize_tree(
         q_params, scales, model.config.compute_dtype
     )
-    return _feed_tokens(model, params, cache, tokens, lo, hi)
+    return feed_tokens(model, params, cache, tokens[None], lo, hi)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -384,6 +383,7 @@ class PendingPrefill:
     cache: Any  # batch-1 cache tree fed through ``pos`` positions
     pos: int = 0
     hit_depth: int = 0  # prefix-cache seed depth (0 = cold)
+    blocks: int = 0  # feed blocks executed so far (``feed_block_count``)
     request_id: str = ""
     done: bool = False
 
@@ -416,6 +416,9 @@ class ServeEngine:
         self.model, self.params, self.fresh_cache = _decode_setup(
             model, params, batch=1
         )
+        # positions a prefill block holds: with ``feed_block_count`` the
+        # host's account of the passes over the weights a prefill made
+        self.prefill_width = feed_width(self.model.config)
         s, l = self.max_slots, self.max_len
         key0 = jax.random.PRNGKey(0)
         self.slots = SlotBatch(
@@ -695,6 +698,12 @@ class ServeEngine:
             self._targets[slot] = int(length)
             return int(start)
 
+    def prefill_blocks(self, lo: int, hi: int) -> int:
+        """Blocks a prefill of positions ``[lo, hi)`` executes (host
+        arithmetic): ``prefill_tokens / (prefill_blocks * prefill_width)``
+        is the share of computed prefill rows that were real."""
+        return feed_block_count(self.prefill_width, lo, hi)
+
     # ----- chunked admission ----------------------------------------------
 
     def begin_prefill(self, slot: int, prime, length: int, *,
@@ -769,6 +778,7 @@ class ServeEngine:
                             pending.row, jnp.int32(pending.pos),
                             jnp.int32(hi),
                         )
+                pending.blocks += self.prefill_blocks(pending.pos, int(hi))
                 pending.pos = int(hi)
                 if self._prefix_cache is not None:
                     with _stage("serve/prefix_insert"):

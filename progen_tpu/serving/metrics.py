@@ -16,7 +16,8 @@ time inside ``engine.advance_prefill`` — the chunk is dispatched and the
 call returns before the device has run it — so it, and
 ``prefill_tokens_per_s`` with it, says what admission costs the serving
 loop's thread, not how fast the device prefills (measured on a v5e,
-PERF.md: ≈ 0.5 ms a token of host time against 4.3 ms on the device).
+PERF.md, PR 27: ≈ 0.5 ms a token of host time against 1.0 ms on the
+device for a 16-token chunk, one block's pass over the weights).
 
 Latency lands in three reservoir-quantile families the scheduler
 observes: ``ttft_s`` (submit → first token), ``itl_s`` (inter-token
@@ -46,6 +47,12 @@ HELP = {
     "prefill_tokens_per_s": (
         "prefill_tokens over prefill_time_s: prime tokens per second of "
         "HOST time in admission, not the device's prefill rate"
+    ),
+    "prefill_blocks": (
+        "Prefill blocks executed: passes over the weights made to feed "
+        "prompts, each an aligned block of the engine's prefill_width "
+        "positions (128 at most, the largest divisor of window_size) of "
+        "which prefill_tokens / (prefill_blocks * width) were real"
     ),
     "xla_compile_count": (
         "XLA compile-or-load events of the whole process (jax.monitoring), "

@@ -569,6 +569,9 @@ class Scheduler:
                 self.metrics.inc("requests_admitted")
                 # start-1 prime tokens actually ran through the model
                 self.metrics.inc("prefill_tokens", max(start - 1, 0))
+                self.metrics.inc(
+                    "prefill_blocks", self.engine.prefill_blocks(0, start - 1)
+                )
                 self.metrics.add_time("prefill_time_s", t1 - t0)
             self.metrics.set_gauge("queue_depth", len(self._queue))
             self.metrics.set_gauge("active_slots", len(self._active))
@@ -595,6 +598,7 @@ class Scheduler:
         self.metrics.inc(
             "prefill_tokens", max(pp.start - 1 - pp.hit_depth, 0)
         )
+        self.metrics.inc("prefill_blocks", pp.blocks)
         if pp.hit_depth > 0:
             self.metrics.inc("prefix_cache_hit_tokens", pp.hit_depth)
         self.metrics.add_time("prefill_time_s", pa.prefill_s)

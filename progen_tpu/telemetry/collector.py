@@ -62,7 +62,7 @@ _ROLES = ("replica", "router", "run")
 _JSONL_COUNTERS = (
     "requests_submitted", "requests_completed", "requests_rejected",
     "requests_admitted", "requests_expired", "decode_steps",
-    "decode_tokens", "prefill_tokens", "tokens_forwarded",
+    "decode_tokens", "prefill_tokens", "prefill_blocks", "tokens_forwarded",
     "dispatched_total", "handoffs_total", "replica_down_total",
     "journal_replayed", "reloads", "reload_rejected", "retries",
     "sequences_scored", "tokens_scored",
