@@ -58,8 +58,7 @@ def main(checkpoint_path, input_path, split, context, out_dir, batch_size,
          metrics_every):
     from progen_tpu import telemetry
     from progen_tpu.checkpoint import get_checkpoint_fns
-    from progen_tpu.config import ProGenConfig
-    from progen_tpu.models.progen import ProGen
+    from progen_tpu.models import build_model, require_progen
     from progen_tpu.resilience.chaos import install_from_env
     from progen_tpu.telemetry import MetricsRegistry
     from progen_tpu.tracking import make_tracker
@@ -77,8 +76,7 @@ def main(checkpoint_path, input_path, split, context, out_dir, batch_size,
     pkg = get_last.restore_params()  # params only: no optimizer moments
     if pkg is None:
         sys.exit(f"no checkpoints found at {checkpoint_path}")
-    config = ProGenConfig.from_dict(pkg.model_config)
-    model = ProGen(config)
+    model = require_progen(build_model(pkg.model_config), "cli.batch_score")
 
     if os.path.isdir(input_path):
         records = tfrecord_records(input_path, split)

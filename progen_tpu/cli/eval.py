@@ -33,17 +33,16 @@ import jax
 @click.option("--batch_size", default=8)
 def main(checkpoint_path, data_path, split, batch_size):
     from progen_tpu.checkpoint import get_checkpoint_fns
-    from progen_tpu.config import ProGenConfig
     from progen_tpu.data.dataset import iterator_from_tfrecords_folder
-    from progen_tpu.models.progen import ProGen
+    from progen_tpu.models import build_model, require_progen
     from progen_tpu.training.loss import sequence_scores
 
     _, get_last, _ = get_checkpoint_fns(checkpoint_path)
     pkg = get_last.restore_params()  # params only: no optimizer moments
     if pkg is None:
         sys.exit(f"no checkpoints found at {checkpoint_path}")
-    config = ProGenConfig.from_dict(pkg.model_config)
-    model = ProGen(config)
+    model = require_progen(build_model(pkg.model_config), "cli.eval")
+    config = model.config
     params = pkg.state
 
     num_seqs, iter_fn = iterator_from_tfrecords_folder(data_path, split)

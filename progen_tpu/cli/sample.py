@@ -62,9 +62,8 @@ import jax
 def main(seed, checkpoint_path, prime, top_k, temperature, top_p,
          naive, num_samples, template, free_char):
     from progen_tpu.checkpoint import get_checkpoint_fns
-    from progen_tpu.config import ProGenConfig
     from progen_tpu.data.tokenizer import decode_tokens, encode_tokens
-    from progen_tpu.models.progen import ProGen
+    from progen_tpu.models import build_model, require_progen
     from progen_tpu.sampling import (
         sample,
         sample_batched,
@@ -78,8 +77,8 @@ def main(seed, checkpoint_path, prime, top_k, temperature, top_p,
     if pkg is None:
         sys.exit(f"no checkpoints found at {checkpoint_path}")
 
-    config = ProGenConfig.from_dict(pkg.model_config)
-    model = ProGen(config)
+    model = require_progen(build_model(pkg.model_config), "cli.sample")
+    config = model.config
     params = pkg.state
 
     num_params = sum(int(np.size(x)) for x in jax.tree.leaves(params))

@@ -56,8 +56,7 @@ def _parse_positions(spec, seq_len):
 def main(checkpoint_path, sequence, fasta, index, context, positions,
          top, chunk, out_path):
     from progen_tpu.checkpoint import get_checkpoint_fns
-    from progen_tpu.config import ProGenConfig
-    from progen_tpu.models.progen import ProGen
+    from progen_tpu.models import build_model, require_progen
     from progen_tpu.workloads import mutagenesis_scan
 
     if (sequence is None) == (fasta is None):
@@ -74,8 +73,7 @@ def main(checkpoint_path, sequence, fasta, index, context, positions,
     pkg = get_last.restore_params()  # params only: no optimizer moments
     if pkg is None:
         sys.exit(f"no checkpoints found at {checkpoint_path}")
-    config = ProGenConfig.from_dict(pkg.model_config)
-    model = ProGen(config)
+    model = require_progen(build_model(pkg.model_config), "cli.scan")
 
     report = mutagenesis_scan(
         model, pkg.state, sequence, context=context,

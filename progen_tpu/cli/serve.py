@@ -7,7 +7,10 @@ event loop. Requests arrive as JSON lines, one object per request:
      "temperature": 0.8, "top_p": 0.95, "top_k": 25, "seed": 7}
 
 (``id`` and ``prime`` required; everything else optional — ``length``
-defaults to --max-len.) The router's resume wire (serving/router.py)
+defaults to --max-len.) ``"tokens": [ids]`` may stand in place of
+``prime``: raw token ids, the only way in to a model family without a
+byte codec (``latent_moe``), which answers ids too — its token events
+carry no ``text`` and its done event ``tokens`` in place of ``text``. The router's resume wire (serving/router.py)
 uses three extra optional fields: ``prime_tokens`` (raw token ids,
 bypassing the tokenizer), ``key`` (explicit uint32 PRNG key pair) and
 ``add_bos`` (default true) — together they let a handed-off request
@@ -96,11 +99,16 @@ def _parse_request(line, defaults):
     except (ValueError, KeyError) as e:
         return None, f"bad request line: {e}"
     try:
-        if obj.get("prime_tokens") is not None:
-            # raw token ids: the router's resume wire (already-tokenized
-            # prefix of a handed-off request) — bypasses the tokenizer
-            prime = np.asarray(
-                [int(t) for t in obj["prime_tokens"]], dtype=np.int32
+        raw = obj.get("prime_tokens", obj.get("tokens"))
+        if raw is not None:
+            # raw token ids: a client of a family without a byte codec,
+            # or the router's resume wire (already-tokenized prefix of a
+            # handed-off request) — bypasses the tokenizer
+            prime = np.asarray([int(t) for t in raw], dtype=np.int32)
+        elif not defaults.get("byte_codec", True):
+            raise ValueError(
+                'this model has no byte codec: send "tokens": [ids] '
+                'in place of "prime" or "template"'
             )
         else:
             prime = np.asarray(
@@ -177,21 +185,30 @@ def _parse_request(line, defaults):
         )
 
 
-def _events_to_lines(events, completions, starts):
+def _as_text(tokens, defaults) -> dict:
+    """The field that carries generated tokens to the client: text, or
+    the ids themselves for a family without a byte codec
+    (``defaults["byte_codec"]``, set once by main())."""
+    from progen_tpu.data.tokenizer import decode_tokens
+
+    if defaults.get("byte_codec", True):
+        return {"text": decode_tokens(tokens)}
+    return {"tokens": [int(t) for t in tokens]}
+
+
+def _events_to_lines(events, completions, starts, defaults):
     """Engine step output -> protocol JSONL strings. ``starts`` maps
     request id -> primed positions, so done-events can report only the
     generated suffix as text (parity with sample.py's print)."""
-    from progen_tpu.data.tokenizer import decode_tokens
-
     lines = []
     for ev in events:
-        lines.append(json.dumps({
-            "event": "token",
-            "id": ev.request_id,
-            "token": int(ev.token),
-            "text": decode_tokens([ev.token]),
-            "index": int(ev.index),
-        }))
+        line = {"event": "token", "id": ev.request_id,
+                "token": int(ev.token)}
+        if defaults.get("byte_codec", True):
+            # without a codec, "token" has said it all
+            line.update(_as_text([ev.token], defaults))
+        line["index"] = int(ev.index)
+        lines.append(json.dumps(line))
     for c in completions:
         start = starts.pop(c.request_id, 0)
         if getattr(c, "embedding", None) is not None:
@@ -208,7 +225,7 @@ def _events_to_lines(events, completions, starts):
         lines.append(json.dumps({
             "event": "done",
             "id": c.request_id,
-            "text": decode_tokens(c.tokens[start:]),
+            **_as_text(c.tokens[start:], defaults),
             "n_generated": int(c.n_generated),
             "ttft_s": round(c.ttft_s, 6),
             "latency_s": round(c.latency_s, 6),
@@ -222,8 +239,7 @@ def _build(checkpoint_path, max_slots, max_len, max_queue,
     import os.path
 
     from progen_tpu.checkpoint import get_checkpoint_fns
-    from progen_tpu.config import ProGenConfig
-    from progen_tpu.models.progen import ProGen
+    from progen_tpu.models import build_model
     from progen_tpu.serving import PrefixCache, Scheduler, ServeEngine
 
     _, get_last, _ = get_checkpoint_fns(checkpoint_path)
@@ -244,8 +260,8 @@ def _build(checkpoint_path, max_slots, max_len, max_queue,
         pkg = get_last.restore_params()
     if pkg is None:
         sys.exit(f"no checkpoints found at {checkpoint_path}")
-    config = ProGenConfig.from_dict(pkg.model_config)
-    model = ProGen(config)
+    model = build_model(pkg.model_config)
+    config = model.config
     engine = ServeEngine(
         model, pkg.state, max_slots=max_slots,
         max_len=min(max_len or config.seq_len, config.seq_len),
@@ -412,6 +428,7 @@ def main(checkpoint_path, max_slots, max_queue, max_len, quantize_int8,
         pin=startup_pin,
     )
     defaults = {
+        "byte_codec": getattr(engine.model.config, "byte_codec", True),
         "length": engine.max_len, "top_k": top_k,
         "temperature": temperature, "top_p": top_p, "seed": seed,
     }
@@ -549,7 +566,6 @@ def main(checkpoint_path, max_slots, max_queue, max_len, quantize_int8,
     replayed_lines = []
     starts0 = {}
     if replay_dir:
-        from progen_tpu.data.tokenizer import decode_tokens
         from progen_tpu.serving import replay_into
 
         jpath = os.path.join(replay_dir, "journal.jsonl")
@@ -560,7 +576,7 @@ def main(checkpoint_path, max_slots, max_queue, max_len, quantize_int8,
             for f in summary["finished"]:
                 replayed_lines.append(json.dumps({
                     "event": "done", "id": f["id"],
-                    "text": decode_tokens(f["emitted"]),
+                    **_as_text(f["emitted"], defaults),
                     "n_generated": 0, "ttft_s": 0.0, "latency_s": 0.0,
                     "replayed": True,
                 }))
@@ -745,7 +761,7 @@ def _serve_stdio(sched, defaults, publish, metrics_every, shutdown,
                 starts[req.id] = len(req.prime) + (1 if req.add_bos else 0)
         if sched.has_work:
             events, comps = sched.step()
-            emit(_events_to_lines(events, comps, starts))
+            emit(_events_to_lines(events, comps, starts, defaults))
             steps += 1
             if metrics_every and steps % metrics_every == 0:
                 publish(steps)
@@ -886,14 +902,15 @@ def _serve_socket(sched, defaults, socket_path, publish, metrics_every,
                     if fd is None:
                         continue
                     ev.request_id = public
-                    send(fd, _events_to_lines([ev], [], starts))
+                    send(fd, _events_to_lines([ev], [], starts, defaults))
                 for c in comps:
                     fd, public = owners.pop(c.request_id, (None, None))
                     if fd is None:
                         continue
                     start = starts.pop(c.request_id, 0)
                     c.request_id = public
-                    send(fd, _events_to_lines([], [c], {public: start}))
+                    send(fd, _events_to_lines(
+                        [], [c], {public: start}, defaults))
                 steps += 1
                 if metrics_every and steps % metrics_every == 0:
                     publish(steps)
@@ -993,14 +1010,15 @@ def _serve_tcp(sched, defaults, hostport, publish, metrics_every,
                     if fd is None:
                         continue
                     ev.request_id = public
-                    send(fd, _events_to_lines([ev], [], starts))
+                    send(fd, _events_to_lines([ev], [], starts, defaults))
                 for c in comps:
                     fd, public = owners.pop(c.request_id, (None, None))
                     if fd is None:
                         continue
                     start = starts.pop(c.request_id, 0)
                     c.request_id = public
-                    send(fd, _events_to_lines([], [c], {public: start}))
+                    send(fd, _events_to_lines(
+                        [], [c], {public: start}, defaults))
                 steps += 1
                 if metrics_every and steps % metrics_every == 0:
                     publish(steps)

@@ -289,6 +289,12 @@ def main(
         model_kwargs = load_toml_config(str(toml_path))
     else:
         model_kwargs = last_meta.model_config
+    if model_kwargs.get("family", "progen") != "progen":
+        sys.exit(
+            f"cli.train trains the progen family only: "
+            f"{model_kwargs['family']!r} has expert layers, and "
+            f"training/step.py has no grouped backward for them"
+        )
     model_kwargs.setdefault("seq_len", seq_len)
     # reference semantics (train.py:53,106): full f32 unless --mixed_precision
     # opts into the fast dtype; an explicit TOML dtype wins when flag absent

@@ -439,15 +439,16 @@ class DeployController:
         """Score the probe FASTA on ``ckpt`` into its own output dir.
         Resumable: a controller killed mid-probe re-enters here and the
         scorer's shard dedupe skips everything durably scored."""
-        from progen_tpu.config import ProGenConfig
-        from progen_tpu.models.progen import ProGen
+        from progen_tpu.models import build_model, require_progen
         from progen_tpu.workloads import fasta_records, run_batch_score
 
         with span("deploy/probe", ckpt=ckpt):
             pkg = self._get_last.restore_params(at=ckpt)
             if pkg is None:
                 raise RuntimeError(f"checkpoint {ckpt} not restorable")
-            model = ProGen(ProGenConfig.from_dict(pkg.model_config))
+            model = require_progen(
+                build_model(pkg.model_config), "the deploy probe's scorer"
+            )
             out_dir = str(self.deploy_dir / "probes" / ckpt)
             run_batch_score(
                 model, pkg.state,

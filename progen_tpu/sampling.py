@@ -166,6 +166,64 @@ def gumbel_step_dynamic(key, logit, top_k, parity, temperature, top_p):
     return key, jnp.where(parity, pick_parity, pick_knobs)
 
 
+# Largest top_k whose threshold comes from a partial selection; beyond it
+# (and for every vocabulary no wider) the row is sorted whole. Either way
+# the threshold is the same element of the row, so the draw is too.
+_TOP_K_PARTIAL = 64
+
+
+def _kth_largest(x, kc):
+    """The ``kc``-th largest (zero-based, (S,)) of each row of x (S, V)."""
+
+    def by_sort():
+        return jnp.take_along_axis(-jnp.sort(-x, axis=-1), kc[:, None], 1)[:, 0]
+
+    if x.shape[-1] <= _TOP_K_PARTIAL:
+        return by_sort()
+
+    def by_top_k():
+        top, _ = jax.lax.top_k(x, _TOP_K_PARTIAL)
+        at = jnp.minimum(kc, _TOP_K_PARTIAL - 1)
+        return jnp.take_along_axis(top, at[:, None], 1)[:, 0]
+
+    return jax.lax.cond(jnp.all(kc < _TOP_K_PARTIAL), by_top_k, by_sort)
+
+
+def gumbel_step_slots(keys, logits, top_k, parity, temperature, top_p, live):
+    """``gumbel_step_dynamic`` for all slots of a pool at once, row for
+    row the same draw bit for bit (tests/test_sampling.py), arranged for a
+    vocabulary of 10^5: the top-k threshold is read off a partial
+    selection while every slot's k allows, and the knob branch — whose
+    nucleus needs the whole row sorted — runs only in a step where a live
+    slot asks for it. keys (S, ...), logits (S, V); the rest (S,)."""
+    split = jax.vmap(jax.random.split)(keys)
+    keys, subs = split[:, 0], split[:, 1]
+    v = logits.shape[-1]
+    noise = jax.vmap(lambda k: gumbel_noise(k, (v,)))(subs)
+    kc = jnp.clip(top_k, 1, v) - 1
+    k_off = (top_k <= 0)[:, None]
+
+    mask_p = (logits > _kth_largest(logits, kc)[:, None]) | k_off
+    pick_parity = jnp.argmax(
+        jnp.where(mask_p, logits, 0.0) + jnp.where(mask_p, noise, 0.0),
+        axis=-1,
+    )
+
+    def with_knobs():
+        lt = logits / temperature[:, None]
+        mask = jax.vmap(select_top_p)(lt, top_p) & (
+            (lt > _kth_largest(lt, kc)[:, None]) | k_off
+        )
+        pick = jnp.argmax(
+            jnp.where(mask, lt, jnp.finfo(lt.dtype).min) + noise, axis=-1
+        )
+        return jnp.where(parity, pick_parity, pick)
+
+    return keys, jax.lax.cond(
+        jnp.all(parity | ~live), lambda: pick_parity, with_knobs
+    )
+
+
 def _validate_infill(template, frozen, length, num_tokens):
     """Host-side checks for the fixed-position infilling mask pair
     (the constrained-sampling workload, progen_tpu/workloads/infill.py).
@@ -429,19 +487,19 @@ def sample_fast(
     return out[0]
 
 
-def _decode_setup(model, params, batch: int):
+def _decode_setup(model, params, batch: int, max_len=None):
     """(decode model, decode-layout params, fresh zeroed cache) for the
     KV-cache paths. The cache skeleton comes from a trace-cached jitted
     init (params creation inside init is dead-code-eliminated since only
     the cache collection is returned), replicated on the params' mesh —
-    see _cache_init_fn."""
-    from progen_tpu.models.progen import decode_model, unstack_params
+    see _cache_init_fn. ``max_len`` bounds the cache of a family whose
+    state grows with the sequence (``models.decode_model``)."""
+    from progen_tpu.models import decode_model, unstack_params
 
-    dec_model = decode_model(model)
-    if model.config.scan_layers:
-        # decode mode is always unrolled (per-layer caches); convert the
-        # scanned stacked layout
-        params = unstack_params(params, model.config)
+    dec_model = decode_model(model, max_len)
+    # decode mode is always unrolled (per-layer caches); a scanned stacked
+    # layout is converted, any other comes back as it is
+    params = unstack_params(params, model.config)
     param_leaf = next(
         (leaf for leaf in jax.tree.leaves(params) if isinstance(leaf, jax.Array)),
         None,
@@ -464,10 +522,14 @@ _FEED_ROWS = 128
 
 
 def feed_width(config) -> int:
-    """The prefill block width for a model: the largest divisor of its
+    """The prefill block width for a model: what its family states
+    (``config.feed_rows``), else the largest divisor of ProGen's
     ``window_size`` that is at most ``_FEED_ROWS``. Dividing the window
     is what lets a block write its keys before attending (no block
     straddles a window boundary, see ``_decode_attend``)."""
+    rows = getattr(config, "feed_rows", None)
+    if rows:  # a family without windows names its own block
+        return int(rows)
     w = config.window_size
     return max(d for d in range(1, min(w, _FEED_ROWS) + 1) if w % d == 0)
 
@@ -495,6 +557,10 @@ def feed_tokens(model, params, cache, tokens, lo, hi):
     a block outside ``[lo, hi)`` are dead (``ProGen.__call__``).
     ``lo``/``hi`` are traced loop bounds, so ONE compiled program serves
     every chunk size and resume depth."""
+    if getattr(model, "slot_batched", False):
+        # positions are an argument of that family's decode mode, not a
+        # counter in its cache: it runs the same aligned blocks itself
+        return model.feed_tokens(params, cache, tokens, lo, hi)
     t = feed_width(model.config)
     last = tokens.shape[-1] - 1
 

@@ -61,6 +61,7 @@ from progen_tpu.sampling import (
     feed_tokens,
     feed_width,
     gumbel_step_dynamic,
+    gumbel_step_slots,
 )
 from progen_tpu.telemetry.spans import span as _span, stage as _stage
 
@@ -266,6 +267,8 @@ def _decode_step_impl(model, params, slots: SlotBatch):
     int8 entry points below."""
     n_slots, length = slots.seqs.shape
     pos = jnp.clip(slots.cur, 0, length - 1)
+    if getattr(model, "slot_batched", False):
+        return _decode_step_batched(model, params, slots, pos)
     toks = jnp.take_along_axis(slots.seqs, pos[:, None], axis=1)[:, :, None]
 
     def one(cache, tok):
@@ -279,6 +282,37 @@ def _decode_step_impl(model, params, slots: SlotBatch):
         slots.keys, logits, slots.top_k, slots.parity, slots.temp,
         slots.top_p,
     )
+    return _write_sampled(slots, cache, logits, keys, sampled)
+
+
+def _decode_step_batched(model, params, slots: SlotBatch, pos):
+    """The decode step of a family whose layers must see every live slot
+    at once (routed experts: one grouped product reads each touched
+    expert once, where a vmapped batch-1 apply would gather each slot's
+    own): the slots are one batch with a position a row, the draw is the
+    pool-wide twin of the per-slot sampler, and the family's counts ride
+    behind the sampled tokens, in a read the host already makes (an
+    output of their own would be a fourth device-to-host read a step);
+    ``model.fold_counts`` names them on the host."""
+    toks = jnp.take_along_axis(slots.seqs, pos[:, None], axis=1)[:, 0]
+    logits, cache, counts = model.decode_slots(
+        params, slots.cache, toks, pos, slots.live
+    )
+    keys, sampled = gumbel_step_slots(
+        slots.keys, logits, slots.top_k, slots.parity, slots.temp,
+        slots.top_p, slots.live,
+    )
+    new, sampled, live, finished = _write_sampled(
+        slots, cache, logits, keys, sampled
+    )
+    return new, jnp.concatenate([sampled, counts.astype(sampled.dtype)]), \
+        live, finished
+
+
+def _write_sampled(slots: SlotBatch, cache, logits, keys, sampled):
+    """The part of a decode step every family shares: the infill rule,
+    the masked scatter-back, the stop rule."""
+    n_slots, length = slots.seqs.shape
     sampled = sampled.astype(slots.seqs.dtype)
     wpos = jnp.clip(slots.cur + 1, 0, length - 1)
     # infilling (mirrors sampling.py::_constrain so an infilled slot is
@@ -413,8 +447,17 @@ class ServeEngine:
                 f"got {self.max_len}"
             )
         self.max_slots = int(max_slots)
+        # a family whose decode step takes all slots as one batch
+        # (models/latent_moe.py); what it cannot do is refused by name
+        self.slot_batched = bool(getattr(model, "slot_batched", False))
+        if quantize_int8 and self.slot_batched:
+            raise ValueError(
+                f"{type(model).__name__} cannot be served in int8: "
+                f"ops/quant.py quantizes 2-D kernels per output channel "
+                f"and knows no stacked expert weights"
+            )
         self.model, self.params, self.fresh_cache = _decode_setup(
-            model, params, batch=1
+            model, params, batch=1, max_len=self.max_len
         )
         # positions a prefill block holds: with ``feed_block_count`` the
         # host's account of the passes over the weights a prefill made
@@ -443,6 +486,9 @@ class ServeEngine:
         )
         self._free = list(range(s))
         self._targets = [l] * s  # host mirror for collect()
+        # counts a family's decode step carries to the host behind its
+        # tokens, summed until the scheduler takes them (pop_counters)
+        self._counters: dict = {}
         self._embed_model = None  # lazily built by embed()
         self._prefix_cache = None  # optional PrefixCache (set_prefix_cache)
         self.quantize_int8 = bool(quantize_int8)
@@ -516,7 +562,7 @@ class ServeEngine:
         the engine serves int8. Touches NO engine
         state (safe off-thread while decode_step runs); the loop thread
         applies the result with ``commit_params`` between steps."""
-        from progen_tpu.models.progen import unstack_params
+        from progen_tpu.models import unstack_params
 
         params = unstack_params(raw_params, self.model.config)
         ref = jax.tree_util.tree_flatten_with_path(self.params)
@@ -570,6 +616,13 @@ class ServeEngine:
         by ``begin_prefill`` and fed at every chunk boundary by
         ``advance_prefill``; cleared on ``commit_params`` (snapshots are
         weight-dependent). The engine serves fine without one."""
+        if self.slot_batched:
+            raise ValueError(
+                f"{type(self.model).__name__} cannot take a prefix cache: "
+                f"a snapshot of its state is max_len latent rows a layer "
+                f"whatever the prefix's depth, and prefix_cache.py budgets "
+                f"whole cache trees"
+            )
         self._prefix_cache = cache
 
     @property
@@ -628,9 +681,11 @@ class ServeEngine:
                 f"top_k must be None or in [1, {self.model.config.num_tokens}]"
                 f", got {top_k}"
             )
-        _validate_infill(
-            template, frozen, length, self.model.config.num_tokens
-        )
+        vocab = self.model.config.num_tokens
+        _validate_infill(template, frozen, length, vocab)
+        ids = np.asarray(prime).reshape(-1)
+        if ids.size and not (0 <= int(ids.min()) and int(ids.max()) < vocab):
+            raise ValueError(f"prime token ids must be in [0, {vocab})")
         _prepare_seq(self.model, prime, length, add_bos)
 
     def _prepare_admission(self, prime, length, *, top_k, add_bos,
@@ -821,11 +876,32 @@ class ServeEngine:
                     self.model, self.params, self.slots
                 )
         with _stage("serve/decode_fetch"):
-            return (
-                np.asarray(sampled),
-                np.asarray(was_live),
-                np.asarray(finished),
-            )
+            sampled, was_live = np.asarray(sampled), np.asarray(was_live)
+            if self.slot_batched:
+                # the family's counts ride behind the tokens; it names
+                # and folds them itself
+                folded = self.model.fold_counts(
+                    sampled[self.max_slots:], int(was_live.sum())
+                )
+                for name, by in folded.items():
+                    self._counters[name] = self._counters.get(name, 0) + by
+                sampled = sampled[: self.max_slots]
+            return sampled, was_live, np.asarray(finished)
+
+    def pop_counters(self) -> dict:
+        """Counter increments gathered since the last call (empty for a
+        family that reports none); the scheduler adds them to its
+        ``ServingMetrics``."""
+        out, self._counters = self._counters, {}
+        return out
+
+    def state_bytes(self) -> dict:
+        """Gauges of the pool's resident state, by kind."""
+        if not self.slot_batched:
+            return {}
+        return {"latent_cache_bytes": sum(
+            leaf.nbytes for leaf in jax.tree.leaves(self.slots.cache)
+        )}
 
     def collect(self, slot: int) -> np.ndarray:
         """The finished request's (target,) token buffer with the
@@ -839,6 +915,13 @@ class ServeEngine:
 
     # ----- embeddings extraction ------------------------------------------
 
+    def check_embeddable(self) -> None:
+        if self.slot_batched:
+            raise ValueError(
+                f"{type(self.model).__name__} serves no embeddings: "
+                f"workloads/embeddings.py pools ProGen's final norm"
+            )
+
     def embed(self, prime, *, add_bos: bool = False) -> np.ndarray:
         """Final-norm mean-pooled representation of ``prime`` — the
         embeddings-extraction request type (workloads/embeddings.py).
@@ -851,6 +934,7 @@ class ServeEngine:
         SGU matrix admits nothing narrower). Returns (dim,) float32."""
         from progen_tpu.workloads.embeddings import bucket_length, embed_step
 
+        self.check_embeddable()
         prime = np.asarray(prime, np.int32).reshape(-1)
         if add_bos:
             prime = np.concatenate([np.zeros((1,), np.int32), prime])

@@ -54,6 +54,32 @@ HELP = {
         "positions (128 at most, the largest divisor of window_size) of "
         "which prefill_tokens / (prefill_blocks * width) were real"
     ),
+    "moe_expert_layer_steps": (
+        "Expert layers run by decode steps (steps x expert layers): the "
+        "divisor of moe_experts_touched, moe_max_load_rows and "
+        "moe_assignments"
+    ),
+    "moe_experts_touched": (
+        "Distinct routed experts whose weights a decode step read, summed "
+        "over expert layers and steps"
+    ),
+    "moe_max_load_rows": (
+        "Rows of the busiest expert, summed over expert layers and decode "
+        "steps"
+    ),
+    "moe_assignments": (
+        "Token-to-expert assignments computed by decode steps (live slots "
+        "x experts per token x expert layers); none is ever dropped"
+    ),
+    "moe_feed_expert_layer_blocks": (
+        "Expert layers run by prefill blocks (blocks x expert layers); "
+        "moe_feed_experts_touched and moe_feed_max_load_rows are summed "
+        "over them, reported with the decode step that follows"
+    ),
+    "latent_cache_bytes": (
+        "Bytes of the slot pool's latent attention cache (rows of "
+        "kv_lora_rank + qk_rope_head_dim values per layer and position)"
+    ),
     "xla_compile_count": (
         "XLA compile-or-load events of the whole process (jax.monitoring), "
         "those the decode/prefill jit-cache counts miss among them"

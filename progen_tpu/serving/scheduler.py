@@ -200,6 +200,8 @@ class Scheduler:
         # counter track / the stderr summary line
         self.metrics.set_gauge("slot_occupancy", 0)
         self.metrics.set_gauge("slots_free", self.engine.max_slots)
+        for name, nbytes in self.engine.state_bytes().items():
+            self.metrics.set_gauge(name, nbytes)
         self._publish_compile_gauges()
         self._publish_prefix_gauges()
 
@@ -344,6 +346,7 @@ class Scheduler:
                 if req.kind == "embed":
                     # embeds run one full forward, no decode slot: the only
                     # bound is the model's context window
+                    self.engine.check_embeddable()
                     n = len(np.asarray(req.prime).reshape(-1))
                     n += 1 if req.add_bos else 0
                     if not 1 <= n <= self.engine.model.config.seq_len:
@@ -700,6 +703,8 @@ class Scheduler:
                                           c.n_generated)
             self.metrics.inc("decode_steps")
             self.metrics.inc("decode_tokens", n_live)
+            for name, by in self.engine.pop_counters().items():
+                self.metrics.inc(name, by)
             self.metrics.add_time("decode_time_s", decode.dur)
             self.metrics.set_gauge("active_slots", len(self._active))
             # recompiles surface the step they happen, not at the next
