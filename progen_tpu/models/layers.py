@@ -15,10 +15,12 @@ scale-only (create_offset=False in the reference, progen.py:22).
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 
 from progen_tpu.config import ProGenConfig
@@ -45,6 +47,50 @@ class DecodeRows(NamedTuple):
     live: Optional[jnp.ndarray]  # (T,) bool, a contiguous run; None = all
 
 
+@functools.lru_cache(maxsize=None)
+def _update_at(axis: int):
+    """``dynamic_update_slice_in_dim`` along ``axis`` with a batching rule
+    of its own. The serving pool vmaps the one-token apply over its slots,
+    each with a start of its own; the plain update then becomes a scatter,
+    which the TPU compiler runs as a serial loop over the slots — a bounds
+    check, a row pick, a select and the update per slot, 74 such loops in
+    a decode step of ProGen-large, a third of its device time (PERF.md,
+    PR 29). One update per slot, written out, is the update alone."""
+
+    def plain(buf, new, start):
+        return jax.lax.dynamic_update_slice_in_dim(buf, new, start, axis=axis)
+
+    update = jax.custom_batching.custom_vmap(plain)
+
+    @update.def_vmap
+    def per_slot(axis_size, in_batched, buf, new, start):
+        buf, new, start = (
+            x if batched else jnp.broadcast_to(x, (axis_size,) + x.shape)
+            for x, batched in zip((buf, new, start), in_batched)
+        )
+        # lax primitives, bound directly: through ``jnp`` indexing and
+        # ``lax.dynamic_update_slice`` each of the slots x leaves updates
+        # (2,368 in a decode step of ProGen-large) pays their Python, which
+        # more than doubles the step's trace time. What is traced is what
+        # they would trace: a negative start wraps, per update — written
+        # as one vector operation before the loop, the same updates take
+        # 0.8 ms longer on a v5e (PERF.md, PR 29).
+        zero = np.zeros((), start.dtype)
+        size = np.asarray(buf.shape[axis + 1], start.dtype)
+        at = [zero] * buf.ndim
+        for s in range(axis_size):
+            i = jax.lax.index_in_dim(start, s, keepdims=False)
+            row = jax.lax.slice_in_dim(new, s, s + 1)
+            at[0] = np.asarray(s, start.dtype)
+            at[axis + 1] = jax.lax.select(
+                jax.lax.lt(i, zero), jax.lax.add(i, size), i
+            )
+            buf = jax.lax.dynamic_update_slice_p.bind(buf, row, *at)
+        return buf, True
+
+    return update
+
+
 def _write_rows(buf, new, start, axis, rows: DecodeRows):
     """Write the block's T rows into ``buf`` at ``start`` along ``axis``,
     keeping what the buffer holds wherever a row is not live."""
@@ -54,7 +100,7 @@ def _write_rows(buf, new, start, axis, rows: DecodeRows):
         shape[axis] = t
         old = jax.lax.dynamic_slice_in_dim(buf, start, t, axis=axis)
         new = jnp.where(rows.live.reshape(shape), new, old)
-    return jax.lax.dynamic_update_slice_in_dim(buf, new, start, axis=axis)
+    return _update_at(axis % buf.ndim)(buf, new, start)
 
 
 def _cached_shift(module: nn.Module, x: jnp.ndarray,

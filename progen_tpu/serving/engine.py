@@ -63,6 +63,7 @@ from progen_tpu.sampling import (
     gumbel_step_dynamic,
     gumbel_step_slots,
 )
+from progen_tpu.serving.served_tree import promoted_mask, serve_tree
 from progen_tpu.telemetry.spans import span as _span, stage as _stage
 
 logger = logging.getLogger(__name__)
@@ -77,6 +78,7 @@ class PreparedParams(NamedTuple):
     q_params: Optional[dict]
     q_scales: Optional[dict]
     quant_report: Optional[dict]
+    served: Optional[dict] = None  # ``params`` as the programs take them
 
 
 class SlotBatch(NamedTuple):
@@ -433,7 +435,9 @@ class PendingPrefill:
 class ServeEngine:
     """Fixed-pool continuous-batching engine bound to one (model, params,
     max_slots, max_len). Host-side it is just a free-list and two jitted
-    calls; all decode state lives on the device in ``self.slots``."""
+    calls; all decode state lives on the device in ``self.slots``.
+    ``params`` is the tree as handed in; the programs take
+    ``served_params`` (see ``__init__``)."""
 
     def __init__(self, model, params, *, max_slots: int = 8,
                  max_len: Optional[int] = None,
@@ -492,6 +496,14 @@ class ServeEngine:
         self._embed_model = None  # lazily built by embed()
         self._prefix_cache = None  # optional PrefixCache (set_prefix_cache)
         self.quantize_int8 = bool(quantize_int8)
+        # ``params`` stays the tree as handed in (the checkpoint's type:
+        # embed(), int8 quantization and a reload's dtype check read it);
+        # the decode step and the prefills take the SERVED tree, in which
+        # every leaf they would convert to the compute type at each of its
+        # uses is held converted, and every other leaf is the raw array.
+        # The int8 programs dequantize their own weights and take neither.
+        self._cast = self._cast_mask()
+        self._served = None  # built on demand, see ``served_params``
         self.quant_report = None
         self._q_params = self._q_scales = None
         if self.quantize_int8:
@@ -501,6 +513,31 @@ class ServeEngine:
             self.quant_report = self._calibrate(
                 leaves, self.params, self._q_params, self._q_scales
             )
+
+    def _cast_mask(self) -> list:
+        """Per leaf of ``params``: does the model convert it to the compute
+        type at every use? (Flax's rule; tests/test_served_tree.py holds
+        it to a trace of the programs this engine runs.)"""
+        if self.quantize_int8:
+            return [False] * len(jax.tree.leaves(self.params))
+        return promoted_mask(self.params, self.model.config.compute_dtype)
+
+    def _serve(self, params):
+        return serve_tree(
+            params, self._cast, self.model.config.compute_dtype
+        )
+
+    @property
+    def served_params(self):
+        """The tree the programs take. It lives while the engine holds a
+        slot: ``release`` of the last one drops it (an idle engine holds
+        the raw tree alone and leaves the rest of the device to whoever
+        reads ``params`` — an embedding, a check against a reference, a
+        reload's candidate) and the next admission builds it again, one
+        conversion of the cast leaves."""
+        if self._served is None:
+            self._served = self._serve(self.params)
+        return self._served
 
     def _calibrate(self, leaves: list, params, q_params, q_scales) -> dict:
         """The logged accuracy contract of the int8 path: per-leaf weight
@@ -558,7 +595,9 @@ class ServeEngine:
         zero-downtime contract; anything else raises ValueError and
         needs a restart, not a reload. Leaf placement is matched to the
         live tree (see ``_match_placement``) so the swap cannot change
-        the jit cache key. Re-runs int8 quantization + calibration when
+        the jit cache key. Builds the candidate's served tree (the one
+        conversion of a reload, here and not on the loop thread) and
+        re-runs int8 quantization + calibration when
         the engine serves int8. Touches NO engine
         state (safe off-thread while decode_step runs); the loop thread
         applies the result with ``commit_params`` between steps."""
@@ -587,7 +626,9 @@ class ServeEngine:
         if self.quantize_int8:
             q_params, q_scales, leaves = quantize_tree(params)
             report = self._calibrate(leaves, params, q_params, q_scales)
-        return PreparedParams(params, q_params, q_scales, report)
+        return PreparedParams(
+            params, q_params, q_scales, report, self._serve(params)
+        )
 
     def commit_params(self, prepared: PreparedParams) -> None:
         """Foreground half: rebind the served weights. The jitted
@@ -598,6 +639,7 @@ class ServeEngine:
         continue on their existing KV caches; only future matmuls see
         the new weights."""
         self.params = prepared.params
+        self._served = prepared.served
         if self.quantize_int8:
             self._q_params = prepared.q_params
             self._q_scales = prepared.q_scales
@@ -658,6 +700,8 @@ class ServeEngine:
                 live=self.slots.live.at[slot].set(False)
             )
         self._free.append(slot)
+        if not self.any_live:
+            self._served = None  # idle: see ``served_params``
 
     # ----- request admission ---------------------------------------------
 
@@ -747,8 +791,8 @@ class ServeEngine:
                 )
             else:
                 self.slots = _prefill(
-                    self.model, self.params, self.slots, self.fresh_cache,
-                    *tail,
+                    self.model, self.served_params, self.slots,
+                    self.fresh_cache, *tail,
                 )
             self._targets[slot] = int(length)
             return int(start)
@@ -829,7 +873,7 @@ class ServeEngine:
                         )
                     else:
                         pending.cache = _prefill_chunk(
-                            self.model, self.params, pending.cache,
+                            self.model, self.served_params, pending.cache,
                             pending.row, jnp.int32(pending.pos),
                             jnp.int32(hi),
                         )
@@ -873,7 +917,7 @@ class ServeEngine:
                 )
             else:
                 self.slots, sampled, was_live, finished = _decode_step(
-                    self.model, self.params, self.slots
+                    self.model, self.served_params, self.slots
                 )
         with _stage("serve/decode_fetch"):
             sampled, was_live = np.asarray(sampled), np.asarray(was_live)
@@ -896,12 +940,25 @@ class ServeEngine:
         return out
 
     def state_bytes(self) -> dict:
-        """Gauges of the pool's resident state, by kind."""
-        if not self.slot_batched:
-            return {}
-        return {"latent_cache_bytes": sum(
-            leaf.nbytes for leaf in jax.tree.leaves(self.slots.cache)
-        )}
+        """Gauges of the engine's state, by kind. The served tree shares
+        every leaf that is not cast with the raw one, so while it lives
+        the device holds ``raw_weight_bytes`` plus the cast leaves' served
+        bytes (counted from shapes: an idle engine has dropped it)."""
+        itemsize = jnp.dtype(self.model.config.compute_dtype).itemsize
+        leaves = jax.tree.leaves(self.params)
+        out = {
+            "raw_weight_bytes": sum(leaf.nbytes for leaf in leaves),
+            "served_weight_bytes": sum(
+                leaf.size * itemsize if cast else leaf.nbytes
+                for leaf, cast in zip(leaves, self._cast)
+            ),
+            "served_leaves_cast": sum(self._cast),
+        }
+        if self.slot_batched:
+            out["latent_cache_bytes"] = sum(
+                leaf.nbytes for leaf in jax.tree.leaves(self.slots.cache)
+            )
+        return out
 
     def collect(self, slot: int) -> np.ndarray:
         """The finished request's (target,) token buffer with the

@@ -80,6 +80,21 @@ HELP = {
         "Bytes of the slot pool's latent attention cache (rows of "
         "kv_lora_rank + qk_rope_head_dim values per layer and position)"
     ),
+    "raw_weight_bytes": (
+        "Bytes of the parameter tree as handed to the engine, in the "
+        "checkpoint's type (embeddings, int8 quantization and a reload's "
+        "dtype check read it)"
+    ),
+    "served_weight_bytes": (
+        "Bytes of the tree the decode step and the prefills take: every "
+        "leaf they would convert to the compute type at each use held "
+        "converted, every other leaf shared with the raw tree"
+    ),
+    "served_leaves_cast": (
+        "Leaves of the served tree held converted (0: the served tree is "
+        "the raw tree; else, while the engine holds a slot, the cast "
+        "leaves' served bytes are resident on top of raw_weight_bytes)"
+    ),
     "xla_compile_count": (
         "XLA compile-or-load events of the whole process (jax.monitoring), "
         "those the decode/prefill jit-cache counts miss among them"

@@ -326,14 +326,17 @@ def test_cli_serve_takes_and_answers_token_ids(tmp_path):
 # ----- ProGen's served programs lower as before ----------------------------
 
 # sha256 of the StableHLO text of ProGen's decode step and prefill chunk at
-# a small size, taken at the commit before this family was added (jax
-# 0.9.0). They move with any change to what ProGen's served path traces:
-# such a change has to say so, and take new values from a tree without it.
+# a small size (jax 0.9.0). They move with any change to what ProGen's
+# served path traces: such a change has to say so, and take new values from
+# a tree without it. Taken at the commit before this family was added, and
+# again in PR 29, which changed what the cache write traces (one update per
+# slot in place of a scatter, ``layers._update_at``) and nothing else here:
+# the programs are lowered with ``engine.params``, the raw tree.
 PROGEN_PROGRAMS = {
-    ("unrolled", "decode"): "0c56650525efc51fefee3df85d0f119306bd4d1a3ae9aa1eaf928b11128c2c4f",
-    ("unrolled", "chunk"): "3ac8504939d115895b9f200e376024cbe2f3bbdcca7f5a6d96b65bc4877cb264",
-    ("scanned", "decode"): "b8ade0b5208f806989d88810858f91dde888f16475df9a82dba32409dadf8741",
-    ("scanned", "chunk"): "5dc6d031062fa4a3abd4cd99bd5c56f44744fc3f71feea46a126ef5dff448ad6",
+    ("unrolled", "decode"): "2a417fde763406947fa7303fafc43240af15b26f9167e8616cfffe7bb4de2cbd",
+    ("unrolled", "chunk"): "3f9a16359420a99c55fe64d735f3f5d068a7151ae04be81ebad4b611002c95fe",
+    ("scanned", "decode"): "ad668887b715d9a6bea1a18afae30311ad075662fb00cb4645e39805f2636df7",
+    ("scanned", "chunk"): "798d0341789dffbda8897a5b987eaaf40b1c66c4831dee1b59be5a1f9d1df6ab",
 }
 
 
