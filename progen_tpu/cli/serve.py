@@ -303,8 +303,9 @@ def _build(checkpoint_path, max_slots, max_len, max_queue,
 @click.option("--prefill_chunk", default=0,
               help="admit long prompts N prime tokens per decode step "
                    "(chunked prefill) instead of stalling every live "
-                   "decode for the whole prompt; 0 = monolithic "
-                   "admission. Streams are bit-identical either way")
+                   "decode for the whole prompt; 0 = no budget, the "
+                   "whole prime as one chunk. Streams are bit-identical "
+                   "either way")
 @click.option("--prefix_cache_mb", default=0,
               help="LRU cache of prefill-state snapshots keyed on the "
                    "token-prefix hash, in MiB of device cache bytes "
@@ -396,7 +397,7 @@ def main(checkpoint_path, max_slots, max_queue, max_len, quantize_int8,
     )
     from progen_tpu.tracking import make_tracker
 
-    # serving chaos sites (serve/prefill, serve/decode, serve/reload*)
+    # serving chaos sites (serve/prefill_chunk, serve/decode, serve/reload*)
     # arm from the environment, same as cli/train.py — the serve
     # kill-matrix drives this process via PROGEN_CHAOS alone
     install_from_env()
@@ -434,7 +435,7 @@ def main(checkpoint_path, max_slots, max_queue, max_len, quantize_int8,
     }
     tracker = make_tracker("progen-serve")
     # per-request async tracing: the scheduler's req/slots records and
-    # the engine's serve/prefill spans land in the tracker's
+    # the engine's serve/prefill_chunk spans land in the tracker's
     # events.jsonl — `progen-tpu-telemetry export-trace` renders each
     # accepted request as one async track (queued → prefill → decode)
     telemetry.configure(sink=tracker.log_event)

@@ -25,8 +25,18 @@ bench.py's ``_suspect_fields``.
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
+
+
+class QuantizedParams(NamedTuple):
+    """``quantize_tree``'s tree and scales as one pytree: what an int8
+    engine hands the serving programs in place of a float tree."""
+
+    q: dict  # the params tree, matmul kernels int8
+    scales: dict  # keystr(path) -> (out,) f32
 
 
 def _is_matmul_kernel(path, leaf) -> bool:
@@ -98,3 +108,12 @@ def dequantize_tree(q_params, scales, dtype):
         return leaf
 
     return jax.tree_util.tree_map_with_path(visit, q_params)
+
+
+def dequantized(params, dtype):
+    """The float tree a serving program computes with: ``params`` as it
+    is, or a ``QuantizedParams`` dequantized to ``dtype``. Decided while
+    tracing, from the type handed in: one program serves both."""
+    if isinstance(params, QuantizedParams):
+        return dequantize_tree(params.q, params.scales, dtype)
+    return params

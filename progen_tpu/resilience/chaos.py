@@ -32,13 +32,17 @@ direct ``maybe_inject`` call sites on span-free hot paths. With
 
 Serving targets (the serve kill-matrix, tests/test_serve_kill_matrix):
 
-  * ``serve/prefill``        — span entry when a request is admitted
-                               (kill here = die mid-prefill);
-  * ``serve/prefill_chunk``  — span entry of each budgeted chunk of a
-                               chunked admission (``kill@N`` = die
-                               mid-chunk with the slot acquired but
-                               never activated; replay must re-run the
-                               whole prefill exactly once);
+  * ``serve/prefill``        — span entry of ``ServeEngine.prefill``,
+                               the one-call admission of direct
+                               callers; the scheduler (so ``cli.serve``)
+                               never passes it;
+  * ``serve/prefill_chunk``  — span entry of each chunk of an admission
+                               — every admission the scheduler makes,
+                               the whole prime when unbudgeted
+                               (``kill@N`` = die mid-chunk with the
+                               slot acquired but never activated;
+                               replay must re-run the whole prefill
+                               exactly once);
   * ``serve/decode``         — called by the scheduler once per decode
                                step, before the engine advances
                                (``kill@N`` = die after N-1 full steps);

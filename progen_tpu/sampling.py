@@ -546,8 +546,8 @@ def feed_tokens(model, params, cache, tokens, lo, hi):
     ``pos`` must stand at ``lo``) through a decode cache in blocks of
     ``feed_width`` positions per ``model.apply``: one pass over the
     weights per block, not per token. The one prime feed of every
-    cached decoder — the serving engine's monolithic and chunked
-    prefills and ``sample_fast`` / ``sample_fast_batched`` — which is
+    cached decoder — the serving engine's chunk program
+    and ``sample_fast`` / ``sample_fast_batched`` — which is
     what keeps their streams token-identical.
 
     Blocks are aligned to absolute positions, so a position sits in the

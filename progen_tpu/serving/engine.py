@@ -8,8 +8,15 @@ device (the slot-pool idea of vLLM/PagedAttention, SOSP '23, at
 granularity one-slot-one-request) and advances EVERY live lane one token
 per ``decode_step`` call (the iteration-level scheduling of Orca,
 OSDI '22). All shapes are functions of (max_slots, max_len) only, so an
-engine's whole lifetime re-executes exactly two compiled programs:
-one prefill, one decode step.
+engine's whole lifetime re-executes exactly three compiled programs:
+``_prefill_chunk`` (a slice of a prompt through a batch-1 cache — the
+whole prompt when admission is unbudgeted), ``_prefill_finish`` (that
+cache and the request's state into the pool) and ``_decode_step``. The
+two that read weights take ``ServeEngine.served_params``: for a float
+engine the tree with every leaf the model would convert at each use held
+in the compute type, for an int8 engine the quantized pair
+(``ops.quant.QuantizedParams``), which the programs dequantize on the
+device. One set of programs, whatever the engine was handed.
 
 A prompt goes through a batch-1 cache in BLOCKS of positions
 (``sampling.feed_tokens``): one ``model.apply`` of ``prefill_width``
@@ -28,7 +35,7 @@ a leading slot axis and the decode step ``vmap``s the one-token apply
 over it, so every slot carries its own scalar ``pos`` (and its own ring
 indices, shift states, and gate history). Dead slots keep computing —
 static shapes are the point — on garbage caches; that is safe because
-``prefill`` rewrites the slot's entire cache tree from a fresh zeroed
+an admission rewrites the slot's entire cache tree from a fresh zeroed
 template (NOT by zeroing in place: ``slot_pos`` initialises to -1)
 before the slot is ever read again.
 
@@ -50,7 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from progen_tpu.ops.quant import dequantize_tree, quantize_tree
+from progen_tpu.ops.quant import QuantizedParams, dequantized, quantize_tree
 from progen_tpu.sampling import (
     _TOP_P_OFF,
     _decode_setup,
@@ -75,10 +82,8 @@ class PreparedParams(NamedTuple):
     ``commit_params`` (loop thread, between decode steps)."""
 
     params: dict
-    q_params: Optional[dict]
-    q_scales: Optional[dict]
-    quant_report: Optional[dict]
-    served: Optional[dict] = None  # ``params`` as the programs take them
+    quant_report: Optional[dict] = None  # int8 engines: the calibration
+    served: Any = None  # ``params`` as the programs take them
 
 
 class SlotBatch(NamedTuple):
@@ -117,8 +122,7 @@ def _scatter_slot(
 ):
     """Scatter a fully primed batch-1 cache + all per-slot state into
     the pool and mark ``slot`` live. Pure data movement (no model
-    arithmetic), shared by the monolithic prefill and the chunked
-    finish program so activation is identical on both paths."""
+    arithmetic): the body of ``_prefill_finish``."""
     length = slots.seqs.shape[1]
     cache = jax.tree.map(
         lambda pool, c: jax.lax.dynamic_update_index_in_dim(
@@ -155,105 +159,39 @@ def _scatter_slot(
     )
 
 
-def _prefill_impl(
-    model,
-    params,
-    slots: SlotBatch,
-    fresh_cache,
-    slot,
-    tokens,
-    start,
-    target,
-    key,
-    temp,
-    top_p,
-    top_k,
-    parity,
-    template,
-    frozen,
-):
-    """Admit one request into ``slot``: run the prime through a FRESH
-    batch-1 cache (positions 0..start-2 — the chunk program's block loop
-    with the whole prime as its one chunk; its bounds are traced, so one
-    compile serves every prime length) and scatter the cache + all
-    per-slot state into the pool. ``slot``/``start``/``target`` are
-    traced, keeping this a single compiled program. Un-jitted body shared
-    by the bf16 and int8 entry points below."""
-    cache1 = feed_tokens(
-        model, params, fresh_cache, tokens[None], 0, start - 1
-    )
-    return _scatter_slot(slots, cache1, slot, tokens, start, target, key,
-                         temp, top_p, top_k, parity, template, frozen)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("model",), donate_argnums=(2,)
-)
-def _prefill(model, params, slots, fresh_cache, slot, tokens, start,
-             target, key, temp, top_p, top_k, parity, template, frozen):
-    """Jitted bf16/f32 prefill. The pool (``slots``, arg 2) is DONATED:
-    every leaf is rebuilt each call and the caller immediately rebinds
-    ``self.slots`` to the result, so the old buffers alias the new ones
-    instead of doubling the pool's HBM footprint. ``fresh_cache`` is NOT
-    donated — it is the reusable zero template."""
-    return _prefill_impl(model, params, slots, fresh_cache, slot, tokens,
-                         start, target, key, temp, top_p, top_k, parity,
-                         template, frozen)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("model",), donate_argnums=(3,)
-)
-def _prefill_q(model, q_params, scales, slots, fresh_cache, slot, tokens,
-               start, target, key, temp, top_p, top_k, parity, template,
-               frozen):
-    """Int8 prefill: dequantize the per-channel int8 kernels on-device
-    (XLA fuses convert+scale into each consuming matmul) and delegate.
-    ``slots`` is arg 3 here, donated for the same reason as _prefill."""
-    params = dequantize_tree(
-        q_params, scales, model.config.compute_dtype
-    )
-    return _prefill_impl(model, params, slots, fresh_cache, slot, tokens,
-                         start, target, key, temp, top_p, top_k, parity,
-                         template, frozen)
-
-
 @functools.partial(jax.jit, static_argnames=("model",))
 def _prefill_chunk(model, params, cache, tokens, lo, hi):
-    """One budgeted slice of a chunked prefill: feed ``tokens[lo:hi]``
-    through an in-progress batch-1 cache, a block of positions per pass
-    over the weights (a chunk inside one aligned block is one pass,
-    whatever its token count). ``lo``/``hi`` are TRACED, so one
-    compiled program serves every chunk size and resume depth (a
-    prefix-cache hit resumes at an arbitrary ``lo``, mid-block: the rows
-    before it are dead). The cache is
+    """One budgeted slice of a prefill: feed ``tokens[lo:hi]`` through an
+    in-progress batch-1 cache, a block of positions per pass over the
+    weights (a chunk inside one aligned block is one pass, whatever its
+    token count; an unbudgeted admission is the whole prime as its one
+    chunk). ``lo``/``hi`` are TRACED, so one compiled program serves
+    every chunk size and resume depth (a prefix-cache hit resumes at an
+    arbitrary ``lo``, mid-block: the rows before it are dead).
+    ``params`` is ``ServeEngine.served_params``: a float tree, or the
+    quantized pair of an int8 engine, dequantized here (XLA fuses
+    convert+scale into each consuming matmul). The cache is
     deliberately NOT donated: the first chunk feeds the engine's
     reusable ``fresh_cache`` zero template, and every chunk's input may
     be a live prefix-cache snapshot — donation would invalidate both.
     Batch-1 caches are small; the transient double-buffer is the price
     of snapshot reuse."""
-    return feed_tokens(model, params, cache, tokens[None], lo, hi)
-
-
-@functools.partial(jax.jit, static_argnames=("model",))
-def _prefill_chunk_q(model, q_params, scales, cache, tokens, lo, hi):
-    """Int8 chunk: dequantize on-device, then the shared feed loop."""
-    params = dequantize_tree(
-        q_params, scales, model.config.compute_dtype
-    )
+    params = dequantized(params, model.config.compute_dtype)
     return feed_tokens(model, params, cache, tokens[None], lo, hi)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _prefill_finish(slots, cache1, slot, tokens, start, target, key,
                     temp, top_p, top_k, parity, template, frozen):
-    """Final step of a chunked prefill: scatter the fully primed cache
-    + per-slot state into the pool (the ONLY point a chunked admission
-    touches the pool — mid-chunk state lives outside it, so decode
-    steps between chunks never see a half-primed slot). ``slots`` is
-    donated exactly like ``_prefill``'s pool arg; ``cache1`` is not (it
-    may be a prefix-cache snapshot). No model arithmetic, so one
-    program serves bf16 and int8 engines alike."""
+    """Final step of a prefill: scatter the fully primed cache +
+    per-slot state into the pool (the ONLY point an admission touches
+    the pool — mid-chunk state lives outside it, so decode steps
+    between chunks never see a half-primed slot). The pool (``slots``)
+    is DONATED: every leaf is rebuilt and the caller immediately rebinds
+    ``self.slots`` to the result, so the old buffers alias the new ones
+    instead of doubling the pool's HBM footprint; ``cache1`` is not (it
+    may be a prefix-cache snapshot). No model arithmetic and no
+    weights."""
     return _scatter_slot(slots, cache1, slot, tokens, start, target, key,
                          temp, top_p, top_k, parity, template, frozen)
 
@@ -265,8 +203,8 @@ def _decode_step_impl(model, params, slots: SlotBatch):
     static-shape program, and exactly what keeps a TPU from recompiling
     as traffic churns. Returns (new_slots, sampled, was_live, finished);
     ``finished`` flags slots that JUST hit EOS (second zero) or their
-    requested length this step. Un-jitted body shared by the bf16 and
-    int8 entry points below."""
+    requested length this step. Un-jitted body of ``_decode_step``
+    (tests/test_served_tree.py traces it on its own)."""
     n_slots, length = slots.seqs.shape
     pos = jnp.clip(slots.cur, 0, length - 1)
     if getattr(model, "slot_batched", False):
@@ -356,24 +294,15 @@ def _write_sampled(slots: SlotBatch, cache, logits, keys, sampled):
     jax.jit, static_argnames=("model",), donate_argnums=(2,)
 )
 def _decode_step(model, params, slots):
-    """Jitted bf16/f32 decode step. ``slots`` (arg 2) is DONATED — the
-    hot-loop fix the PGL003 audit asked for: every decode step rebuilds
-    the full pool (cache + per-slot state) and the caller rebinds
-    ``self.slots``, so without donation the engine held two copies of
-    the (max_slots, 2w) K/V pool across every step."""
-    return _decode_step_impl(model, params, slots)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("model",), donate_argnums=(3,)
-)
-def _decode_step_q(model, q_params, scales, slots):
-    """Int8 decode step: per-channel dequant fused into the matmuls,
-    then the shared body. ``slots`` is arg 3, donated as above; the int8
-    weights themselves are never donated (read every step)."""
-    params = dequantize_tree(
-        q_params, scales, model.config.compute_dtype
-    )
+    """Jitted decode step. ``params`` is ``ServeEngine.served_params``
+    (an int8 engine's quantized pair is dequantized here, per channel,
+    fused into the matmuls; never donated — read every step). ``slots``
+    (arg 2) is DONATED — the hot-loop fix the PGL003 audit asked for:
+    every decode step rebuilds the full pool (cache + per-slot state)
+    and the caller rebinds ``self.slots``, so without donation the
+    engine held two copies of the (max_slots, 2w) K/V pool across every
+    step."""
+    params = dequantized(params, model.config.compute_dtype)
     return _decode_step_impl(model, params, slots)
 
 
@@ -395,7 +324,7 @@ def _match_placement(new, live):
 
 @dataclasses.dataclass
 class PendingPrefill:
-    """Host-side state of an in-progress chunked admission — everything
+    """Host-side state of an admission in progress — everything
     ``_prefill_finish`` will need, plus the batch-1 cache being fed.
     Lives OUTSIDE the pool until the final chunk: decode steps taken
     between chunks never observe a half-primed slot, and a crash
@@ -403,7 +332,7 @@ class PendingPrefill:
     replay re-runs the prefill from scratch or a prefix-cache hit).
     ``pos`` counts prime positions already fed (the feed region is
     ``0..start-2``; the last prime token is consumed by the first
-    decode step, exactly as in the monolithic program)."""
+    decode step)."""
 
     slot: int
     row: jnp.ndarray  # (max_len,) int32 padded token buffer
@@ -434,8 +363,8 @@ class PendingPrefill:
 
 class ServeEngine:
     """Fixed-pool continuous-batching engine bound to one (model, params,
-    max_slots, max_len). Host-side it is just a free-list and two jitted
-    calls; all decode state lives on the device in ``self.slots``.
+    max_slots, max_len). Host-side it is just a free-list and three
+    jitted calls; all decode state lives on the device in ``self.slots``.
     ``params`` is the tree as handed in; the programs take
     ``served_params`` (see ``__init__``)."""
 
@@ -498,48 +427,48 @@ class ServeEngine:
         self.quantize_int8 = bool(quantize_int8)
         # ``params`` stays the tree as handed in (the checkpoint's type:
         # embed(), int8 quantization and a reload's dtype check read it);
-        # the decode step and the prefills take the SERVED tree, in which
-        # every leaf they would convert to the compute type at each of its
-        # uses is held converted, and every other leaf is the raw array.
-        # The int8 programs dequantize their own weights and take neither.
-        self._cast = self._cast_mask()
-        self._served = None  # built on demand, see ``served_params``
+        # the decode step and the prefills take the SERVED tree. A float
+        # engine's holds every leaf the model would convert to the compute
+        # type at each of its uses converted (Flax's rule;
+        # tests/test_served_tree.py holds it to a trace of the programs)
+        # and every other leaf as the raw array; it is built on demand,
+        # see ``served_params``. An int8 engine's is the quantized pair: a
+        # calibration, not a cast, so no leaf counts as cast and it is
+        # built here and in ``prepare_params`` and nowhere else.
+        self._cast = promoted_mask(
+            self.params, self.model.config.compute_dtype
+        )
+        self._served = None
         self.quant_report = None
-        self._q_params = self._q_scales = None
         if self.quantize_int8:
-            self._q_params, self._q_scales, leaves = quantize_tree(
-                self.params
-            )
-            self.quant_report = self._calibrate(
-                leaves, self.params, self._q_params, self._q_scales
-            )
-
-    def _cast_mask(self) -> list:
-        """Per leaf of ``params``: does the model convert it to the compute
-        type at every use? (Flax's rule; tests/test_served_tree.py holds
-        it to a trace of the programs this engine runs.)"""
-        if self.quantize_int8:
-            return [False] * len(jax.tree.leaves(self.params))
-        return promoted_mask(self.params, self.model.config.compute_dtype)
+            self._cast = [False] * len(self._cast)
+            self._served, self.quant_report = self._quantize(self.params)
 
     def _serve(self, params):
         return serve_tree(
             params, self._cast, self.model.config.compute_dtype
         )
 
+    def _quantize(self, params):
+        """(the pair the programs take, its calibration report)."""
+        q, scales, leaves = quantize_tree(params)
+        pair = QuantizedParams(q, scales)
+        return pair, self._calibrate(leaves, params, pair)
+
     @property
     def served_params(self):
-        """The tree the programs take. It lives while the engine holds a
-        slot: ``release`` of the last one drops it (an idle engine holds
-        the raw tree alone and leaves the rest of the device to whoever
-        reads ``params`` — an embedding, a check against a reference, a
-        reload's candidate) and the next admission builds it again, one
-        conversion of the cast leaves."""
+        """What the programs take. A float engine's tree lives while the
+        engine holds a slot: ``release`` of the last one drops it (an idle
+        engine holds the raw tree alone and leaves the rest of the device
+        to whoever reads ``params`` — an embedding, a check against a
+        reference, a reload's candidate) and the next admission builds it
+        again, one conversion of the cast leaves. An int8 engine's pair is
+        never dropped."""
         if self._served is None:
             self._served = self._serve(self.params)
         return self._served
 
-    def _calibrate(self, leaves: list, params, q_params, q_scales) -> dict:
+    def _calibrate(self, leaves: list, params, pair) -> dict:
         """The logged accuracy contract of the int8 path: per-leaf weight
         max-abs-error from quantize_tree plus the worst logits
         max-abs-error of the dequantized weights vs the full-precision
@@ -547,9 +476,7 @@ class ServeEngine:
         exact op sequence decode runs). Takes the tree being calibrated
         explicitly so a hot reload can calibrate candidate weights while
         the live ones keep serving."""
-        deq = dequantize_tree(
-            q_params, q_scales, self.model.config.compute_dtype
-        )
+        deq = dequantized(pair, self.model.config.compute_dtype)
         cache_a = cache_b = self.fresh_cache
         worst = 0.0
         for tok in (1, 7, 23, 4):  # fixed calibration prompt
@@ -590,8 +517,8 @@ class ServeEngine:
         """Background half of a hot swap: bring a freshly restored param
         tree into this engine's decode layout and verify it is
         hot-swappable — identical treedef and per-leaf shape/dtype vs
-        the live tree. Same shapes mean the two compiled programs
-        (prefill, decode step) are reused verbatim, which is the whole
+        the live tree. Same shapes mean the compiled programs
+        (prefill chunk, decode step) are reused verbatim, which is the whole
         zero-downtime contract; anything else raises ValueError and
         needs a restart, not a reload. Leaf placement is matched to the
         live tree (see ``_match_placement``) so the swap cannot change
@@ -622,13 +549,10 @@ class ServeEngine:
                     f"restart"
                 )
         params = jax.tree.map(_match_placement, params, self.params)
-        q_params = q_scales = report = None
         if self.quantize_int8:
-            q_params, q_scales, leaves = quantize_tree(params)
-            report = self._calibrate(leaves, params, q_params, q_scales)
-        return PreparedParams(
-            params, q_params, q_scales, report, self._serve(params)
-        )
+            served, report = self._quantize(params)
+            return PreparedParams(params, report, served)
+        return PreparedParams(params, None, self._serve(params))
 
     def commit_params(self, prepared: PreparedParams) -> None:
         """Foreground half: rebind the served weights. The jitted
@@ -640,10 +564,7 @@ class ServeEngine:
         the new weights."""
         self.params = prepared.params
         self._served = prepared.served
-        if self.quantize_int8:
-            self._q_params = prepared.q_params
-            self._q_scales = prepared.q_scales
-            self.quant_report = prepared.quant_report
+        self.quant_report = prepared.quant_report
         if self._prefix_cache is not None:
             # snapshots are caches computed under the OLD weights —
             # serving one after the swap would silently answer with
@@ -700,7 +621,7 @@ class ServeEngine:
                 live=self.slots.live.at[slot].set(False)
             )
         self._free.append(slot)
-        if not self.any_live:
+        if not self.any_live and not self.quantize_int8:
             self._served = None  # idle: see ``served_params``
 
     # ----- request admission ---------------------------------------------
@@ -735,10 +656,8 @@ class ServeEngine:
     def _prepare_admission(self, prime, length, *, top_k, add_bos,
                            temperature, top_p, key, seed, template,
                            frozen):
-        """Validation + host-side row construction shared by the
-        monolithic and chunked admission paths — both must build
-        byte-identical operands or the bit-parity contract between them
-        is fiction. Returns (row, start, key, parity, trow, frow)."""
+        """Validation + host-side row construction of an admission.
+        Returns (row, start, key, parity, trow, frow)."""
         with _stage("serve/prepare"):
             self.validate(prime, length, add_bos=add_bos,
                           temperature=temperature, top_p=top_p, top_k=top_k,
@@ -761,41 +680,24 @@ class ServeEngine:
                 top_p=None, key=None, seed: int = 0,
                 request_id: Optional[str] = None,
                 template=None, frozen=None) -> int:
-        """Admit a request into ``slot``. Returns the number of primed
+        """Admit a request into ``slot`` in one call (direct callers; the
+        scheduler drives ``begin_prefill`` / ``advance_prefill`` itself):
+        the whole prime as one chunk. Returns the number of primed
         positions (``start``). The slot's stream is bit-identical to
         ``sample_fast(key, model, params, prime, length, ...)``.
         ``template``/``frozen`` ((length,) arrays) enable fixed-position
         infilling for this slot, matching ``sample_fast``'s constraint.
         ``request_id`` is telemetry-only: the prefill span carries it so
         the trace ties device work back to the request's async track."""
-        row, start, key, parity, trow, frow = self._prepare_admission(
-            prime, length, top_k=top_k, add_bos=add_bos,
-            temperature=temperature, top_p=top_p, key=key, seed=seed,
-            template=template, frozen=frozen,
-        )
         with _span("serve/prefill", slot=int(slot),
                    request_id="" if request_id is None else str(request_id)):
-            tail = (
-                jnp.int32(slot), jnp.asarray(row), jnp.int32(start),
-                jnp.int32(length), key,
-                jnp.float32(temperature),
-                jnp.float32(_TOP_P_OFF if top_p is None else top_p),
-                jnp.int32(0 if top_k is None else top_k),
-                jnp.asarray(parity),
-                jnp.asarray(trow), jnp.asarray(frow),
+            pending = self.begin_prefill(
+                slot, prime, length, top_k=top_k, add_bos=add_bos,
+                temperature=temperature, top_p=top_p, key=key, seed=seed,
+                request_id=request_id, template=template, frozen=frozen,
             )
-            if self.quantize_int8:
-                self.slots = _prefill_q(
-                    self.model, self._q_params, self._q_scales, self.slots,
-                    self.fresh_cache, *tail,
-                )
-            else:
-                self.slots = _prefill(
-                    self.model, self.served_params, self.slots,
-                    self.fresh_cache, *tail,
-                )
-            self._targets[slot] = int(length)
-            return int(start)
+            self.advance_prefill(pending)
+            return pending.start
 
     def prefill_blocks(self, lo: int, hi: int) -> int:
         """Blocks a prefill of positions ``[lo, hi)`` executes (host
@@ -803,21 +705,21 @@ class ServeEngine:
         is the share of computed prefill rows that were real."""
         return feed_block_count(self.prefill_width, lo, hi)
 
-    # ----- chunked admission ----------------------------------------------
+    # ----- admission in chunks --------------------------------------------
 
     def begin_prefill(self, slot: int, prime, length: int, *,
                       top_k=25, add_bos: bool = False,
                       temperature: float = 1.0, top_p=None, key=None,
                       seed: int = 0, request_id: Optional[str] = None,
                       template=None, frozen=None) -> PendingPrefill:
-        """Start a chunked admission into ``slot``: validate + build the
-        same operands as ``prefill`` but run NO device work yet — the
-        caller (the scheduler) advances the returned ``PendingPrefill``
-        with ``advance_prefill`` between decode steps. When a prefix
+        """Start an admission into ``slot``: validate + build the
+        operands but run NO device work yet — the caller (the scheduler)
+        advances the returned ``PendingPrefill`` with
+        ``advance_prefill`` between decode steps. When a prefix
         cache is attached, the longest cached prefix of the feed region
         seeds the pending state at its depth, so a repeated scaffold
-        skips straight to the tail. The eventual token stream is
-        bit-identical to ``prefill`` with the same arguments."""
+        skips straight to the tail. The eventual token stream does not
+        depend on how ``advance_prefill`` splits the prime."""
         row, start, key, parity, trow, frow = self._prepare_admission(
             prime, length, top_k=top_k, add_bos=add_bos,
             temperature=temperature, top_p=top_p, key=key, seed=seed,
@@ -865,18 +767,11 @@ class ServeEngine:
                    lo=int(pending.pos), hi=int(hi)):
             if hi > pending.pos:
                 with _stage("serve/prefill_dispatch"):
-                    if self.quantize_int8:
-                        pending.cache = _prefill_chunk_q(
-                            self.model, self._q_params, self._q_scales,
-                            pending.cache, pending.row,
-                            jnp.int32(pending.pos), jnp.int32(hi),
-                        )
-                    else:
-                        pending.cache = _prefill_chunk(
-                            self.model, self.served_params, pending.cache,
-                            pending.row, jnp.int32(pending.pos),
-                            jnp.int32(hi),
-                        )
+                    pending.cache = _prefill_chunk(
+                        self.model, self.served_params, pending.cache,
+                        pending.row, jnp.int32(pending.pos),
+                        jnp.int32(hi),
+                    )
                 pending.blocks += self.prefill_blocks(pending.pos, int(hi))
                 pending.pos = int(hi)
                 if self._prefix_cache is not None:
@@ -911,14 +806,9 @@ class ServeEngine:
         (sampled, was_live, finished), each (max_slots,) — ``sampled[i]``
         is meaningful only where ``was_live[i]``."""
         with _stage("serve/decode_dispatch"):
-            if self.quantize_int8:
-                self.slots, sampled, was_live, finished = _decode_step_q(
-                    self.model, self._q_params, self._q_scales, self.slots
-                )
-            else:
-                self.slots, sampled, was_live, finished = _decode_step(
-                    self.model, self.served_params, self.slots
-                )
+            self.slots, sampled, was_live, finished = _decode_step(
+                self.model, self.served_params, self.slots
+            )
         with _stage("serve/decode_fetch"):
             sampled, was_live = np.asarray(sampled), np.asarray(was_live)
             if self.slot_batched:
@@ -1029,12 +919,7 @@ class ServeEngine:
 
     @staticmethod
     def prefill_compile_count() -> int:
-        """Compiled prefill variants across the whole family: the
-        monolithic program plus the chunk and finish halves of the
-        chunked path. Flat-after-warmup is the acceptance bar for both
-        paths (traced bounds are what keep the chunk program at one)."""
-        return (
-            _prefill._cache_size()
-            + _prefill_chunk._cache_size()
-            + _prefill_finish._cache_size()
-        )
+        """Compiled variants of the chunk and finish programs across ALL
+        engines in the process. Flat-after-warmup is the acceptance bar
+        (traced bounds are what keep the chunk program at one)."""
+        return _prefill_chunk._cache_size() + _prefill_finish._cache_size()

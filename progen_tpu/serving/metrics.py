@@ -41,8 +41,8 @@ from progen_tpu.telemetry.registry import (  # noqa: F401 — re-exported
 # `# HELP` text for the names whose meaning the name does not carry
 HELP = {
     "prefill_time_s": (
-        "Host seconds inside engine.prefill / advance_prefill: dispatch "
-        "of the prefill programs, not the device's prefill work"
+        "Host seconds inside engine.advance_prefill: dispatch of the "
+        "prefill programs, not the device's prefill work"
     ),
     "prefill_tokens_per_s": (
         "prefill_tokens over prefill_time_s: prime tokens per second of "

@@ -12,18 +12,17 @@ Backpressure is explicit: the queue is bounded and ``submit`` answers
 ("invalid: ..."). Invalid requests are rejected at submit time (engine
 validation, no device work) so they never occupy queue space.
 
-Chunked admission (``prefill_chunk`` > 0, or a prefix cache attached):
-instead of running the whole prime through ``engine.prefill`` inline —
-which stalls every live decode for the full prompt length — the head
-request becomes a ``PendingPrefill`` and ``step()`` feeds it at most
-``prefill_chunk`` prime positions per call before advancing the
-decoders, so a long prompt admits WHILE the pool keeps streaming. At
-most one prefill is in flight (FIFO order is preserved: later arrivals
-wait behind the head), the slot counts as occupied for the whole
-admission (the gauges and the router's least-loaded placement see it),
-and chunk progress is deliberately NOT journaled — a crash mid-chunk
-replays the accept and re-runs the prefill (or hits the prefix cache),
-which is exactly the monolithic crash contract.
+Admission: the head request becomes a ``PendingPrefill`` and ``step()``
+feeds it at most ``prefill_chunk`` prime positions per call before
+advancing the decoders, so a long prompt admits WHILE the pool keeps
+streaming; ``prefill_chunk`` 0 is no budget — every prime that finds a
+slot is fed whole, one chunk each, before decode resumes, which stalls
+every live decode for the full prompt length. At most one prefill is
+in flight (FIFO order is preserved: later arrivals wait behind the
+head), the slot counts as occupied for the whole admission (the gauges
+and the router's least-loaded placement see it), and chunk progress is
+deliberately NOT journaled — a crash mid-chunk replays the accept and
+re-runs the prefill (or hits the prefix cache).
 
 Every accepted request is additionally traced through the process
 telemetry as ONE async track (``{"ev": "req", "ph": "b"/"n"/"e"}``
@@ -132,7 +131,7 @@ class _Active:
 
 @dataclasses.dataclass
 class _PendingAdmission:
-    """The head request mid-chunked-prefill: its engine-side state plus
+    """The head request mid-prefill: its engine-side state plus
     the timing the scheduler owes the metrics once the slot goes live.
     ``prefill_s`` accumulates the wall time of the chunk calls ONLY —
     the decode steps interleaved between chunks belong to the decoders,
@@ -163,16 +162,11 @@ class Scheduler:
         self.engine = engine
         self.max_queue = int(max_queue)
         # prime positions fed per step() across pending admissions;
-        # 0 = unbudgeted (the whole prefill runs before decode resumes,
-        # the monolithic stall profile). A prefix cache alone also
-        # routes admission through the chunked path so hits can seed it.
+        # 0 = unbudgeted (the whole prefill runs before decode resumes)
         self.prefill_chunk = int(prefill_chunk)
         self.prefix_cache = prefix_cache
         if prefix_cache is not None:
             engine.set_prefix_cache(prefix_cache)
-        self._use_chunked = (
-            self.prefill_chunk > 0 or prefix_cache is not None
-        )
         self._pending: Optional[_PendingAdmission] = None
         self.metrics = metrics if metrics is not None else ServingMetrics()
         # optional RequestJournal (serving/journal.py): accepted work is
@@ -523,10 +517,9 @@ class Scheduler:
         )
 
     def _admit(self) -> None:
-        """Move queued requests onto slots. At most ONE chunked
-        admission is in flight (FIFO: later arrivals queue behind the
-        head); on the legacy inline path this loop runs whole prefills
-        until the pool or the queue is empty, exactly as before."""
+        """Move the queue's head onto a slot as the pending admission.
+        At most ONE is in flight (FIFO: later arrivals queue behind the
+        head): the loop ends as soon as one is pending."""
         with stage("serve/admit"):
             while self._pending is None and self._queue:
                 if self._queue[0][0].kind == "embed":
@@ -542,40 +535,16 @@ class Scheduler:
                                 trace=req.trace_id)
                 self._req_event("b", req.id, "prefill", ts=w0,
                                 trace=req.trace_id, slot=slot)
-                if self._use_chunked:
-                    # no device work yet: the prime is fed chunk-at-a-time
-                    # by _pump_admissions between decode steps
-                    pp = self.engine.begin_prefill(
-                        slot, req.prime, req.length, top_k=req.top_k,
-                        add_bos=req.add_bos, temperature=req.temperature,
-                        top_p=req.top_p, key=req.key, seed=req.seed,
-                        request_id=req.id, template=req.template,
-                        frozen=req.frozen,
-                    )
-                    self._pending = _PendingAdmission(req, pp, t_submit)
-                    continue  # loop condition ends admission for this step
-                t0 = self._clock()
-                start = self.engine.prefill(
+                # no device work yet: the prime is fed chunk-at-a-time
+                # by _pump_admissions
+                pp = self.engine.begin_prefill(
                     slot, req.prime, req.length, top_k=req.top_k,
                     add_bos=req.add_bos, temperature=req.temperature,
                     top_p=req.top_p, key=req.key, seed=req.seed,
                     request_id=req.id, template=req.template,
                     frozen=req.frozen,
                 )
-                t1 = self._clock()
-                w1 = time.time()
-                self._req_event("e", req.id, "prefill", ts=w1,
-                                trace=req.trace_id)
-                self._req_event("b", req.id, "decode", ts=w1,
-                                trace=req.trace_id, slot=slot)
-                self._active[slot] = _Active(req, slot, start, t_submit, t1)
-                self.metrics.inc("requests_admitted")
-                # start-1 prime tokens actually ran through the model
-                self.metrics.inc("prefill_tokens", max(start - 1, 0))
-                self.metrics.inc(
-                    "prefill_blocks", self.engine.prefill_blocks(0, start - 1)
-                )
-                self.metrics.add_time("prefill_time_s", t1 - t0)
+                self._pending = _PendingAdmission(req, pp, t_submit)
             self.metrics.set_gauge("queue_depth", len(self._queue))
             self.metrics.set_gauge("active_slots", len(self._active))
             self._emit_slots()
@@ -583,7 +552,7 @@ class Scheduler:
     def _activate(self, pa: _PendingAdmission) -> None:
         """A pending prefill finished its last chunk: the slot is live
         in the pool; open its decode phase and settle admission
-        metrics. Mirrors the inline path's bookkeeping exactly."""
+        metrics — the only place an admission is settled."""
         self._pending = None
         req, pp = pa.req, pa.pp
         t1 = self._clock()

@@ -491,15 +491,22 @@ class TestServingDonation:
 
     def test_engine_jits_donate_slot_buffers(self):
         registry = self._registry("progen_tpu/serving/engine.py")
-        for fn in ("_prefill", "_prefill_q",
-                   "_decode_step", "_decode_step_q"):
+        for fn in ("_prefill_finish", "_decode_step"):
             assert fn in registry, f"{fn} lost its jit decorator"
             assert "slots" in registry[fn].donated_names, (
                 f"{fn} no longer donates its slot batch"
             )
-            # fresh_cache is the reusable zero template every prefill
-            # reads: donating it would corrupt later admissions
-            assert "fresh_cache" not in registry[fn].donated_names, fn
+            # cache1 may be a prefix-cache snapshot; the weights are read
+            # by every later step
+            assert not {"cache1", "params"} & set(
+                registry[fn].donated_names
+            ), fn
+        # the chunk's cache is the reusable zero template on a cold
+        # admission (or a snapshot): donating it would corrupt later ones
+        assert "_prefill_chunk" in registry
+        assert not registry["_prefill_chunk"].donated_names
+        assert set(registry) >= {"_decode_step", "_prefill_chunk",
+                                 "_prefill_finish"}
 
     def test_train_step_compile_donates_state(self):
         # assignment-form jit with explicit shardings: assert on source

@@ -158,7 +158,7 @@ class TestHotSwap:
         engine = ServeEngine(
             model, params, max_slots=2, max_len=24, quantize_int8=True
         )
-        q_before = engine._q_params
+        q_before = engine.served_params
         report_before = engine.quant_report
         assert report_before["quantized_leaves"] > 0
 
@@ -166,7 +166,7 @@ class TestHotSwap:
         reloader = WeightReloader(engine, ck, current=name_a)
         _reload(reloader)
         assert reloader.maybe_commit() is not None
-        assert engine._q_params is not q_before
+        assert engine.served_params is not q_before
         assert engine.quant_report is not report_before
         assert engine.quant_report["quantized_leaves"] == \
             report_before["quantized_leaves"]

@@ -529,7 +529,7 @@ class TestFleetKillMatrixSlow:
         must hand off (or re-dispatch) without loss."""
         tokens, done, rejected, rdirs, procs, _ = _run_fleet(
             workspace, tmp_path,
-            replica_chaos=("serve/prefill:kill@2",),
+            replica_chaos=("serve/prefill_chunk:kill@2",),
         )
         assert procs[0].wait(timeout=60) == -9
         assert sorted(done) == ["r0", "r1", "r2", "r3"]

@@ -235,7 +235,7 @@ class TestKillMatrixSlow:
         """Die inside the second request's prefill: accepted-but-never-
         admitted requests must replay too."""
         _, _, tokens2, done2, _ = _kill_then_replay(
-            workspace, tmp_path, "serve/prefill:kill@2"
+            workspace, tmp_path, "serve/prefill_chunk:kill@2"
         )
         assert done2, "replay settled nothing"
 

@@ -118,8 +118,11 @@ def run_chunk(eng, tree):
 
 
 def run_prefill(eng, tree):
-    return E._prefill(
-        eng.model, tree, copy(eng.slots), eng.fresh_cache, jnp.int32(1),
+    # a whole prime as the chunk program's one chunk, then into the pool
+    cache = E._prefill_chunk(eng.model, tree, eng.fresh_cache, row_of(17),
+                             jnp.int32(0), jnp.int32(16))
+    return E._prefill_finish(
+        copy(eng.slots), cache, jnp.int32(1),
         row_of(17), jnp.int32(17), jnp.int32(LEN), jax.random.PRNGKey(9),
         jnp.float32(1.0), jnp.float32(E._TOP_P_OFF), jnp.int32(8),
         jnp.asarray(True), jnp.zeros((LEN,), jnp.int32),
@@ -434,18 +437,21 @@ def test_an_int8_engine_quantizes_the_raw_tree_and_builds_no_copy():
                       quantize_int8=True)
     q, scales, leaves = quantize_tree(params)
     assert eng.quant_report["leaves"] == leaves
-    same_bits(eng._q_params, q)
-    same_bits(eng._q_scales, scales)
+    same_bits(eng.served_params.q, q)
+    same_bits(eng.served_params.scales, scales)
     got = eng.state_bytes()
     assert got["served_leaves_cast"] == 0
     assert got["served_weight_bytes"] == got["raw_weight_bytes"]
-    for s, r, p in zip(jax.tree.leaves(eng.served_params),
+    # the served tree is the quantized pair: a leaf is the raw float32
+    # array itself or its int8 kernel, never a cast copy
+    for s, r, p in zip(jax.tree.leaves(eng.served_params.q),
                        jax.tree.leaves(eng.params), jax.tree.leaves(params)):
-        assert s is r is p and p.dtype == jnp.float32
+        assert r is p and p.dtype == jnp.float32
+        assert s is r or s.dtype == jnp.int8
     prepared = eng.prepare_params(jax.tree.map(lambda x: x * 1.5, params))
-    for s, r in zip(jax.tree.leaves(prepared.served),
+    for s, r in zip(jax.tree.leaves(prepared.served.q),
                     jax.tree.leaves(prepared.params)):
-        assert s is r
+        assert s is r or s.dtype == jnp.int8
 
 
 # ----- the gauges -----------------------------------------------------------

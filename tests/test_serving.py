@@ -341,3 +341,38 @@ class TestInt8Decode:
             total += n
         assert total > 0
         assert agree / total >= 0.6
+
+    def test_int8_programs_are_counted_by_the_compile_gauges(
+        self, model_and_params
+    ):
+        """An int8 engine runs the programs every engine runs, so the
+        two jit-cache counters see its compiles — and see none after the
+        first decode step and the first admission, whatever the next
+        request's length or chunk budget. (Shapes no other engine of
+        this module has, so the first calls are real compiles.)"""
+        model, params = model_and_params
+        engine = ServeEngine(
+            model, params, max_slots=3, max_len=27, quantize_int8=True
+        )
+        decode0 = engine.decode_compile_count()
+        prefill0 = engine.prefill_compile_count()
+        slot = engine.acquire()
+        engine.prefill(slot, np.array([1, 5, 9]), 12,
+                       key=jax.random.PRNGKey(0))
+        assert engine.prefill_compile_count() > prefill0
+        assert engine.decode_compile_count() == decode0
+        engine.decode_step()
+        assert engine.decode_compile_count() == decode0 + 1
+        warm = (engine.decode_compile_count(),
+                engine.prefill_compile_count())
+        # another length, fed under another budget, beside the first
+        pending = engine.begin_prefill(
+            engine.acquire(), np.array([2, 4, 6, 8, 10, 12, 14, 3, 5]), 20,
+            key=jax.random.PRNGKey(1),
+        )
+        while not engine.advance_prefill(pending, 3):
+            engine.decode_step()
+        engine.decode_step()
+        assert (engine.decode_compile_count(),
+                engine.prefill_compile_count()) == warm
+

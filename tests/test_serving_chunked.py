@@ -303,7 +303,7 @@ class TestPrefixCache:
         assert len(cache) >= 1
         inserts = cache.inserts
         engine.commit_params(
-            PreparedParams(engine.params, None, None, None)
+            PreparedParams(engine.params)
         )
         assert len(cache) == 0 and cache.bytes == 0
         assert cache.inserts == inserts  # counters not reset

@@ -104,6 +104,67 @@ class TestBackpressure:
         assert [c.request_id for c in comps] == ["q0", "q1", "q2"]
 
 
+class TestUnbudgetedAdmission:
+    """``prefill_chunk=0`` is the budgeted path without a budget: the
+    same pending admissions, settled by the same ``_activate``."""
+
+    @staticmethod
+    def _run(model, params, prefill_chunk):
+        from progen_tpu.telemetry import spans
+
+        seen = []
+        spans.configure(sink=seen.append)
+        try:
+            engine = ServeEngine(model, params, max_slots=3, max_len=32)
+            sched = Scheduler(engine, max_queue=8,
+                              prefill_chunk=prefill_chunk)
+            for i in range(3):  # primes of 5: four positions to feed each
+                assert sched.submit(Request(
+                    id=f"u{i}", prime=np.arange(1, 6) + 3 * i,
+                    length=14 + 2 * i, key=jax.random.PRNGKey(70 + i),
+                ))[0]
+            sched.step()
+            after_one_step = len(sched.active_ids)
+            _, comps = sched.run_to_completion(max_steps=300)
+        finally:
+            spans.configure()
+        events = {
+            f"u{i}": [
+                (r["name"], r["ph"], r.get("slot")) for r in seen
+                if r.get("ev") == "req" and r["req"] == f"u{i}"
+                and r["name"] in ("queued", "prefill", "decode")
+            ]
+            for i in range(3)
+        }
+        counters = {
+            name: sched.metrics.snapshot()[name]
+            for name in ("requests_admitted", "prefill_tokens",
+                         "prefill_blocks", "requests_completed")
+        }
+        tokens = {c.request_id: c.tokens.tolist() for c in comps}
+        return after_one_step, events, counters, tokens
+
+    def test_one_step_admits_what_a_budget_of_four_settles_in_three(
+        self, model_and_params
+    ):
+        model, params = model_and_params
+        live0, events0, counters0, tokens0 = self._run(model, params, 0)
+        live4, events4, counters4, tokens4 = self._run(model, params, 4)
+        assert (live0, live4) == (3, 1)
+        assert counters0 == counters4
+        assert counters0["requests_admitted"] == 3
+        assert counters0["prefill_tokens"] == 12
+        assert counters0["prefill_blocks"] == 3
+        assert events0 == events4
+        for i in range(3):
+            assert events0[f"u{i}"] == [
+                ("queued", "b", None), ("queued", "e", None),
+                ("prefill", "b", i), ("prefill", "e", None),
+                ("decode", "b", i), ("decode", "e", None),
+            ]
+        assert tokens0 == tokens4 and len(tokens0) == 3
+
+
 class TestSlotLifecycle:
     def test_mixed_length_release_and_reuse(self, model_and_params):
         """6 requests with very different lengths through 2 slots: every
@@ -370,13 +431,16 @@ class TestRequestTracing:
             if r["name"] == "request" and r["ph"] == "e"
         ]
         assert done[0]["n_generated"] > 0
-        # the prefill slice itself ran under a serve/prefill span
+        # the prefill slice itself ran under a serve/prefill_chunk span
         # stamped with the request id (engine-side attribution)
         prefill_spans = [
             r for r in records
-            if r.get("ev") == "B" and r.get("span") == "serve/prefill"
+            if r.get("ev") == "B" and r.get("span") == "serve/prefill_chunk"
         ]
         assert {r["request_id"] for r in prefill_spans} == {"q0", "q1"}
+        assert all(
+            r["slot"] == 0 and 0 <= r["lo"] <= r["hi"] for r in prefill_spans
+        )
 
     def test_expired_request_track_closes_with_reason(
         self, model_and_params, records
