@@ -276,14 +276,18 @@ def _prepare_seq(model, prime, length, add_bos):
     """Validate and build the fixed-shape decode buffer (shared by ALL
     decode paths): BOS shift (utils.py:110-111), right-padding, and the
     bounds the model can actually serve. ``prime`` may be (prime_len,) or
-    (batch, prime_len) — padding applies to the last axis either way."""
+    (batch, prime_len) — padding applies to the last axis either way.
+    The buffer is built with numpy on the host: the serving loop calls
+    this at every submit and admission, and a pad on the device would be
+    a dispatch, a compile per new shape and a read-back that waits
+    behind whatever the device is running."""
     seq_len = model.config.seq_len
     if length > seq_len:
         raise ValueError(
             f"length {length} exceeds the model's seq_len {seq_len} (RoPE "
             f"tables and the SGU spatial matrix are bound to seq_len)"
         )
-    prime = jnp.asarray(prime, jnp.int32)
+    prime = np.asarray(prime, np.int32)
     start = prime.shape[-1] + (1 if add_bos else 0)
     if start == 0:
         raise ValueError("empty prime requires add_bos=True")
@@ -295,7 +299,7 @@ def _prepare_seq(model, prime, length, add_bos):
         else (0, length - prime.shape[-1])
     )
     widths = ((0, 0),) * (prime.ndim - 1) + (pad,)
-    return jnp.pad(prime, widths), start
+    return np.pad(prime, widths), start
 
 
 @functools.partial(

@@ -336,6 +336,7 @@ class PendingPrefill:
 
     slot: int
     row: jnp.ndarray  # (max_len,) int32 padded token buffer
+    host_row: np.ndarray  # the same buffer as the host built it
     start: int  # primed positions; feed region is row[0:start-1]
     length: int  # requested total length (the slot's target)
     key: jnp.ndarray  # per-request PRNG key (untouched until scatter)
@@ -363,8 +364,13 @@ class PendingPrefill:
 
 class ServeEngine:
     """Fixed-pool continuous-batching engine bound to one (model, params,
-    max_slots, max_len). Host-side it is just a free-list and three
-    jitted calls; all decode state lives on the device in ``self.slots``.
+    max_slots, max_len). Host-side it is a free-list, three jitted calls
+    and a mirror of what the host itself put into each slot (the prime
+    it admitted, the tokens it fetched); all decode state lives on the
+    device in ``self.slots``, and the only read of it is the fetch of a
+    decode step's three small outputs. A decode step takes nothing from
+    the host, so ``launch_step`` may enqueue one before its predecessor
+    was fetched: at most one step is ever launched and unfetched.
     ``params`` is the tree as handed in; the programs take
     ``served_params`` (see ``__init__``)."""
 
@@ -418,7 +424,20 @@ class ServeEngine:
             frozen=jnp.zeros((s, l), bool),
         )
         self._free = list(range(s))
-        self._targets = [l] * s  # host mirror for collect()
+        # what the host knows of each slot without reading the device:
+        # the token buffer (the prime it admitted, then every token it
+        # fetched), the position of the last of them, the requested
+        # length, whether the occupant is still decoding as far as a
+        # fetched step has said, and a count of occupants — a step's
+        # results belong to the occupants AS OF ITS LAUNCH
+        self._targets = [l] * s
+        self._rows = np.zeros((s, l), np.int32)
+        self._cur = np.zeros((s,), np.int64)
+        self._live = np.zeros((s,), bool)
+        self._occupant = np.zeros((s,), np.int64)
+        # the decode step launched and not yet fetched: its three device
+        # outputs and ``_occupant`` as it stood at the launch
+        self._in_flight = None
         # counts a family's decode step carries to the host behind its
         # tokens, summed until the scheduler takes them (pop_counters)
         self._counters: dict = {}
@@ -558,8 +577,9 @@ class ServeEngine:
         """Foreground half: rebind the served weights. The jitted
         programs take params as a per-call operand, so between two
         ``decode_step`` calls this is an atomic host-side swap — the
-        next step reads the new tree with zero recompiles (shape/dtype
-        equality enforced by ``prepare_params``). In-flight requests
+        next step LAUNCHED reads the new tree with zero recompiles
+        (shape/dtype equality enforced by ``prepare_params``); a step
+        already in flight ran on the old one. In-flight requests
         continue on their existing KV caches; only future matmuls see
         the new weights."""
         self.params = prepared.params
@@ -612,14 +632,21 @@ class ServeEngine:
 
     def release(self, slot: int) -> None:
         """Return a finished (or cancelled) slot to the free list. Device
-        state is NOT scrubbed — the next prefill fully rewrites it; a
-        cancelled still-live slot is silenced so it stops burning steps."""
+        state is NOT scrubbed — the next prefill fully rewrites it. No
+        read of the device: whether the occupant still decodes is what
+        the fetched steps have told the host. A cancelled slot that does
+        is silenced by an update enqueued behind whatever is in flight,
+        so it stops burning steps; what a step already launched draws
+        for it belongs to nobody and is masked out of that step's
+        fetch."""
         if slot in self._free:
             return
-        if bool(self.slots.live[slot]):
+        if self._live[slot]:
             self.slots = self.slots._replace(
                 live=self.slots.live.at[slot].set(False)
             )
+            self._live[slot] = False
+        self._occupant[slot] += 1
         self._free.append(slot)
         if not self.any_live and not self.quantize_int8:
             self._served = None  # idle: see ``served_params``
@@ -664,7 +691,7 @@ class ServeEngine:
                           template=template, frozen=frozen)
             seq, start = _prepare_seq(self.model, prime, length, add_bos)
             row = np.zeros((self.max_len,), np.int32)
-            row[: int(seq.shape[0])] = np.asarray(seq)
+            row[: seq.shape[0]] = seq
             trow = np.zeros((self.max_len,), np.int32)
             frow = np.zeros((self.max_len,), bool)
             if template is not None:
@@ -728,6 +755,7 @@ class ServeEngine:
         pending = PendingPrefill(
             slot=int(slot),
             row=jnp.asarray(row),
+            host_row=row,
             start=start,
             length=int(length),
             key=key,
@@ -777,8 +805,7 @@ class ServeEngine:
                 if self._prefix_cache is not None:
                     with _stage("serve/prefix_insert"):
                         self._prefix_cache.insert(
-                            np.asarray(pending.row), pending.pos,
-                            pending.cache,
+                            pending.host_row, pending.pos, pending.cache,
                         )
             if pending.pos >= feed_len:
                 with _stage("serve/prefill_finish"):
@@ -795,22 +822,68 @@ class ServeEngine:
                     self.slots = _prefill_finish(
                         self.slots, pending.cache, *tail
                     )
-                self._targets[pending.slot] = int(pending.length)
+                slot = pending.slot
+                self._targets[slot] = int(pending.length)
+                self._rows[slot] = pending.host_row
+                self._cur[slot] = pending.start - 1
+                self._live[slot] = True
+                self._occupant[slot] += 1
                 pending.done = True
         return pending.done
 
     # ----- the hot loop ---------------------------------------------------
 
+    @property
+    def step_in_flight(self) -> bool:
+        """A decode step is launched and not yet fetched."""
+        return self._in_flight is not None
+
+    def launch_step(self) -> None:
+        """Enqueue one decode step and return without waiting for it:
+        the step takes nothing from the host (liveness, the stop and
+        infill rules and the sampler's keys live in ``slots``), so it is
+        fully determined the moment its predecessor is enqueued. At most
+        one step is in flight: ``decode_step`` fetches it."""
+        if self._in_flight is not None:
+            raise RuntimeError("a decode step is already in flight")
+        with _stage("serve/decode_dispatch"):
+            self.slots, *outputs = _decode_step(
+                self.model, self.served_params, self.slots
+            )
+        self._in_flight = (outputs, self._occupant.copy())
+
+    def drop_step(self) -> None:
+        """Forget the step in flight, if any, without waiting for it —
+        for a caller that knows it advanced no slot (every occupant had
+        finished or been released before its launch was due)."""
+        if self._in_flight is not None and self._live.any():
+            raise RuntimeError("the step in flight may hold live slots")
+        self._in_flight = None
+
     def decode_step(self):
         """One token for every live slot. Returns host arrays
         (sampled, was_live, finished), each (max_slots,) — ``sampled[i]``
-        is meaningful only where ``was_live[i]``."""
-        with _stage("serve/decode_dispatch"):
-            self.slots, sampled, was_live, finished = _decode_step(
-                self.model, self.served_params, self.slots
-            )
+        is meaningful only where ``was_live[i]``.
+
+        With nothing in flight: launch a step, fetch it, return it. With
+        a step in flight (``launch_step``): launch its SUCCESSOR first —
+        unless no occupant is left that a fetched step has not seen
+        finish —, then fetch and return the one that was in flight: the
+        device holds a whole step of queued work while the host reads,
+        and the fetch is the one wait on the device. The results are
+        those of the occupants as of the step's launch: where a slot was
+        released since (a cancellation; a new request may hold it by
+        now), ``was_live`` and ``finished`` read False."""
+        fetch, self._in_flight = self._in_flight, None
+        if fetch is None:
+            self.launch_step()
+            fetch, self._in_flight = self._in_flight, None
+        elif self._live.any():
+            self.launch_step()
+        outputs, occupant = fetch
         with _stage("serve/decode_fetch"):
-            sampled, was_live = np.asarray(sampled), np.asarray(was_live)
+            # one wait for the three of them, not one after the other
+            sampled, was_live, finished = jax.device_get(outputs)
             if self.slot_batched:
                 # the family's counts ride behind the tokens; it names
                 # and folds them itself
@@ -820,7 +893,13 @@ class ServeEngine:
                 for name, by in folded.items():
                     self._counters[name] = self._counters.get(name, 0) + by
                 sampled = sampled[: self.max_slots]
-            return sampled, was_live, np.asarray(finished)
+            same = occupant == self._occupant
+            was_live, finished = was_live & same, finished & same
+            wrote = np.flatnonzero(was_live)
+            self._cur[wrote] += 1
+            self._rows[wrote, self._cur[wrote]] = sampled[wrote]
+            self._live[finished] = False
+            return sampled, was_live, finished
 
     def pop_counters(self) -> dict:
         """Counter increments gathered since the last call (empty for a
@@ -851,12 +930,14 @@ class ServeEngine:
         return out
 
     def collect(self, slot: int) -> np.ndarray:
-        """The finished request's (target,) token buffer with the
-        standalone decoders' truncation applied (everything after the
-        second zero -> 0), so it compares token-for-token with
-        ``sample_fast`` output."""
-        row = np.asarray(self.slots.seqs[slot])[: self._targets[slot]]
-        row = row.copy()
+        """The slot's (target,) token buffer with the standalone
+        decoders' truncation applied (everything after the second zero
+        -> 0), so it compares token-for-token with ``sample_fast``
+        output. Built from what the host holds — the prime it admitted
+        and every token it fetched, which is what ``slots.seqs`` holds on
+        the device once the slot's last step is fetched — not from a
+        read of the pool."""
+        row = self._rows[slot, : self._targets[slot]].copy()
         row[np.cumsum(row == 0) > 1] = 0
         return row
 

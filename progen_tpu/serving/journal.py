@@ -63,6 +63,21 @@ STATUS_COMPLETED = "completed"
 STATUS_HANDED_OFF = "handed_off"
 
 
+def _seed_key(seed: int) -> np.ndarray:
+    """``jax.random.PRNGKey(seed)`` as host integers, computed on the
+    CPU backend where there is one: on the accelerator the few integer
+    operations would queue behind the decode step in flight, and reading
+    them back would hold ``submit`` until that step is done."""
+    import jax
+
+    try:
+        cpu = jax.devices("cpu")[0]
+    except RuntimeError:  # a process held to the accelerator's platform
+        cpu = None
+    with jax.default_device(cpu):
+        return np.asarray(jax.random.PRNGKey(seed))
+
+
 class RequestJournal:
     """Append-only journal of request acceptance, emitted-token
     watermarks, and completion. One instance per serve process; safe to
@@ -89,9 +104,7 @@ class RequestJournal:
         """Journal everything needed to re-create ``req`` from nothing.
         The PRNG key is resolved NOW (explicit key, else seed-derived) so
         replay does not depend on how the key was originally specified."""
-        import jax
-
-        key = req.key if req.key is not None else jax.random.PRNGKey(req.seed)
+        key = req.key if req.key is not None else _seed_key(req.seed)
         self.emit({
             "ev": "journal", "op": "accept", "ts": time.time(),
             "req": str(req.id),

@@ -48,6 +48,11 @@ HELP = {
         "prefill_tokens over prefill_time_s: prime tokens per second of "
         "HOST time in admission, not the device's prefill rate"
     ),
+    "decode_steps_ahead": (
+        "Decode steps whose successor was already launched when the host "
+        "fetched their tokens: over decode_steps, the share of steps the "
+        "device did not wait for the host between"
+    ),
     "prefill_blocks": (
         "Prefill blocks executed: passes over the weights made to feed "
         "prompts, each an aligned block of the engine's prefill_width "

@@ -2,9 +2,24 @@
 
 Continuous batching in the Orca (OSDI '22) sense: admission happens at
 token-iteration granularity — every ``step()`` first drains the FIFO
-queue into whatever slots just freed, then advances all live slots one
-token. A finished request's slot is back in rotation on the very next
-step, so the pool stays saturated as long as the queue is non-empty.
+queue into whatever slots the host knows to be free, then advances all
+live slots one token. The pool stays saturated as long as the queue is
+non-empty.
+
+The loop keeps one decode step ahead of the host. A decode step takes
+nothing from the host, so while requests decode, step N is already
+running on the device when ``step()`` is entered; the call enqueues its
+admission work behind N, launches N+1, and only then fetches N's tokens
+(the one wait on the device), emits and journals them, and returns. The
+device always holds a whole decode step of queued work while the host
+does everything else. Two things follow. Every request's token stream
+is what it was (the sampler's state lives on the device; bit-identical
+to ``sample_fast``), and every token is journaled before ``step()``
+returns it. But the host learns that step N finished a request after
+N+1 was launched: the freed slot's next request starts one decode step
+later than it would in a strictly serial loop, and when the last
+request finishes, the step launched behind it has advanced nothing and
+is forgotten.
 
 Backpressure is explicit: the queue is bounded and ``submit`` answers
 (accepted, reason) instead of blocking — a serving front-end must know
@@ -207,8 +222,8 @@ class Scheduler:
             "prefill_compile_count", self.engine.prefill_compile_count()
         )
         # every XLA compile-or-load of the process, the ones the two
-        # jit-cache counts above miss among them (an eager pad at a new
-        # prompt shape, the embed twin); counted from load_env_file() on
+        # jit-cache counts above miss among them (the embed twin, an
+        # eager op at a new shape); counted from load_env_file() on
         if compiles.installed():
             self.metrics.set_gauge(
                 "xla_compile_count", compiles.backend_compiles()
@@ -282,19 +297,21 @@ class Scheduler:
         })
 
     def _shed_traced(self, req: Request, reason: str,
-                     ts: Optional[float] = None) -> None:
-        """Close an accepted-but-never-admitted request's track: the
-        shed instant, then the still-open queued phase, then the
-        envelope. The shed is also a journal settlement — the client
+                     ts: Optional[float] = None, phase: str = "queued",
+                     n_generated: int = 0) -> None:
+        """Close the track of an accepted request that will not be
+        answered (never admitted, unless ``phase`` names a later one: a
+        cancellation): the shed instant, then the still-open phase, then
+        the envelope. The shed is also a journal settlement — the client
         was told 'rejected', so replay must never resurrect it."""
         ts = time.time() if ts is None else ts
         rid, trace = req.id, req.trace_id
         self._req_event("n", rid, reason, ts=ts, trace=trace)
-        self._req_event("e", rid, "queued", ts=ts, trace=trace)
+        self._req_event("e", rid, phase, ts=ts, trace=trace)
         self._req_event("e", rid, "request", ts=ts, trace=trace,
                         reason=reason)
         if self.journal is not None:
-            self.journal.done(rid, reason, 0)
+            self.journal.done(rid, reason, n_generated)
 
     def close_tracks(self, reason: str = "killed") -> None:
         """Crash-path teardown (second-signal "exit now"): close every
@@ -386,6 +403,42 @@ class Scheduler:
             return True, None
 
     # ----- the loop -------------------------------------------------------
+
+    def cancel(self, request_id: str) -> bool:
+        """Stop serving ONE request wherever it is — queued, mid-prefill
+        or decoding — and settle it (journal ``done(cancelled)``: a
+        replay never resurrects it). No ``TokenEvent`` carries its id
+        from here on: a decoding request's slot is released at once and
+        silenced on the device behind whatever is in flight, and what
+        the step in flight draws for it is dropped by the engine (the
+        slot may hold the next request by the time that step is
+        fetched). Returns True iff the request was found."""
+        slot = next((s for s, rec in self._active.items()
+                     if rec.req.id == request_id), None)
+        queued = next((i for i, (req, _) in enumerate(self._queue)
+                       if req.id == request_id), None)
+        if slot is not None:
+            rec = self._active.pop(slot)
+            req, phase, n_generated = rec.req, "decode", rec.n_generated
+            self.engine.release(slot)
+            if not self._active:
+                self.engine.drop_step()
+        elif self._pending is not None and self._pending.req.id == request_id:
+            req, phase, n_generated = self._pending.req, "prefill", 0
+            self.engine.release(self._pending.pp.slot)
+            self._pending = None
+        elif queued is not None:
+            req, phase, n_generated = self._queue[queued][0], "queued", 0
+            del self._queue[queued]
+        else:
+            return False
+        self.metrics.inc("requests_cancelled")
+        self.metrics.set_gauge("queue_depth", len(self._queue))
+        self.metrics.set_gauge("active_slots", len(self._active))
+        self._shed_traced(req, "cancelled", phase=phase,
+                          n_generated=n_generated)
+        self._emit_slots()
+        return True
 
     @property
     def has_work(self) -> bool:
@@ -610,7 +663,16 @@ class Scheduler:
         Returns the tokens produced this step (streaming order =
         slot order, stable) and any requests that finished. Expired
         queued requests are shed first (check ``pop_expired()``) so a
-        dead deadline never consumes a freed slot."""
+        dead deadline never consumes a freed slot.
+
+        The order of a call while requests decode: expire, admit (chunks
+        and the slot scatter are enqueued behind the decode step already
+        in flight), launch the NEXT decode step, fetch the one in flight
+        — the call's one wait on the device —, emit, journal, return.
+        Every token returned is in the journal first; every stream is
+        bit-identical to a serial loop's; a slot freed by the fetched
+        step is refilled one decode step later than a serial loop would
+        (the next step was launched before the host saw it free)."""
         with stage("serve/step"):
             self._expire_queued(self._clock())
             self._pump_admissions()
@@ -625,6 +687,11 @@ class Scheduler:
             # in resilience/retry.py
             maybe_inject("serve/decode")
             with stage("serve/decode") as decode:
+                # with a step in flight, decode_step launches its
+                # successor before it fetches it; the first step of a
+                # busy stretch puts one in flight here
+                if not self.engine.step_in_flight:
+                    self.engine.launch_step()
                 sampled, was_live, finished = self.engine.decode_step()
             now = self._clock()
             events: List[TokenEvent] = []
@@ -671,6 +738,9 @@ class Scheduler:
                         self.journal.done(c.request_id, "completed",
                                           c.n_generated)
             self.metrics.inc("decode_steps")
+            self.metrics.inc(
+                "decode_steps_ahead", int(self.engine.step_in_flight)
+            )
             self.metrics.inc("decode_tokens", n_live)
             for name, by in self.engine.pop_counters().items():
                 self.metrics.inc(name, by)
@@ -680,6 +750,9 @@ class Scheduler:
             # --metrics-every publish — a recompile storm is exactly when
             # the console needs to see the count move
             self._publish_compile_gauges()
+            if not self._active:
+                # the step launched behind the last request's last one
+                self.engine.drop_step()
             return events, embed_done + completions
 
     def _finish(self, slot: int, rec: _Active, now: float) -> Completion:

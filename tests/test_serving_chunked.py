@@ -422,7 +422,9 @@ class TestStages:
         t_step = time.perf_counter()
         events, _ = sched.step()
         t_end = time.perf_counter()
-        assert {e.request_id for e in events} == {"first", "second"}
+        # "second" went live in the decode step this call LAUNCHED; the
+        # one it fetched was launched a call earlier, before the admission
+        assert {e.request_id for e in events} == {"first"}
 
         submits = [r for r in tel.stages(since=t_submit, until=t_step)
                    if r[2] == "serve/submit"]
@@ -453,6 +455,8 @@ class TestStages:
         first_step = [r for r in tel.stages(until=t_submit)
                       if r[2] == "serve/decode"][-1]
         assert decode_s == pytest.approx(first_step[4] + by_name["serve/decode"][0][4])
+        events, _ = sched.step()
+        assert {e.request_id for e in events} == {"first", "second"}
         journal.close()
 
     def test_stages_change_no_token(self, model_and_params):
