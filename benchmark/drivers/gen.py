@@ -14,6 +14,7 @@ import numpy as np
 
 from benchmark import stats
 from benchmark import traffic as traffic_mod
+from benchmark.counts import progen as counts
 from benchmark.reference import progen_ref
 
 # The served model computes in bfloat16 from float32 weights; the
@@ -74,6 +75,24 @@ def make_request(rid: str, prompt: np.ndarray, out_len: int, t: dict):
         top_k=t["top_k"], temperature=t["temperature"],
         seed=int(prompt[:4].sum()), template=template, frozen=frozen,
     )
+
+
+def real_row_share(counters: dict, engine):
+    """Share of the rows the window's prefill passes computed that fed a
+    prompt: a pass computes a whole block of the engine's width."""
+    rows = counters["prefill_blocks"] * engine.prefill_width
+    return counters["prefill_tokens"] / rows if rows else None
+
+
+def compared(check: dict, n_wrong: int, counters: dict, **more) -> dict:
+    """Each number that decides ``correct`` beside its limit."""
+    return {
+        "logits_rms_over_std": [check["rms_err_over_std"], check["tolerances"][0]],
+        "logits_max_over_std": [check["max_err_over_std"], check["tolerances"][1]],
+        **more,
+        "wrong_output_lengths": [n_wrong, 0],
+        "compiles_in_window": [counters["engine_compiles_in_window"], 0],
+    }
 
 
 def check_against_reference(run, engine) -> dict:
@@ -143,8 +162,8 @@ def run(run) -> dict:
 
     requests = traffic_mod.gen_requests(t, run.seed)
     n_clients = t["clients"]
-    owner, expect, submit_t = {}, {}, {}
-    token_t, ttft = {}, {}
+    owner, expect, submit_t, fed = {}, {}, {}, {}
+    token_t, first_at, ttft = {}, {}, {}
     n_submitted = n_rejected = n_wrong = n_done = 0
     first_seen, replaced = set(), 0
 
@@ -161,6 +180,7 @@ def run(run) -> dict:
             n_rejected += 1
             return
         owner[rid], expect[rid], submit_t[rid] = client, out_len, now
+        fed[rid] = len(prompt)  # BOS + all but the prompt's last token
         token_t[rid] = []
 
     # the ramp: every client starts part-way through a request, so that
@@ -178,6 +198,7 @@ def run(run) -> dict:
             times = token_t[ev.request_id]
             if not times:
                 ttft[ev.request_id] = now - submit_t[ev.request_id]
+                first_at[ev.request_id] = ev.index  # the rest follow it
                 if ev.request_id in initial:
                     first_seen.add(ev.request_id)
                 else:
@@ -204,9 +225,25 @@ def run(run) -> dict:
     m1 = sched.metrics.snapshot()
     gaps = stats.gaps_in_window(token_t, t_open, t_close)
     run.samples["itl_s"] = gaps
-    run.samples["ttft_s"] = [
-        v for rid, v in ttft.items() if t_open < submit_t[rid] + v <= t_close
-    ]
+    first_in = [rid for rid, v in ttft.items()
+                if t_open < submit_t[rid] + v <= t_close]
+    run.samples["ttft_s"] = [ttft[rid] for rid in first_in]
+    # positions each token of the window saw (benchmark/counts/progen.py
+    # counts from them): the k-th token of a request is written at index
+    # first_at + k by the query one position before it, which mixes every
+    # position up to itself and attends to the rows of its window; a
+    # prompt that fed p positions computed position j likewise
+    cfg = run.config
+    at = [first_at[rid] + k for rid, times in token_t.items()
+          for k, when in enumerate(times) if t_open < when <= t_close]
+    run.counters.update(
+        decode_context_sum=sum(at),
+        decode_window_rows_sum=sum(counts.window_rows(cfg, i - 1) for i in at),
+        prefill_context_sum=sum(fed[r] * (fed[r] + 1) // 2 for r in first_in),
+        prefill_window_rows_sum=sum(
+            counts.window_rows(cfg, j) for r in first_in for j in range(fed[r])
+        ),
+    )
     run.counters.update(
         tokens=stats.tokens_in_window(token_t, t_open, t_close),
         max_slots=t["max_slots"],
@@ -214,9 +251,11 @@ def run(run) -> dict:
                                    + engine.prefill_compile_count() - before),
         requests_completed=n_done - done0,
         **{k: m1.get(k, 0.0) - m0.get(k, 0.0) for k in
-           ("decode_steps", "decode_tokens", "prefill_tokens",
-            "prefill_time_s", "decode_time_s")},
+           ("decode_steps", "decode_steps_ahead", "decode_tokens",
+            "prefill_tokens", "prefill_blocks", "prefill_time_s",
+            "decode_time_s")},
     )
+    run.counters["prefill_real_row_share"] = real_row_share(run.counters, engine)
     edges = [0.0, 0.02, 0.03, 0.04, 0.06, 0.08, 0.1, 0.12, 0.15, 0.2, 0.3, 0.5]
     run.notes["itl_histogram"] = {"edges_s": edges,
                                   "counts": stats.histogram(gaps, edges)}
@@ -225,4 +264,5 @@ def run(run) -> dict:
         "correct": check["ok"] and n_wrong == 0 and run.counters["engine_compiles_in_window"] == 0,
         "attempted": attempted, "failed": n_rejected + n_wrong,
         "check": check,
+        "compared": compared(check, n_wrong, run.counters),
     }

@@ -17,7 +17,7 @@ import numpy as np
 
 from benchmark import stats
 from benchmark import traffic as traffic_mod
-from benchmark.drivers.gen import make_request
+from benchmark.drivers.gen import compared, make_request, real_row_share
 from benchmark.reference import latent_moe_ref
 from progen_tpu.models import build_model  # a program without it fails here
 from progen_tpu.sampling import gumbel_step_dynamic
@@ -219,8 +219,8 @@ def check_against_reference(run, engine, reference_params=None) -> dict:
 
 
 COUNTERS = (
-    "decode_steps", "decode_tokens", "prefill_tokens", "prefill_blocks",
-    "prefill_time_s", "decode_time_s", "moe_expert_layer_steps",
+    "decode_steps", "decode_steps_ahead", "decode_tokens", "prefill_tokens",
+    "prefill_blocks", "prefill_time_s", "decode_time_s", "moe_expert_layer_steps",
     "moe_assignments", "moe_experts_touched", "moe_max_load_rows",
     "moe_feed_expert_layer_blocks", "moe_feed_experts_touched",
     "moe_feed_max_load_rows",
@@ -333,6 +333,7 @@ def run(run) -> dict:
         prefill_context_sum=sum(fed[r] * (fed[r] + 1) // 2 for r in first_in),
         **{k: m1.get(k, 0.0) - m0.get(k, 0.0) for k in COUNTERS},
     )
+    run.counters["prefill_real_row_share"] = real_row_share(run.counters, engine)
     run.counters["moe_mean_load_rows"] = (
         run.counters["moe_assignments"] / run.config["n_routed_experts"]
     )
@@ -346,4 +347,12 @@ def run(run) -> dict:
         "correct": check["ok"] and n_wrong == 0 and run.counters["engine_compiles_in_window"] == 0,
         "attempted": attempted, "failed": n_rejected + n_wrong,
         "check": check,
+        "compared": compared(
+            check, n_wrong, run.counters,
+            routing_slack=[check["routing_slack"], SLACK_TOLERANCE],
+            exchanged_share=[check["exchanged_share"], EXCHANGED_TOLERANCE],
+            served_tokens_redrawn_at_least=[
+                check["served_tokens_redrawn"],
+                REDRAWN_AT_LEAST * check["positions"]],
+        ),
     }

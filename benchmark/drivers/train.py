@@ -99,4 +99,9 @@ def run(run) -> dict:
         "attempted": steps, "failed": 0 if finite else int(
             (~np.isfinite(losses)).sum()),
         "check": check,
+        "compared": {
+            "first_loss_abs_err": [check["abs_err"], LOSS_TOLERANCE],
+            "losses_not_finite": [int((~np.isfinite(losses)).sum()), 0],
+            "compiles_in_window": [step._cache_size() - 1, 0],
+        },
     }

@@ -11,9 +11,6 @@ The spec's ``what`` picks the reduction:
                   calls of their duration, in ms; of their self time
                   where ``self`` is true; only of the calls that have a
                   direct child named ``child`` where one is given
-  ``overhead``    trace: median over the ``stage`` host events of their
-                  duration less the device time of the ``program``
-                  executions inside them, ms
   ``idle``        trace: device-0 idle seconds attributed to the stages in
                   ``stages`` over the host events of ``den``, in ms
 
@@ -129,7 +126,11 @@ def idle_by_stage(trace: dict, lo: float, hi: float) -> dict:
 def overheads(trace: dict, stage: str, program: str) -> dict:
     """Each host event named ``stage`` with the device-0 execution of
     ``program`` (a regex) that overlaps it most: ``seconds`` is the host
-    event's duration less the execution's, per pair. Durations only, so
+    event's duration less the execution's, per pair. No metric reads it
+    since the served loop launches step N+1 before it fetches step N (a
+    stage then ends with an execution launched a call earlier, and the
+    difference reads negative): it stays for reading a trace by hand and
+    for the bound below. Durations only, so
     it holds however far the device's clock is off the host's;
     ``clock_bounds_ms`` says how far that can be: the execution cannot
     start before the stage that launches it nor end after the stage
@@ -221,7 +222,7 @@ def write_table(run, data) -> None:
 
 def read(run, spec):
     what = spec["what"]
-    if what not in ("per_call", "calls", "overhead", "idle"):
+    if what not in ("per_call", "calls", "idle"):
         raise ValueError(f"program_stages: unknown reduction {what!r}")
     data = collect(run)
     table = data["table"]
@@ -240,10 +241,6 @@ def read(run, spec):
         return 1000.0 * stat(secs)
     if data["trace"] is None or not data["trace"]["devices"]:
         return None
-    if what == "overhead":
-        over = overheads(data["trace"], spec["stage"], spec["program"])
-        run.notes[f"device_clock_behind_host_ms.{spec['stage']}"] = over["clock_bounds_ms"]
-        return 1000.0 * statistics.median(over["seconds"]) if over["seconds"] else None
     calls = sum(1 for e in data["trace"]["host"] if e[0] == spec["den"])
     if not calls or not any(n in table for n in spec["stages"]):
         return None

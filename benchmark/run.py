@@ -241,6 +241,8 @@ class Run:
                 "device_ops": xplane.op_ranking(self.trace),
                 "idle_gaps": xplane.idle_gaps(self.trace, *self.trace_window),
             }
+        # what decided ``correct``, each number beside its limit: last
+        line["compared"] = outcome.get("compared", {})
         return line
 
     def write_details(self, outcome: dict) -> None:
@@ -326,6 +328,8 @@ def main(argv=None) -> int:
                / f"seed{args.seed}-trace{args.trace}")
     line = execute(manifest, cell, config, traffic, args.seed, seconds,
                    args.trace, devices, out_dir)
+    for name, (value, limit) in line["compared"].items():
+        print(f"compared {name}: {value} (limit {limit})", file=sys.stderr)
     sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0

@@ -9,6 +9,7 @@ import sys
 from pathlib import Path
 
 import jax
+import pytest
 
 from benchmark import run as bm
 
@@ -65,6 +66,19 @@ def test_gen_driver_counts_every_token_and_compiles_nothing_in_the_window(tmp_pa
     assert c["window_s"] >= 1.0 and c["requests_completed"] > 0
     assert sum(detail["notes"]["itl_histogram"]["counts"]) <= c["tokens"]
     assert detail["check"]["positions"] == 8 and detail["check"]["ok"]
+    # what the served yardsticks count from: positions seen by the window's
+    # tokens (a token sees at least its prompt and BOS, at most the request),
+    # the steps launched ahead, the prefill passes and their real rows
+    assert 5 * c["tokens"] <= c["decode_context_sum"] <= 27 * c["tokens"]
+    assert c["decode_window_rows_sum"] == c["decode_context_sum"]  # under 2 windows
+    assert 0 < c["prefill_window_rows_sum"] <= c["prefill_context_sum"]
+    assert 0 < c["decode_steps_ahead"] <= c["decode_steps"]
+    assert c["prefill_blocks"] > 0 and 0 < c["prefill_real_row_share"] <= 1
+    assert set(line["compared"]) == {
+        "logits_rms_over_std", "logits_max_over_std", "wrong_output_lengths",
+        "compiles_in_window"}
+    assert all(v <= lim for v, lim in line["compared"].values())
+    assert list(line)[-1] == "compared"  # the key comes last
 
 
 def test_gen_driver_traced_reports_the_per_layer_metrics_it_can(tmp_path):
@@ -72,7 +86,11 @@ def test_gen_driver_traced_reports_the_per_layer_metrics_it_can(tmp_path):
     assert_contract_line(line, "large.gen-closed", 1)
     got = set(line["metrics"])
     assert {"sched.occupancy", "sched.ttft_p50_s", "sched.host_ms_per_step",
-            "engine.compiles_in_window"} <= got
+            "engine.compiles_in_window", "sched.steps_ahead_share"} <= got
+    assert 50 < line["metrics"]["sched.steps_ahead_share"]["value"] <= 100
+    # a CPU has no peak: no share of one, whatever the counters say
+    assert not {"serve.mfu", "engine.decode_roofline",
+                "engine.prefill_roofline"} & got
     # no device in the trace on a CPU: those readers find nothing to read
     assert not {"device.idle_share", "engine.decode_device_ms",
                 "engine.prefill_ms_per_tok"} & got
@@ -82,17 +100,73 @@ def test_gen_driver_traced_reports_the_per_layer_metrics_it_can(tmp_path):
     assert spans["journal"] > spans["sched.step"]  # a line per token
 
 
-def test_score_driver_counts_real_tokens_only(tmp_path):
+def bucketed(real):
+    """A scorer stub with two buckets, as bucketed scoring would have:
+    short and long records fill batches of their own through the real
+    scorer, the two ragged tails are flushed last, and the output is
+    written group by group — so batches are not all full and the
+    stream's first record stands wherever its group put it."""
+    def run_batch_score(model, params, records, out_dir, *, batch_size, **kw):
+        os.makedirs(out_dir, exist_ok=True)
+        total = {"tokens": 0, "batches": 0, "n_scored": 0, "n_skipped": 0,
+                 "times": {"step": 0.0, "compile": 0.0, "write": 0.0, "data": 0.0}}
+        lines, groups, n_calls = {False: [], True: []}, {False: [], True: []}, 0
+
+        def flush(long):
+            nonlocal n_calls
+            part = os.path.join(out_dir, f"part{n_calls}")
+            n_calls += 1
+            summary = real(model, params, iter(groups[long]), part,
+                           batch_size=batch_size, **kw)
+            groups[long] = []
+            for shard in sorted(Path(part).glob("scores-*.jsonl")):
+                lines[long] += shard.read_text().splitlines()
+            for key in ("tokens", "batches", "n_scored", "n_skipped"):
+                total[key] += summary[key]
+            for key in total["times"]:
+                total["times"][key] += summary["times"][key]
+
+        for rid, raw in records:
+            long = len(raw) > 35
+            groups[long].append((rid, raw))
+            if len(groups[long]) == batch_size:
+                flush(long)
+        for long in (True, False):  # the ragged tails, the long ones first
+            if groups[long]:
+                flush(long)
+        Path(out_dir, "scores-00000.jsonl").write_text(
+            "".join(line + "\n" for line in lines[True] + lines[False]))
+        return total
+
+    return run_batch_score
+
+
+@pytest.mark.parametrize("scorer", ["todays", "two_ragged_groups"])
+def test_score_driver_counts_real_tokens_only(tmp_path, monkeypatch, scorer):
+    from progen_tpu.workloads import scoring
+
+    if scorer == "two_ragged_groups":
+        monkeypatch.setattr(scoring, "run_batch_score",
+                            bucketed(scoring.run_batch_score))
     line, detail = rehearse("large.score-batch", tmp_path, 0, batch_size=4,
                             lengths=[10, 20, 30, 40, 50, 60])
     assert_contract_line(line, "large.score-batch", 0)
     c = detail["counters"]
     assert line["correct"] and line["failed"] == 0
-    assert c["records"] == c["batches"] * 4 == line["attempted"]
+    # every record once, whatever the batches: full ones from today's
+    # scorer, ragged tails allowed from one with buckets
+    assert c["records"] == line["attempted"] == c["records_written"] > 0
+    assert c["records_amiss"] == 0 and c["records"] % 4 == 0  # whole batches fed
+    assert c["records"] <= c["batches"] * 4
+    if scorer == "todays":
+        assert c["records"] == c["batches"] * 4
+    else:
+        assert c["batches"] * 4 - c["records"] <= 2 * 3  # two tails at most
     # 35 bytes a record on average plus its EOS; padding to 64 is not counted
     assert 30 * c["records"] < c["tokens"] < 42 * c["records"]
     assert c["engine_compiles_in_window"] == 0
-    assert detail["check"]["ok"]
+    assert detail["check"]["ok"] and detail["check"]["record"] == "r0"
+    assert line["compared"]["records_missing_or_twice"] == [0, 0]
 
 
 def test_train_driver_counts_whole_steps(tmp_path):

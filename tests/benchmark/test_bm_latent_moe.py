@@ -53,61 +53,28 @@ def test_the_manifest_with_the_new_cell_is_consistent():
     assert entry["reduced"] == ["num_hidden_layers"]
 
 
-ADDED = ["serve.mfu", "engine.decode_roofline", "engine.prefill_roofline",
-         "moe.experts_touched", "moe.load_max_over_mean"]
+def test_what_this_cell_added_is_still_there():
+    """This cell's own entries, guarded by presence as every PR that adds
+    a cell guards its own: the five metrics it brought list it, and it
+    stands on every list of the served path (``bm_floor.served_lists``
+    holds the general rule). What else the manifest holds, and how much,
+    is for ``bm_floor`` and the cells that came later."""
+    import bm_floor
 
-
-def test_what_the_manifest_holds_now():
-    """EVERY assertion of ``test_bm_manifest.py::test_the_four_cells_and_
-    their_chips`` and ``test_bm_program_stages.py::test_the_manifest_is_
-    clean_with_the_twelve_entries_appended``, line for line, with only the
-    three facts this cell moves brought up to date: the set of cells, the
-    count of per-layer metrics (so the twelve are no longer the last), and
-    this cell's name appended to the served path's ``workloads`` lists
-    (conftest.py says why those two cannot be edited and are marked)."""
-    from test_bm_program_stages import NEW, RING
-
-    man = json.loads((ROOT / "BENCHMARK.json").read_text())
-    # test_the_four_cells_and_their_chips
-    chips = {w["name"]: w["chips"] for w in man["workloads"]}
-    assert chips == {"large.gen-closed": 1, "large.score-batch": 1,
-                     "long8k.train": 1, "large.train-dp2tp2": 4,
-                     CELL: 1}  # was: the four
-    assert man["command"] == ["python3", "-m", "benchmark.run"]
-    assert man["paths"] == ["benchmark", "tests/benchmark"]
-    # test_the_manifest_is_clean_with_the_twelve_entries_appended
-    assert manifest.check(ROOT) == []
-    names = [m["name"] for m in man["per_layer"]]
-    # was: names[-12:] == NEW and len(names) == 28
-    assert names[-17:-5] == NEW and names[-5:] == ADDED and len(names) == 33
-    by = {m["name"]: m for m in man["per_layer"]}
-    for name in RING:
-        cell = "large.score-batch" if name.startswith("score.") else "large.gen-closed"
-        # was: == [cell]
-        assert by[name]["workloads"] == (
-            [cell] if name.startswith("score.") else [cell, CELL])
-        assert by[name]["source"] == "program_span"
-    for name in ("env.compile_load_s", "env.cache_misses"):
-        assert "workloads" not in by[name] and by[name]["moves"] == "setup_s"
-        assert by[name]["source"] == "program_counter"
-    assert all(by[n]["better"] == "lower" for n in NEW)
-    # a metric is filed under the layer whose code its stage times
-    assert {n: by[n]["layer"] for n in ("sched.admit_ms", "engine.prepare_ms")} == {
-        "sched.admit_ms": "scheduler", "engine.prepare_ms": "engine"}
-    # and what this PR appended: five metrics of this cell alone, and the
-    # cell's name behind large.gen-closed on every list that had it
-    both = man["end_to_end"] + man["per_layer"]
-    for m in both:
-        if m["name"] in ADDED:
-            assert m["workloads"] == [CELL]
-        elif CELL in m.get("workloads", []):
-            assert m["workloads"] == ["large.gen-closed", CELL]
-    assert sum(CELL in m.get("workloads", []) for m in both) == 20 + 5
-    for m in both:  # nothing else of an accepted list changed
-        if "large.gen-closed" in m.get("workloads", []):
-            assert m["workloads"] == ["large.gen-closed", CELL]
+    assert bm_floor.faults(ROOT) == []
+    man = bm_floor.load(ROOT)
+    by = {m["name"]: m for m in man["end_to_end"] + man["per_layer"]}
+    for name in bm_floor.ADDED:
+        assert CELL in by[name]["workloads"]
+    for name in ("moe.experts_touched", "moe.load_max_over_mean"):
+        assert by[name]["workloads"][0] == CELL  # the family's own
+    for name in ("serve_tok_s_chip", "itl_p50_s", "itl_p95_s"):
+        assert by[name]["workloads"][:2] == ["large.gen-closed", CELL]
+    shared = [m for m in man["per_layer"]
+              if "large.gen-closed" in m.get("workloads", [])]
+    assert len(shared) >= 20 and all(CELL in m["workloads"] for m in shared)
     for name in ("env.compile_s", "env.compile_load_s", "env.cache_misses"):
-        assert "workloads" not in by[name]
+        assert "workloads" not in by[name]  # every cell, this one too
 
 
 def test_the_configuration_keeps_every_published_width():
@@ -158,7 +125,7 @@ def test_gen_driver_traced_reports_the_per_layer_metrics_it_can(tmp_path):
 
 
 def test_the_yardsticks_follow_the_counters():
-    from benchmark.readers import latent_moe_yardsticks as y
+    from benchmark.readers import served_yardsticks as y
 
     cfg = json.loads((ROOT / "benchmark/configs/kanana2-30b-a3b.json").read_text())
     # the issue's own arithmetic, by part
@@ -177,6 +144,7 @@ def test_the_yardsticks_follow_the_counters():
                     "prefill_tokens": 512, "prefill_context_sum": 131328,
                     "moe_experts_touched": 7000, "window_s": 0.25, "chips": 1}
 
+    assert y.counts_for(cfg).__name__ == "benchmark.counts.latent_moe"
     mfu = y.read(Run, {"what": "mfu"})
     assert 0 < mfu < 100
     assert y.read(Run, {"what": "decode", "match": "^jit__decode_step"}) is None

@@ -1,13 +1,18 @@
-"""BENCHMARK.json against the benchmark's files, and the proof that a later
-PR can add a configuration, a traffic mix, a driver, a metric and a reader
-as new files plus new entries, editing no file that is there."""
+"""BENCHMARK.json against the benchmark's files; what was accepted, held
+as a floor and not as a census (``bm_floor.py``); and the proof that a
+later PR can add a served cell of a new family — a configuration, a
+traffic mix, a driver, a count module, a metric and a reader — as new
+files plus new entries, editing no file that is there, with the
+manifest's check and the floor both passing."""
 
 import json
 import shutil
+import sys
 from pathlib import Path
 
 import pytest
 
+import bm_floor
 from benchmark import manifest
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -32,13 +37,20 @@ def test_the_manifest_as_committed_is_consistent():
     assert manifest.check(ROOT) == []
 
 
-def test_the_four_cells_and_their_chips():
-    man = json.loads((ROOT / "BENCHMARK.json").read_text())
-    chips = {w["name"]: w["chips"] for w in man["workloads"]}
-    assert chips == {"large.gen-closed": 1, "large.score-batch": 1,
-                     "long8k.train": 1, "large.train-dp2tp2": 4}
-    assert man["command"] == ["python3", "-m", "benchmark.run"]
-    assert man["paths"] == ["benchmark", "tests/benchmark"]
+def test_the_accepted_cells_and_their_chips():
+    """The five accepted cells lead ``workloads`` with the configuration,
+    traffic and chips they were accepted with; more may follow, to 24."""
+    assert bm_floor.accepted_cells(ROOT) == []
+    man = bm_floor.load(ROOT)
+    assert [w["name"] for w in man["workloads"]][:5] == [
+        "large.gen-closed", "large.score-batch", "long8k.train",
+        "large.train-dp2tp2", "kanana2-30b-a3b.gen-chat"]
+    assert {w["name"]: w["chips"] for w in man["workloads"]}[
+        "large.train-dp2tp2"] == 4
+
+
+def test_the_floor_holds_on_the_manifest_as_committed():
+    assert bm_floor.faults(ROOT) == []
 
 
 def test_every_file_under_benchmark_is_named_from_allowed_characters():
@@ -48,37 +60,115 @@ def test_every_file_under_benchmark_is_named_from_allowed_characters():
         assert all(c.isalnum() or c in "_.-" for c in p.name), p
 
 
-def test_one_of_each_can_be_added_as_new_files_and_entries(copy):
-    before = {p: p.read_bytes() for p in (copy / "benchmark").rglob("*") if p.is_file()}
-    b = copy / "benchmark"
-    (b / "configs" / "base.json").write_text(json.dumps({"dim": 1024}))
-    (b / "traffic" / "embed-burst.json").write_text(json.dumps({"driver": "embed"}))
-    (b / "drivers" / "embed.py").write_text("def run(run):\n    return {}\n")
-    (b / "metrics" / "embed.rows_per_call.json").write_text(
+def add_a_served_cell_of_a_new_family(root):
+    """What a ``model_config`` PR brings for a served cell of a made-up
+    family, as new files and appended entries alone."""
+    b = root / "benchmark"
+    (b / "configs" / "hybrid-7b.json").write_text(json.dumps(
+        {"family": "hybrid", "hidden_size": 4096, "num_hidden_layers": 16}))
+    (b / "traffic" / "gen-long.json").write_text(json.dumps(
+        {"driver": "gen_hybrid", "prompt_lengths": [4096, 8192]}))
+    (b / "drivers" / "gen_hybrid.py").write_text("def run(run):\n    return {}\n")
+    (b / "reference" / "hybrid_ref.py").write_text("def forward(p, t, c):\n    return None\n")
+    (b / "counts" / "hybrid.py").write_text(
+        "def window_flops(c, k):\n    return 2.0 * c['hidden_size'] * k['tokens']\n"
+        "def decode_need(c, k):\n    return 1.0, 2.0\n"
+        "def prefill_need(c, k):\n    return None\n")
+    (b / "metrics" / "state.rows_per_step.json").write_text(
         json.dumps({"reader": "rows"}))
-    (b / "metrics" / "embed_p95_s.json").write_text(json.dumps({"reader": "percentile"}))
     (b / "readers" / "rows.py").write_text("def read(run, spec):\n    return None\n")
+    cell = "hybrid-7b.gen-long"
 
     def add(man):
-        man["configs"].append({"name": "base", "source": "https://example.org/base",
-                               "file": "benchmark/configs/base.json",
-                               "reduced": [], "why": "the width between the two"})
-        man["workloads"].append({"name": "base.embed-burst", "config": "base",
-                                 "traffic": "embed-burst", "chips": 1,
-                                 "why": "embeddings in bursts of 8"})
-        man["end_to_end"].append({"name": "embed_p95_s", "unit": "s",
-                                  "better": "lower", "bound": 0.03,
-                                  "source": "host_clock",
-                                  "workloads": ["base.embed-burst"]})
-        man["per_layer"].append({"name": "embed.rows_per_call", "unit": "count",
-                                 "better": "higher", "source": "program_counter",
-                                 "layer": "engine", "moves": "embed_p95_s",
-                                 "workloads": ["base.embed-burst"]})
+        man["configs"].append({"name": "hybrid-7b", "source": "https://example.org/hybrid",
+                               "file": "benchmark/configs/hybrid-7b.json",
+                               "reduced": ["num_hidden_layers"],
+                               "why": "linear and sparse attention layers in whole periods"})
+        man["workloads"].append({"name": cell, "config": "hybrid-7b",
+                                 "traffic": "gen-long", "chips": 1,
+                                 "why": "long prompts, so the recurrent state does the work"})
+        for m in man["end_to_end"] + man["per_layer"]:
+            if bm_floor.SERVED in m.get("workloads", []):
+                m["workloads"].append(cell)  # every list of the served path
+        man["per_layer"].append({"name": "state.rows_per_step", "unit": "count",
+                                 "better": "lower", "source": "program_counter",
+                                 "layer": "model", "moves": "itl_p50_s",
+                                 "workloads": [cell]})
 
-    edit(copy, add)
+    edit(root, add)
+    return cell
+
+
+def test_a_served_cell_of_a_new_family_is_added_as_new_files_and_entries(copy):
+    before = {p: p.read_bytes() for p in (copy / "benchmark").rglob("*") if p.is_file()}
+    accepted = bm_floor.load(copy)
+    cell = add_a_served_cell_of_a_new_family(copy)
     assert manifest.check(copy) == []
+    assert bm_floor.faults(copy) == []
     after = {p: p.read_bytes() for p in before}
     assert after == before  # nothing that was there was edited
+    # every accepted list is a prefix of the new one, never its whole
+    man = bm_floor.load(copy)
+    for kind in ("configs", "workloads", "end_to_end", "per_layer"):
+        assert [x["name"] for x in man[kind]][:len(accepted[kind])] == [
+            x["name"] for x in accepted[kind]]
+    for was, now in zip(accepted["end_to_end"] + accepted["per_layer"],
+                        man["end_to_end"] + man["per_layer"]):
+        if "workloads" in was:
+            assert now["workloads"][:len(was["workloads"])] == was["workloads"]
+    # the cell reports the served rate, both gaps and every metric of the
+    # scheduler and the engine that the first served cell reports
+    mine = {m["name"] for m in man["end_to_end"] + man["per_layer"]
+            if cell in m.get("workloads", [cell])}
+    theirs = {m["name"] for m in man["end_to_end"] + man["per_layer"]
+              if bm_floor.SERVED in m.get("workloads", [bm_floor.SERVED])}
+    assert theirs <= mine and "state.rows_per_step" in mine - theirs
+
+
+def test_the_yardsticks_read_the_new_familys_counts_by_its_name(copy, monkeypatch):
+    import importlib.util
+    import types
+
+    from benchmark.readers import served_yardsticks
+
+    add_a_served_cell_of_a_new_family(copy)
+    spec = importlib.util.spec_from_file_location(
+        "benchmark.counts.hybrid", copy / "benchmark" / "counts" / "hybrid.py")
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    config = json.loads((copy / "benchmark/configs/hybrid-7b.json").read_text())
+    run = types.SimpleNamespace(
+        config=config, trace=None, notes={},
+        peak={"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
+        counters={"tokens": 1e9, "window_s": 2.0, "chips": 1})
+    assert served_yardsticks.read(run, {"what": "mfu"}) is None  # no module yet
+    monkeypatch.setitem(sys.modules, "benchmark.counts.hybrid", module)
+    mfu = served_yardsticks.read(run, {"what": "mfu"})
+    assert mfu == pytest.approx(100 * 2.0 * 4096 * 1e9 / (2.0 * 197e12))
+    assert served_yardsticks.read(run, {"what": "prefill", "match": "^jit"}) is None
+
+
+@pytest.mark.parametrize("metric", ["sched.occupancy", "serve.mfu",
+                                    "engine.idle_ms_per_step", "itl_p95_s"])
+def test_a_served_cell_left_off_one_list_fails_a_rule(copy, metric):
+    cell = add_a_served_cell_of_a_new_family(copy)
+
+    def drop(man):
+        m = next(m for m in man["end_to_end"] + man["per_layer"]
+                 if m["name"] == metric)
+        m["workloads"].remove(cell)
+
+    edit(copy, drop)
+    faults = manifest.check(copy) + bm_floor.faults(copy)
+    assert any(metric in f or cell in f for f in faults), faults
+
+
+def test_the_floor_sees_an_accepted_cell_or_metric_taken_away(copy):
+    edit(copy, lambda m: m["workloads"].reverse())
+    assert any("prefix" in f for f in bm_floor.accepted_cells(copy))
+    edit(copy, lambda m: m["per_layer"].pop(
+        next(i for i, x in enumerate(m["per_layer"]) if x["name"] == "serve.mfu")))
+    assert any("serve.mfu is gone" in f for f in bm_floor.accepted_metrics(copy))
 
 
 @pytest.mark.parametrize("name,break_it,fault", [
