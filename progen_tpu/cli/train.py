@@ -292,8 +292,10 @@ def main(
     if model_kwargs.get("family", "progen") != "progen":
         sys.exit(
             f"cli.train trains the progen family only: "
-            f"{model_kwargs['family']!r} has expert layers, and "
-            f"training/step.py has no grouped backward for them"
+            f"{model_kwargs['family']!r} is served by cli.serve alone "
+            f"(training/step.py has neither a grouped backward for expert "
+            f"layers nor a backward through a recurrent state or a block "
+            f"selection; ROADMAP.md, Reach 3)"
         )
     model_kwargs.setdefault("seq_len", seq_len)
     # reference semantics (train.py:53,106): full f32 unless --mixed_precision

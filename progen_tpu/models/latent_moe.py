@@ -262,6 +262,31 @@ def _init():
     return nn.initializers.normal(stddev=0.02)  # the published range
 
 
+def feed_blocks(model, params, cache, tokens, lo, hi):
+    """``feed_tokens`` of a family whose decode mode takes positions and
+    ``live`` as arguments: the aligned blocks of ``config.feed_rows``
+    positions that ``[lo, hi)`` touches, each one ``model.apply`` without
+    the head; rows of a block outside the range are not live, and rows
+    past the buffer's end feed any token."""
+    t = model.config.feed_rows
+    b, last = tokens.shape[0], tokens.shape[-1] - 1
+
+    def feed(blk, cache):
+        at = blk * t + jnp.arange(t)
+        live = (at >= lo) & (at < hi)
+        _, mut = model.apply(
+            {"params": params, "cache": cache},
+            tokens[:, jnp.minimum(at, last)],
+            jnp.broadcast_to(at, (b, t)), jnp.broadcast_to(live, (b, t)),
+            head=False, mutable=["cache"],
+        )
+        return mut["cache"]
+
+    return jax.lax.fori_loop(
+        lo // t, jnp.where(hi > lo, -(-hi // t), lo // t), feed, cache
+    )
+
+
 class LatentAttention(nn.Module):
     config: LatentMoEConfig
 
@@ -394,6 +419,9 @@ class LatentMoE(nn.Module):
     # the serving pool hands the decode step ALL slots as one batch with a
     # position per row (routing must see every live slot at once)
     slot_batched = True
+    # the pool's state by kind, for ``ServeEngine.state_bytes``: every
+    # leaf of this family's cache counts as latent cache
+    cache_kinds = {"*": "latent_cache_bytes"}
 
     @nn.compact
     def __call__(self, tokens, positions=None, live=None, head: bool = True):
@@ -462,23 +490,7 @@ class LatentMoE(nn.Module):
         decode cache in aligned blocks of ``FEED_ROWS`` positions, one
         pass over the weights a block and no head (``sampling.feed_tokens``
         is the contract: traced bounds, bit-equal under any split)."""
-        t = FEED_ROWS
-        b, last = tokens.shape[0], tokens.shape[-1] - 1
-
-        def feed(blk, cache):
-            at = blk * t + jnp.arange(t)
-            live = (at >= lo) & (at < hi)
-            _, mut = self.apply(
-                {"params": params, "cache": cache},
-                tokens[:, jnp.minimum(at, last)],
-                jnp.broadcast_to(at, (b, t)), jnp.broadcast_to(live, (b, t)),
-                head=False, mutable=["cache"],
-            )
-            return mut["cache"]
-
-        return jax.lax.fori_loop(
-            lo // t, jnp.where(hi > lo, -(-hi // t), lo // t), feed, cache
-        )
+        return feed_blocks(self, params, cache, tokens, lo, hi)
 
     def decode_slots(self, params, cache, toks, pos, live):
         """One token for every slot of a pool whose cache leaves are

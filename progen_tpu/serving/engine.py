@@ -386,14 +386,18 @@ class ServeEngine:
                 f"got {self.max_len}"
             )
         self.max_slots = int(max_slots)
-        # a family whose decode step takes all slots as one batch
-        # (models/latent_moe.py); what it cannot do is refused by name
+        # a family whose decode step takes all slots as one batch (a
+        # model that says ``slot_batched``); what it cannot do is
+        # refused by name
         self.slot_batched = bool(getattr(model, "slot_batched", False))
         if quantize_int8 and self.slot_batched:
             raise ValueError(
                 f"{type(model).__name__} cannot be served in int8: "
-                f"ops/quant.py quantizes 2-D kernels per output channel "
-                f"and knows no stacked expert weights"
+                f"ops/quant.py quantizes the 2-D ``kernel`` leaves of "
+                f"ProGen's Dense layers per output channel; a slot-batched "
+                f"family names its weights itself (stacked expert weights "
+                f"among them) and is served in the type its configuration "
+                f"states"
             )
         self.model, self.params, self.fresh_cache = _decode_setup(
             model, params, batch=1, max_len=self.max_len
@@ -602,9 +606,12 @@ class ServeEngine:
         if self.slot_batched:
             raise ValueError(
                 f"{type(self.model).__name__} cannot take a prefix cache: "
-                f"a snapshot of its state is max_len latent rows a layer "
-                f"whatever the prefix's depth, and prefix_cache.py budgets "
-                f"whole cache trees"
+                f"a snapshot of a slot-batched family's state is its whole "
+                f"batch-1 cache tree, max_len rows a layer whatever the "
+                f"prefix's depth and, where part of it is a recurrence, "
+                f"good at the depth it was stored at alone, while "
+                f"prefix_cache.py budgets whole cache trees and resumes "
+                f"from any shorter prefix"
             )
         self._prefix_cache = cache
 
@@ -923,10 +930,13 @@ class ServeEngine:
             ),
             "served_leaves_cast": sum(self._cast),
         }
-        if self.slot_batched:
-            out["latent_cache_bytes"] = sum(
-                leaf.nbytes for leaf in jax.tree.leaves(self.slots.cache)
-            )
+        # the pool's state by kind, where the family names kinds: a cache
+        # leaf's name -> the gauge that counts its bytes ("*": any other)
+        kinds = getattr(self.model, "cache_kinds", None) or {}
+        for path, leaf in jax.tree_util.tree_leaves_with_path(self.slots.cache):
+            gauge = kinds.get(path[-1].key, kinds.get("*"))
+            if gauge:
+                out[gauge] = out.get(gauge, 0) + leaf.nbytes
         return out
 
     def collect(self, slot: int) -> np.ndarray:

@@ -85,6 +85,43 @@ HELP = {
         "Bytes of the slot pool's latent attention cache (rows of "
         "kv_lora_rank + qk_rope_head_dim values per layer and position)"
     ),
+    "sparse_layer_steps": (
+        "Block-sparse attention layers run by decode steps (steps x sparse "
+        "layers): the divisor of sparse_rows_visible, sparse_rows_attended "
+        "and sparse_blocks_selected per layer and step"
+    ),
+    "sparse_rows_visible": (
+        "Cached rows the live slots' queries could see (position + 1), "
+        "summed over sparse layers and decode steps"
+    ),
+    "sparse_rows_attended": (
+        "Cached rows the live slots' queries attended, a key/value group: "
+        "the rows up to the query of its chosen blocks (all it could see "
+        "below dense_len), summed over sparse layers and decode steps"
+    ),
+    "sparse_blocks_selected": (
+        "Blocks those attended rows lay in, a key/value group, summed over "
+        "sparse layers and decode steps"
+    ),
+    "sparse_feed_layer_blocks": (
+        "Sparse layers run by prefill blocks (blocks x sparse layers); "
+        "sparse_feed_rows_visible, sparse_feed_rows_attended and "
+        "sparse_feed_blocks_selected are summed over their live rows, "
+        "reported with the decode step that follows"
+    ),
+    "kv_cache_bytes": (
+        "Bytes of the slot pool's key and value rows (sparse attention "
+        "layers: max_len rows a slot, layer and key/value group)"
+    ),
+    "index_cache_bytes": (
+        "Bytes of the slot pool's pooled keys, the index the block "
+        "selection scores (one a kernel_stride rows)"
+    ),
+    "linear_state_bytes": (
+        "Bytes of the slot pool's recurrent states (linear-attention "
+        "layers: heads x head_dim x head_dim float32 a slot and layer, "
+        "whatever the context)"
+    ),
     "raw_weight_bytes": (
         "Bytes of the parameter tree as handed to the engine, in the "
         "checkpoint's type (embeddings, int8 quantization and a reload's "
