@@ -25,6 +25,10 @@ from flax import linen as nn
 
 from progen_tpu.config import ProGenConfig
 from progen_tpu.ops.attention import local_attention
+from progen_tpu.ops.pallas_decode_attention import (
+    decode_attention,
+    ring_attention,
+)
 from progen_tpu.ops.rotary import apply_rotary_pos_emb
 from progen_tpu.ops.sgu import causal_sgu_mix
 from progen_tpu.ops.shift import shift_tokens
@@ -357,6 +361,19 @@ class LocalAttentionBlock(nn.Module):
         zero-score/zero-value keys via an analytic denominator
         correction — the reference's zero-padded previous window
         (progen.py:90-96) without materializing it.
+
+        The arithmetic is ``ops/pallas_decode_attention.py``'s. A block
+        of T > 1 rows (a prefill chunk, one slot's ring) and any
+        unbatched one-token call (``sample_fast``) run its plain form,
+        ``ring_attention``: scores over the whole ring, masked
+        afterwards. The one-token call under the serving pool's ``vmap``
+        over its slots — the engine's decode step — goes through
+        ``decode_attention``, whose batching rule on a TPU is a kernel
+        that reads, per slot, only the ring blocks in which that slot's
+        query sees a row (the blocks listed from this cache's own
+        ``slot_pos``), where the head size and window fit its tiling;
+        off the TPU, and at the shapes of the tests' small models, the
+        rule is the vmapped plain form.
         """
         c = self.config
         b, h, _, dh = q.shape
@@ -380,31 +397,13 @@ class LocalAttentionBlock(nn.Module):
             cv.value = _write_rows(cv.value, v, slot, 2, rows)
             cpos.value = _write_rows(cpos.value, pos, slot, 0, rows)
 
-        slot_pos = cpos.value
-        visible = (
-            (slot_pos >= 0)
-            & (slot_pos <= pos[:, None])
-            & (pos[:, None] // w - slot_pos // w <= 1)
-        )  # (T, ring)
-        scores = jnp.einsum(
-            "bhqd,bhkd->bhqk", q, ck.value,
-            preferred_element_type=jnp.float32,
-        ) * (dh ** -0.5)
-        scores = jnp.where(visible[None, None], scores, -1e10)
-
-        first_window = (pos < w).astype(jnp.float32)[:, None]  # (T, 1)
-        # softmax with analytic phantom-key dilution: shift-invariant, so a
-        # stable max including the phantoms' score 0 is fine
-        m = jnp.maximum(
-            scores.max(axis=-1, keepdims=True),
-            jnp.where(first_window > 0, 0.0, -jnp.inf),
-        )
-        e = jnp.exp(scores - m)
-        denom = e.sum(axis=-1, keepdims=True) + first_window * w * jnp.exp(-m)
-        out = jnp.einsum(
-            "bhqk,bhkd->bhqd", e, cv.value.astype(jnp.float32)
-        ) / denom
-        return out.astype(q.dtype)
+        if q.shape[2] == 1:
+            # the decode step: under the pool's ``vmap`` over its slots
+            # this reads, per slot, the ring blocks its query sees
+            return decode_attention(w)(
+                q, ck.value, cv.value, cpos.value, pos
+            )
+        return ring_attention(q, ck.value, cv.value, cpos.value, pos, w)
 
 
 class SpatialGatingUnit(nn.Module):

@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from progen_tpu.ops.pallas_decode_attention import kernel_block, listed_rows
 from progen_tpu.ops.quant import QuantizedParams, dequantized, quantize_tree
 from progen_tpu.sampling import (
     _TOP_P_OFF,
@@ -204,7 +205,16 @@ def _decode_step_impl(model, params, slots: SlotBatch):
     as traffic churns. Returns (new_slots, sampled, was_live, finished);
     ``finished`` flags slots that JUST hit EOS (second zero) or their
     requested length this step. Un-jitted body of ``_decode_step``
-    (tests/test_served_tree.py traces it on its own)."""
+    (tests/test_served_tree.py traces it on its own). The ``vmap`` over
+    the slots is also what picks the attention of a ProGen step: on a
+    TPU each attention layer is one kernel over the pool that reads
+    every slot's LIVE ring blocks only
+    (``ops/pallas_decode_attention.py``: the blocks a slot's query sees,
+    listed from the cache's own ``slot_pos``; dead slots read what their
+    counters say, like any other); off the TPU, or where a model's head
+    size or window does not fit the kernel's tiling, it is the plain
+    form over whole rings. ``ServeEngine._count_ring_rows`` keeps the
+    host's account of it."""
     n_slots, length = slots.seqs.shape
     pos = jnp.clip(slots.cur, 0, length - 1)
     if getattr(model, "slot_batched", False):
@@ -903,10 +913,31 @@ class ServeEngine:
             same = occupant == self._occupant
             was_live, finished = was_live & same, finished & same
             wrote = np.flatnonzero(was_live)
+            if not self.slot_batched:
+                self._count_ring_rows(self._cur[wrote])
             self._cur[wrote] += 1
             self._rows[wrote, self._cur[wrote]] = sampled[wrote]
             self._live[finished] = False
             return sampled, was_live, finished
+
+    def _count_ring_rows(self, pos) -> None:
+        """What a decode step's attention read of the K/V rings of the
+        slots it advanced (queries at ``pos``), a layer: the ring blocks
+        the kernel lists for them, or whole rings where the step runs the
+        plain form. Host arithmetic on positions the host holds: the
+        quotient of the two counters is the share of the rings' rows a
+        step reads."""
+        c = self.model.config
+        ring = 2 * c.window_size
+        block = kernel_block(
+            c.window_size, c.heads, c.dim_head, c.compute_dtype
+        )
+        held = ring * len(pos)
+        read = held if block is None else int(
+            listed_rows(pos, c.window_size, ring, block).sum()
+        )
+        for name, by in (("ring_rows_read", read), ("ring_rows_held", held)):
+            self._counters[name] = self._counters.get(name, 0) + by
 
     def pop_counters(self) -> dict:
         """Counter increments gathered since the last call (empty for a

@@ -59,6 +59,16 @@ HELP = {
         "positions (128 at most, the largest divisor of window_size) of "
         "which prefill_tokens / (prefill_blocks * width) were real"
     ),
+    "ring_rows_read": (
+        "Rows of the live slots' K/V rings a decode step's attention read, "
+        "a layer, summed over decode steps: the ring blocks in which a "
+        "slot's query sees a row where the decode-attention kernel runs "
+        "(ops/pallas_decode_attention.py), whole rings where it does not"
+    ),
+    "ring_rows_held": (
+        "Rows of the live slots' K/V rings (live slots x 2 x window_size), "
+        "summed over decode steps: the divisor of ring_rows_read"
+    ),
     "moe_expert_layer_steps": (
         "Expert layers run by decode steps (steps x expert layers): the "
         "divisor of moe_experts_touched, moe_max_load_rows and "

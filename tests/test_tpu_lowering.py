@@ -223,3 +223,32 @@ class TestFusedLayerKernelsLowerForTpu:
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         mlir = _export_for_tpu(jax.grad(loss_fn), params, tokens).mlir_module()
         assert mlir.count("tpu_custom_call") >= 2  # norm-shift + SGU
+
+
+class TestDecodeAttentionLowering:
+    """The decode step's attention kernel over the slot pool
+    (ops/pallas_decode_attention.py) at the shapes it is served at."""
+
+    @pytest.mark.parametrize(
+        "slots,heads,dh,window,dtype",
+        [
+            (32, 14, 128, 512, jnp.bfloat16),  # ProGen-large, the gen cell
+            (8, 8, 64, 512, jnp.bfloat16),  # long8k served
+            (4, 2, 64, 128, jnp.float32),  # the engine test's model
+        ],
+    )
+    def test_pooled_kernel_lowers_for_tpu(self, slots, heads, dh, window,
+                                          dtype):
+        from progen_tpu.ops.pallas_decode_attention import (
+            pooled_decode_attention,
+        )
+
+        ring = 2 * window
+        kv = jnp.zeros((slots, 1, heads, ring, dh), dtype)
+        exp = _export_for_tpu(
+            functools.partial(pooled_decode_attention, window=window),
+            jnp.zeros((slots, heads, dh), dtype), kv, kv,
+            jnp.zeros((slots, ring), jnp.int32),
+            jnp.zeros((slots,), jnp.int32),
+        )
+        assert "tpu_custom_call" in exp.mlir_module()
