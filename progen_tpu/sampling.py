@@ -30,6 +30,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 EPS = 1e-20  # reference log() epsilon, utils.py:20
 
@@ -493,27 +494,33 @@ def sample_fast(
 
 def _decode_setup(model, params, batch: int, max_len=None):
     """(decode model, decode-layout params, fresh zeroed cache) for the
-    KV-cache paths. The cache skeleton comes from a trace-cached jitted
-    init (params creation inside init is dead-code-eliminated since only
-    the cache collection is returned), replicated on the params' mesh —
-    see _cache_init_fn. ``max_len`` bounds the cache of a family whose
-    state grows with the sequence (``models.decode_model``)."""
+    KV-cache paths; the cache is ``cache_builder``'s. ``max_len`` bounds
+    the cache of a family whose state grows with the sequence
+    (``models.decode_model``)."""
     from progen_tpu.models import decode_model, unstack_params
 
     dec_model = decode_model(model, max_len)
     # decode mode is always unrolled (per-layer caches); a scanned stacked
     # layout is converted, any other comes back as it is
     params = unstack_params(params, model.config)
+    return dec_model, params, cache_builder(dec_model, params, batch)()
+
+
+def cache_builder(dec_model, params, batch: int):
+    """The compiled builder of a decode model's initial cache: every call
+    returns a new tree. It comes from a trace-cached jitted init (params
+    creation inside init is dead-code-eliminated since only the cache
+    collection is returned), replicated on the params' mesh — see
+    _cache_init_fn."""
     param_leaf = next(
         (leaf for leaf in jax.tree.leaves(params) if isinstance(leaf, jax.Array)),
         None,
     )
     sharding = param_leaf.sharding if param_leaf is not None else None
     try:
-        init_fn = _cache_init_fn(dec_model, sharding, batch)
+        return _cache_init_fn(dec_model, sharding, batch)
     except TypeError:  # unhashable sharding: fall back to uncached
-        init_fn = _cache_init_fn.__wrapped__(dec_model, sharding, batch)
-    return dec_model, params, init_fn()
+        return _cache_init_fn.__wrapped__(dec_model, sharding, batch)
 
 
 # Rows of one prefill block, at most: a prompt goes through the cache in
@@ -545,6 +552,16 @@ def feed_block_count(width: int, lo: int, hi: int) -> int:
     return -(-hi // width) - lo // width if hi > lo else 0
 
 
+def _row_major(x):
+    """``x`` held to the row-major layout wherever the compiler would
+    choose one for it (inside a loop or a branch)."""
+    if x.ndim < 2:
+        return x
+    return with_layout_constraint(
+        x, Layout(major_to_minor=tuple(range(x.ndim)))
+    )
+
+
 def feed_tokens(model, params, cache, tokens, lo, hi):
     """Feed positions ``[lo, hi)`` of ``tokens`` ((B, L); the cache's
     ``pos`` must stand at ``lo``) through a decode cache in blocks of
@@ -560,7 +577,15 @@ def feed_tokens(model, params, cache, tokens, lo, hi):
     the cache after ``[0, hi)`` is bit-equal under every split. Rows of
     a block outside ``[lo, hi)`` are dead (``ProGen.__call__``).
     ``lo``/``hi`` are traced loop bounds, so ONE compiled program serves
-    every chunk size and resume depth."""
+    every chunk size and resume depth.
+
+    A block hands every cache leaf on in the layout it came in (row-major,
+    the layout a program's arguments and results have). Left to itself
+    the TPU compiler carries ProGen's K/V rings through the loop with
+    their rows innermost, which costs a conversion of every ring before
+    the loop and another after it, however few rows the blocks write
+    (ProGen-large on a v5e: 94 copies of 3.67 MB, 1.4 of a 16-token
+    chunk's 5.6 ms; PERF.md, PR 37). No value changes."""
     if getattr(model, "slot_batched", False):
         # positions are an argument of that family's decode mode, not a
         # counter in its cache: it runs the same aligned blocks itself
@@ -575,7 +600,7 @@ def feed_tokens(model, params, cache, tokens, lo, hi):
             {"params": params, "cache": cache}, tokens[:, at], hi,
             mutable=["cache"],
         )
-        return mut["cache"]
+        return jax.tree.map(_row_major, mut["cache"])
 
     return jax.lax.fori_loop(
         lo // t, jnp.where(hi > lo, -(-hi // t), lo // t), feed, cache

@@ -11,7 +11,11 @@ OSDI '22). All shapes are functions of (max_slots, max_len) only, so an
 engine's whole lifetime re-executes exactly three compiled programs:
 ``_prefill_chunk`` (a slice of a prompt through a batch-1 cache — the
 whole prompt when admission is unbudgeted), ``_prefill_finish`` (that
-cache and the request's state into the pool) and ``_decode_step``. The
+cache and the request's state into the pool) and ``_decode_step``. Each
+takes the state it rewrites DONATED — the pool, and the batch-1 cache
+of the admission in flight, which the engine owns from ``begin_prefill``
+to the slot's activation (see ``PendingPrefill``) — and nothing else of
+an admission runs on the device: its scalars ride as host values. The
 two that read weights take ``ServeEngine.served_params``: for a float
 engine the tree with every leaf the model would convert at each use held
 in the compute type, for an int8 engine the quantized pair
@@ -35,9 +39,9 @@ a leading slot axis and the decode step ``vmap``s the one-token apply
 over it, so every slot carries its own scalar ``pos`` (and its own ring
 indices, shift states, and gate history). Dead slots keep computing —
 static shapes are the point — on garbage caches; that is safe because
-an admission rewrites the slot's entire cache tree from a fresh zeroed
-template (NOT by zeroing in place: ``slot_pos`` initialises to -1)
-before the slot is ever read again.
+an admission rewrites the slot's entire cache tree from one that began
+as the model's own initial cache (NOT zeros: ``slot_pos`` initialises
+to -1) before the slot is ever read again.
 
 Sampling params ride as per-slot DATA (gumbel_step_dynamic), so one
 compiled step serves any mix of temperature/top_k/top_p. Each slot
@@ -65,6 +69,7 @@ from progen_tpu.sampling import (
     _prepare_seq,
     _validate_infill,
     _validate_knobs,
+    cache_builder,
     feed_block_count,
     feed_tokens,
     feed_width,
@@ -160,7 +165,9 @@ def _scatter_slot(
     )
 
 
-@functools.partial(jax.jit, static_argnames=("model",))
+@functools.partial(
+    jax.jit, static_argnames=("model",), donate_argnums=(2,)
+)
 def _prefill_chunk(model, params, cache, tokens, lo, hi):
     """One budgeted slice of a prefill: feed ``tokens[lo:hi]`` through an
     in-progress batch-1 cache, a block of positions per pass over the
@@ -171,30 +178,54 @@ def _prefill_chunk(model, params, cache, tokens, lo, hi):
     arbitrary ``lo``, mid-block: the rows before it are dead).
     ``params`` is ``ServeEngine.served_params``: a float tree, or the
     quantized pair of an int8 engine, dequantized here (XLA fuses
-    convert+scale into each consuming matmul). The cache is
-    deliberately NOT donated: the first chunk feeds the engine's
-    reusable ``fresh_cache`` zero template, and every chunk's input may
-    be a live prefix-cache snapshot — donation would invalidate both.
-    Batch-1 caches are small; the transient double-buffer is the price
-    of snapshot reuse."""
+    convert+scale into each consuming matmul). The cache (arg 2) is
+    DONATED: a chunk writes its rows into the buffers it was handed and
+    the call makes no buffer (ProGen-large on a v5e, the pool of 32
+    slots resident: 123 leaves, 206 MB, whose allocation was 7.6 of the
+    9.7 ms the undonated call took to enqueue; PERF.md, PR 37). Whoever
+    calls owns the tree it hands in and must not read it again: the
+    engine feeds a tree that is the admission's alone
+    (``PendingPrefill``), never one the prefix cache holds."""
     params = dequantized(params, model.config.compute_dtype)
     return feed_tokens(model, params, cache, tokens[None], lo, hi)
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
+@functools.partial(
+    jax.jit, static_argnames=("new_cache",), donate_argnums=(0, 1)
+)
 def _prefill_finish(slots, cache1, slot, tokens, start, target, key,
-                    temp, top_p, top_k, parity, template, frozen):
+                    temp, top_p, top_k, parity, template, frozen, *,
+                    new_cache):
     """Final step of a prefill: scatter the fully primed cache +
     per-slot state into the pool (the ONLY point an admission touches
     the pool — mid-chunk state lives outside it, so decode steps
-    between chunks never see a half-primed slot). The pool (``slots``)
-    is DONATED: every leaf is rebuilt and the caller immediately rebinds
-    ``self.slots`` to the result, so the old buffers alias the new ones
-    instead of doubling the pool's HBM footprint; ``cache1`` is not (it
-    may be a prefix-cache snapshot). No model arithmetic and no
-    weights."""
-    return _scatter_slot(slots, cache1, slot, tokens, start, target, key,
+    between chunks never see a half-primed slot). Both trees are
+    DONATED. The pool (``slots``): every leaf is rebuilt and the caller
+    immediately rebinds ``self.slots`` to the result, so the old buffers
+    alias the new ones instead of doubling the pool's HBM footprint.
+    The batch-1 cache (``cache1``): once scattered nobody reads it
+    again, so its buffers come back holding the model's INITIAL cache —
+    ``new_cache`` is the compiled builder of that tree
+    (``sampling.cache_builder``; static, and inlined here from the trace
+    it already has), whose values are constants, written over the spent
+    ones — and the next cold admission starts from them: an admission
+    allocates nothing (run as a program of its own the builder makes 123
+    buffers and takes 6.2 ms to enqueue where this call takes 3.3;
+    PERF.md, PR 37). Returns ``(pool, that tree)``. No model arithmetic
+    and no weights."""
+    pool = _scatter_slot(slots, cache1, slot, tokens, start, target, key,
                          temp, top_p, top_k, parity, template, frozen)
+    return pool, new_cache()
+
+
+@jax.jit
+def _copy_cache(cache):
+    """A second batch-1 tree with the first one's values, for the two
+    moments at which one tree would otherwise have two owners, the
+    prefix cache and an admission (``begin_prefill``'s hit,
+    ``advance_prefill``'s insert): one keeps the original, the other
+    takes this."""
+    return jax.tree.map(jnp.copy, cache)
 
 
 def _decode_step_impl(model, params, slots: SlotBatch):
@@ -316,6 +347,22 @@ def _decode_step(model, params, slots):
     return _decode_step_impl(model, params, slots)
 
 
+def seed_key(seed: int) -> np.ndarray:
+    """``jax.random.PRNGKey(seed)`` as host integers, computed on the
+    CPU backend where there is one: on the accelerator the few integer
+    operations would be programs of their own, queued behind the decode
+    step in flight, and reading them back would hold the caller until
+    that step is done. The journal writes these integers down; an
+    admission hands them to ``_prefill_finish`` with its other host
+    operands."""
+    try:
+        cpu = jax.devices("cpu")[0]
+    except RuntimeError:  # a process held to the accelerator's platform
+        cpu = None
+    with jax.default_device(cpu):
+        return np.asarray(jax.random.PRNGKey(seed))
+
+
 def _match_placement(new, live):
     """Give a reloaded leaf the SAME placement key as the live one. The
     jit fastpath cache keys on (aval, sharding, committed): checkpoint
@@ -340,26 +387,35 @@ class PendingPrefill:
     between chunks never observe a half-primed slot, and a crash
     mid-chunk loses nothing durable (the journal holds the accept; a
     replay re-runs the prefill from scratch or a prefix-cache hit).
-    ``pos`` counts prime positions already fed (the feed region is
-    ``0..start-2``; the last prime token is consumed by the first
-    decode step)."""
+    ``cache`` belongs to this admission ALONE from ``begin_prefill`` to
+    the slot's activation: every program that touches it takes it
+    donated and ``cache`` is rebound to what came back, so a reference
+    kept to an earlier value is dead. Where a tree would be shared with
+    the prefix cache one side gets a copy (``_copy_cache``, counted in
+    ``copies``). Everything else rides on the host until the
+    program that needs it: ``row`` goes to the device once, for the
+    chunks, the scalars, the key and the template rows with the
+    scatter's call. ``pos`` counts prime positions already fed (the feed
+    region is ``0..start-2``; the last prime token is consumed by the
+    first decode step)."""
 
     slot: int
     row: jnp.ndarray  # (max_len,) int32 padded token buffer
     host_row: np.ndarray  # the same buffer as the host built it
     start: int  # primed positions; feed region is row[0:start-1]
     length: int  # requested total length (the slot's target)
-    key: jnp.ndarray  # per-request PRNG key (untouched until scatter)
+    key: Any  # per-request PRNG key (host integers when made from a seed)
     temperature: float
     top_p_val: float  # _TOP_P_OFF when off
     top_k_val: int  # 0 when off
     parity: bool
-    trow: jnp.ndarray  # (max_len,) int32 infill template row
-    frow: jnp.ndarray  # (max_len,) bool infill frozen row
+    trow: np.ndarray  # (max_len,) int32 infill template row
+    frow: np.ndarray  # (max_len,) bool infill frozen row
     cache: Any  # batch-1 cache tree fed through ``pos`` positions
     pos: int = 0
     hit_depth: int = 0  # prefix-cache seed depth (0 = cold)
     blocks: int = 0  # feed blocks executed so far (``feed_block_count``)
+    copies: int = 0  # batch-1 trees copied for the prefix cache's sake
     request_id: str = ""
     done: bool = False
 
@@ -382,7 +438,21 @@ class ServeEngine:
     the host, so ``launch_step`` may enqueue one before its predecessor
     was fetched: at most one step is ever launched and unfetched.
     ``params`` is the tree as handed in; the programs take
-    ``served_params`` (see ``__init__``)."""
+    ``served_params`` (see ``__init__``).
+
+    Batch-1 caches: the engine owns the tree of every admission in
+    flight (``PendingPrefill.cache``) and hands it to the chunk program
+    and the scatter DONATED, so an admission copies and allocates
+    nothing. A cold admission starts from ``new_cache()``: the tree the
+    last scatter handed back with the model's initial values written
+    over it, or, when there is none (the first admission was that of
+    ``__init__``'s tree; a second admission in flight; a cancelled one),
+    a run of the program that made the first. Only a prefix cache makes
+    a tree shared, and there one side gets a copy: the insert at a chunk
+    boundary leaves the tree to the cache and the admission goes on with
+    a copy, a hit copies the stored tree for the admission
+    (``_copy_cache``; ``prefill_cache_copies`` counts them). Without a
+    prefix cache nothing is ever copied."""
 
     def __init__(self, model, params, *, max_slots: int = 8,
                  max_len: Optional[int] = None,
@@ -409,9 +479,13 @@ class ServeEngine:
                 f"among them) and is served in the type its configuration "
                 f"states"
             )
-        self.model, self.params, self.fresh_cache = _decode_setup(
+        self.model, self.params, cache1 = _decode_setup(
             model, params, batch=1, max_len=self.max_len
         )
+        # the program that made ``cache1`` and the tree itself: what the
+        # first cold admission is fed (``new_cache``)
+        self._build_cache = cache_builder(self.model, self.params, 1)
+        self._spare_cache = cache1
         # positions a prefill block holds: with ``feed_block_count`` the
         # host's account of the passes over the weights a prefill made
         self.prefill_width = feed_width(self.model.config)
@@ -420,7 +494,7 @@ class ServeEngine:
         self.slots = SlotBatch(
             cache=jax.tree.map(
                 lambda c: jnp.broadcast_to(c[None], (s,) + c.shape).copy(),
-                self.fresh_cache,
+                cache1,
             ),
             seqs=jnp.zeros((s, l), jnp.int32),
             cur=jnp.zeros((s,), jnp.int32),
@@ -452,8 +526,7 @@ class ServeEngine:
         # the decode step launched and not yet fetched: its three device
         # outputs and ``_occupant`` as it stood at the launch
         self._in_flight = None
-        # counts a family's decode step carries to the host behind its
-        # tokens, summed until the scheduler takes them (pop_counters)
+        # counts summed until the scheduler takes them (pop_counters)
         self._counters: dict = {}
         self._embed_model = None  # lazily built by embed()
         self._prefix_cache = None  # optional PrefixCache (set_prefix_cache)
@@ -510,7 +583,8 @@ class ServeEngine:
         explicitly so a hot reload can calibrate candidate weights while
         the live ones keep serving."""
         deq = dequantized(pair, self.model.config.compute_dtype)
-        cache_a = cache_b = self.fresh_cache
+        # not ``new_cache()``: a reload calibrates off the loop's thread
+        cache_a = cache_b = self._build_cache()
         worst = 0.0
         for tok in (1, 7, 23, 4):  # fixed calibration prompt
             t = jnp.full((1, 1), tok, jnp.int32)
@@ -612,7 +686,9 @@ class ServeEngine:
         """Attach a ``PrefixCache`` (serving/prefix_cache.py). Consulted
         by ``begin_prefill`` and fed at every chunk boundary by
         ``advance_prefill``; cleared on ``commit_params`` (snapshots are
-        weight-dependent). The engine serves fine without one."""
+        weight-dependent). A stored snapshot is the cache's own tree: no
+        admission feeds it to a program (``_copy_cache``). The engine
+        serves fine without one, and then copies no tree."""
         if self.slot_batched:
             raise ValueError(
                 f"{type(self.model).__name__} cannot take a prefix cache: "
@@ -715,7 +791,7 @@ class ServeEngine:
                 trow[:length] = np.asarray(template, np.int32).reshape(-1)
                 frow[:length] = np.asarray(frozen, bool).reshape(-1)
             if key is None:
-                key = jax.random.PRNGKey(seed)
+                key = seed_key(seed)
             parity = temperature == 1.0 and top_p is None
             return row, int(start), key, parity, trow, frow
 
@@ -757,11 +833,14 @@ class ServeEngine:
                       seed: int = 0, request_id: Optional[str] = None,
                       template=None, frozen=None) -> PendingPrefill:
         """Start an admission into ``slot``: validate + build the
-        operands but run NO device work yet — the caller (the scheduler)
+        operands but run NO program yet (the prime's row goes to the
+        device; without the recycled tree, see ``new_cache``, the
+        cache's builder runs) — the caller (the scheduler)
         advances the returned ``PendingPrefill`` with
         ``advance_prefill`` between decode steps. When a prefix
         cache is attached, the longest cached prefix of the feed region
-        seeds the pending state at its depth, so a repeated scaffold
+        seeds the pending state at its depth — with a copy of the stored
+        snapshot, which stays the cache's — so a repeated scaffold
         skips straight to the tail. The eventual token stream does not
         depend on how ``advance_prefill`` splits the prime."""
         row, start, key, parity, trow, frow = self._prepare_admission(
@@ -771,7 +850,7 @@ class ServeEngine:
         )
         pending = PendingPrefill(
             slot=int(slot),
-            row=jnp.asarray(row),
+            row=jax.device_put(row),
             host_row=row,
             start=start,
             length=int(length),
@@ -780,17 +859,27 @@ class ServeEngine:
             top_p_val=float(_TOP_P_OFF if top_p is None else top_p),
             top_k_val=int(0 if top_k is None else top_k),
             parity=bool(parity),
-            trow=jnp.asarray(trow),
-            frow=jnp.asarray(frow),
-            cache=self.fresh_cache,
+            trow=trow,
+            frow=frow,
+            cache=None,
             request_id="" if request_id is None else str(request_id),
         )
         if self._prefix_cache is not None:
             depth, snap = self._prefix_cache.lookup(row, pending.feed_len)
             if snap is not None:
-                pending.cache = snap
+                pending.cache = _copy_cache(snap)
+                pending.copies = 1
                 pending.pos = pending.hit_depth = int(depth)
+        if pending.cache is None:
+            pending.cache = self.new_cache()
         return pending
+
+    def new_cache(self):
+        """A batch-1 cache tree holding the model's initial values, the
+        caller's own (it may donate it): the tree the last scatter handed
+        back, else a new one from the program that made the first."""
+        cache, self._spare_cache = self._spare_cache, None
+        return self._build_cache() if cache is None else cache
 
     def advance_prefill(self, pending: PendingPrefill,
                         budget: Optional[int] = None) -> bool:
@@ -798,9 +887,13 @@ class ServeEngine:
         when None) through the pending batch-1 cache; when the feed
         region is exhausted, scatter + activate the slot in the same
         call (the slot scatter happens ONLY on this final chunk).
-        Chunk boundaries are snapshotted into the prefix cache. Returns
-        True once the slot is live. ``lo``/``hi`` ride as traced
-        operands, so every chunk size reuses one compiled program."""
+        Chunk boundaries are snapshotted into the prefix cache: what it
+        stores becomes its own and the admission goes on with a copy.
+        Returns True once the slot is live. ``lo``/``hi`` ride as traced
+        operands, so every chunk size reuses one compiled program — as
+        HOST scalars, like the scatter's: a ``jnp`` scalar would be a
+        program of its own each, queued behind the step in flight (0.6
+        ms of the caller's time against 0.24 on a v5e, PERF.md, PR 37)."""
         if pending.done:
             return True
         feed_len = pending.feed_len
@@ -814,31 +907,35 @@ class ServeEngine:
                 with _stage("serve/prefill_dispatch"):
                     pending.cache = _prefill_chunk(
                         self.model, self.served_params, pending.cache,
-                        pending.row, jnp.int32(pending.pos),
-                        jnp.int32(hi),
+                        pending.row, np.int32(pending.pos), np.int32(hi),
                     )
                 pending.blocks += self.prefill_blocks(pending.pos, int(hi))
                 pending.pos = int(hi)
                 if self._prefix_cache is not None:
                     with _stage("serve/prefix_insert"):
-                        self._prefix_cache.insert(
+                        if self._prefix_cache.insert(
                             pending.host_row, pending.pos, pending.cache,
-                        )
+                        ):
+                            pending.cache = _copy_cache(pending.cache)
+                            pending.copies += 1
             if pending.pos >= feed_len:
                 with _stage("serve/prefill_finish"):
                     tail = (
-                        jnp.int32(pending.slot), pending.row,
-                        jnp.int32(pending.start), jnp.int32(pending.length),
+                        np.int32(pending.slot), pending.row,
+                        np.int32(pending.start), np.int32(pending.length),
                         pending.key,
-                        jnp.float32(pending.temperature),
-                        jnp.float32(pending.top_p_val),
-                        jnp.int32(pending.top_k_val),
-                        jnp.asarray(pending.parity),
+                        np.float32(pending.temperature),
+                        np.float32(pending.top_p_val),
+                        np.int32(pending.top_k_val),
+                        np.bool_(pending.parity),
                         pending.trow, pending.frow,
                     )
-                    self.slots = _prefill_finish(
-                        self.slots, pending.cache, *tail
+                    self.slots, self._spare_cache = _prefill_finish(
+                        self.slots, pending.cache, *tail,
+                        new_cache=self._build_cache,
                     )
+                    pending.cache = None
+                self._count("prefill_cache_copies", pending.copies)
                 slot = pending.slot
                 self._targets[slot] = int(pending.length)
                 self._rows[slot] = pending.host_row
@@ -908,7 +1005,7 @@ class ServeEngine:
                     sampled[self.max_slots:], int(was_live.sum())
                 )
                 for name, by in folded.items():
-                    self._counters[name] = self._counters.get(name, 0) + by
+                    self._count(name, by)
                 sampled = sampled[: self.max_slots]
             same = occupant == self._occupant
             was_live, finished = was_live & same, finished & same
@@ -936,13 +1033,18 @@ class ServeEngine:
         read = held if block is None else int(
             listed_rows(pos, c.window_size, ring, block).sum()
         )
-        for name, by in (("ring_rows_read", read), ("ring_rows_held", held)):
-            self._counters[name] = self._counters.get(name, 0) + by
+        self._count("ring_rows_read", read)
+        self._count("ring_rows_held", held)
+
+    def _count(self, name: str, by: int) -> None:
+        self._counters[name] = self._counters.get(name, 0) + by
 
     def pop_counters(self) -> dict:
-        """Counter increments gathered since the last call (empty for a
-        family that reports none); the scheduler adds them to its
-        ``ServingMetrics``."""
+        """Counter increments gathered since the last call; the scheduler
+        adds them to its ``ServingMetrics``: a family's own counts, the
+        ring rows of ProGen's decode steps, and ``prefill_cache_copies``
+        (batch-1 trees copied because a prefix cache shares them, counted
+        when their admission activates: 0 without one)."""
         out, self._counters = self._counters, {}
         return out
 
@@ -1041,7 +1143,9 @@ class ServeEngine:
 
     @staticmethod
     def prefill_compile_count() -> int:
-        """Compiled variants of the chunk and finish programs across ALL
-        engines in the process. Flat-after-warmup is the acceptance bar
-        (traced bounds are what keep the chunk program at one)."""
-        return _prefill_chunk._cache_size() + _prefill_finish._cache_size()
+        """Compiled variants of the admission's programs — the chunk, the
+        scatter, the copy a prefix cache asks for — across ALL engines
+        in the process. Flat-after-warmup is the acceptance bar (traced
+        bounds are what keep the chunk program at one)."""
+        return (_prefill_chunk._cache_size() + _prefill_finish._cache_size()
+                + _copy_cache._cache_size())

@@ -54,6 +54,7 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
+from progen_tpu.serving.engine import seed_key
 from progen_tpu.serving.scheduler import Request
 from progen_tpu.telemetry.spans import get_telemetry
 from progen_tpu.telemetry.trace import LineDrops, iter_jsonl
@@ -61,21 +62,6 @@ from progen_tpu.telemetry.trace import LineDrops, iter_jsonl
 STATUS_COMPLETED = "completed"
 # ownership transferred to the router: settled HERE, answered elsewhere
 STATUS_HANDED_OFF = "handed_off"
-
-
-def _seed_key(seed: int) -> np.ndarray:
-    """``jax.random.PRNGKey(seed)`` as host integers, computed on the
-    CPU backend where there is one: on the accelerator the few integer
-    operations would queue behind the decode step in flight, and reading
-    them back would hold ``submit`` until that step is done."""
-    import jax
-
-    try:
-        cpu = jax.devices("cpu")[0]
-    except RuntimeError:  # a process held to the accelerator's platform
-        cpu = None
-    with jax.default_device(cpu):
-        return np.asarray(jax.random.PRNGKey(seed))
 
 
 class RequestJournal:
@@ -104,7 +90,7 @@ class RequestJournal:
         """Journal everything needed to re-create ``req`` from nothing.
         The PRNG key is resolved NOW (explicit key, else seed-derived) so
         replay does not depend on how the key was originally specified."""
-        key = req.key if req.key is not None else _seed_key(req.seed)
+        key = req.key if req.key is not None else seed_key(req.seed)
         self.emit({
             "ev": "journal", "op": "accept", "ts": time.time(),
             "req": str(req.id),

@@ -15,7 +15,13 @@ bit-identically.
 This is an LRU over such snapshots, keyed on ``(depth, sha1 of the
 token bytes)``. ``advance_prefill`` inserts at every chunk boundary;
 ``begin_prefill`` looks up the DEEPEST stored prefix of a new request's
-feed region and resumes there. A byte budget bounds device memory:
+feed region and resumes there. A stored snapshot is the cache's OWN
+tree: the engine's programs overwrite the batch-1 cache they are handed
+(it is donated to them), so the admission that inserted a tree goes on
+with a copy of it and an admission that hits one is seeded with a copy
+— ``insert`` and ``lookup`` themselves hand references about and copy
+nothing (the engine does, ``engine._copy_cache``, and counts it). A byte
+budget bounds device memory:
 snapshots are whole batch-1 cache trees (summed leaf ``nbytes``), and
 inserting past the budget evicts least-recently-used entries first.
 

@@ -491,22 +491,22 @@ class TestServingDonation:
 
     def test_engine_jits_donate_slot_buffers(self):
         registry = self._registry("progen_tpu/serving/engine.py")
-        for fn in ("_prefill_finish", "_decode_step"):
-            assert fn in registry, f"{fn} lost its jit decorator"
-            assert "slots" in registry[fn].donated_names, (
-                f"{fn} no longer donates its slot batch"
-            )
-            # cache1 may be a prefix-cache snapshot; the weights are read
-            # by every later step
-            assert not {"cache1", "params"} & set(
-                registry[fn].donated_names
-            ), fn
-        # the chunk's cache is the reusable zero template on a cold
-        # admission (or a snapshot): donating it would corrupt later ones
-        assert "_prefill_chunk" in registry
-        assert not registry["_prefill_chunk"].donated_names
         assert set(registry) >= {"_decode_step", "_prefill_chunk",
                                  "_prefill_finish"}
+        # every program is handed the state it rewrites and hands it
+        # back: the pool to the decode step; the pool and the batch-1
+        # cache of the admission in flight (the engine's own tree, never
+        # one a prefix cache holds) to the scatter; that cache to the
+        # chunk. The weights are read by every later step.
+        donated = {
+            "_decode_step": {"slots"},
+            "_prefill_finish": {"slots", "cache1"},
+            "_prefill_chunk": {"cache"},
+        }
+        for fn, names in donated.items():
+            assert set(registry[fn].donated_names) == names, (
+                f"{fn} donates {registry[fn].donated_names}, not {names}"
+            )
 
     def test_train_step_compile_donates_state(self):
         # assignment-form jit with explicit shardings: assert on source

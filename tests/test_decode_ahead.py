@@ -27,7 +27,8 @@ from progen_tpu.serving import (
     Scheduler,
     ServeEngine,
 )
-from progen_tpu.serving.journal import RequestJournal, _seed_key
+from progen_tpu.serving.engine import seed_key
+from progen_tpu.serving.journal import RequestJournal
 
 REPO = Path(__file__).resolve().parents[1]
 
@@ -395,8 +396,8 @@ def test_one_wait_on_the_device_a_step_and_none_between(family, tmp_path,
 
 def test_the_journals_key_is_prngkeys(progen):
     for seed in (0, 7, 2**31 - 1):
-        assert (_seed_key(seed) == np.asarray(jax.random.PRNGKey(seed))).all()
-        assert isinstance(_seed_key(seed), np.ndarray)
+        assert (seed_key(seed) == np.asarray(jax.random.PRNGKey(seed))).all()
+        assert isinstance(seed_key(seed), np.ndarray)
 
 
 # ----- (d) journaled before returned ---------------------------------------------

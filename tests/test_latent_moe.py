@@ -331,12 +331,18 @@ def test_cli_serve_takes_and_answers_token_ids(tmp_path):
 # a tree without it. Taken at the commit before this family was added, and
 # again in PR 29, which changed what the cache write traces (one update per
 # slot in place of a scatter, ``layers._update_at``) and nothing else here:
-# the programs are lowered with ``engine.params``, the raw tree.
+# the programs are lowered with ``engine.params``, the raw tree. The chunk's
+# values are PR 37's: the program takes its cache donated (a
+# ``tf.aliasing_output`` mark on each cache leaf's argument) and a block
+# hands each cache leaf on under a layout constraint (a ``LayoutConstraint``
+# custom call a leaf, ``sampling._row_major``), and nothing else — with the
+# constraint an identity and the marks taken out of the text, both layouts
+# hashed to the values that stood here before (3f9a1635…95fe, 798d0341…f6ab).
 PROGEN_PROGRAMS = {
     ("unrolled", "decode"): "2a417fde763406947fa7303fafc43240af15b26f9167e8616cfffe7bb4de2cbd",
-    ("unrolled", "chunk"): "3f9a16359420a99c55fe64d735f3f5d068a7151ae04be81ebad4b611002c95fe",
+    ("unrolled", "chunk"): "b31c52813d43f9959425c37026532185b5e440e4cc8c6755bae6ca170734b208",
     ("scanned", "decode"): "ad668887b715d9a6bea1a18afae30311ad075662fb00cb4645e39805f2636df7",
-    ("scanned", "chunk"): "798d0341789dffbda8897a5b987eaaf40b1c66c4831dee1b59be5a1f9d1df6ab",
+    ("scanned", "chunk"): "cfa1e037ecd62078fa97102e41326a7dd1dc19c4c060d781d303d82e0a3283c6",
 }
 
 
@@ -361,7 +367,7 @@ def test_progens_decode_and_prefill_programs_lower_unchanged(layout):
     row = jnp.zeros((24,), jnp.int32)
     texts = {
         "decode": E._decode_step.lower(eng.model, eng.params, eng.slots),
-        "chunk": E._prefill_chunk.lower(eng.model, eng.params, eng.fresh_cache,
+        "chunk": E._prefill_chunk.lower(eng.model, eng.params, eng.new_cache(),
                                         row, jnp.int32(0), jnp.int32(5)),
     }
     for name, lowered in texts.items():

@@ -80,7 +80,8 @@ def mixed():
 
 
 def copy(tree):
-    # the decode step and the prefill donate the pool
+    # the decode step and the prefill donate the pool (and the prefill the
+    # batch-1 cache it is fed, which is why each run takes a new one)
     return jax.tree.map(jnp.copy, tree)
 
 
@@ -111,7 +112,7 @@ def run_decode(eng, tree):
 
 def run_chunk(eng, tree):
     # two chunks, the second resuming mid-block
-    cache = E._prefill_chunk(eng.model, tree, eng.fresh_cache, row_of(30),
+    cache = E._prefill_chunk(eng.model, tree, eng.new_cache(), row_of(30),
                              jnp.int32(0), jnp.int32(13))
     return E._prefill_chunk(eng.model, tree, cache, row_of(30),
                             jnp.int32(13), jnp.int32(29))
@@ -119,14 +120,14 @@ def run_chunk(eng, tree):
 
 def run_prefill(eng, tree):
     # a whole prime as the chunk program's one chunk, then into the pool
-    cache = E._prefill_chunk(eng.model, tree, eng.fresh_cache, row_of(17),
+    cache = E._prefill_chunk(eng.model, tree, eng.new_cache(), row_of(17),
                              jnp.int32(0), jnp.int32(16))
     return E._prefill_finish(
         copy(eng.slots), cache, jnp.int32(1),
         row_of(17), jnp.int32(17), jnp.int32(LEN), jax.random.PRNGKey(9),
         jnp.float32(1.0), jnp.float32(E._TOP_P_OFF), jnp.int32(8),
         jnp.asarray(True), jnp.zeros((LEN,), jnp.int32),
-        jnp.zeros((LEN,), bool),
+        jnp.zeros((LEN,), bool), new_cache=eng._build_cache,
     )
 
 
@@ -134,7 +135,7 @@ def run_logits(eng, tree):
     # ISSUE 29's scratch run: 20 positions fed in blocks, then 10 decode
     # steps through the cache, the logits of each kept
     row = row_of(31)
-    cache = feed_tokens(eng.model, tree, eng.fresh_cache, row[None], 0, 20)
+    cache = feed_tokens(eng.model, tree, eng.new_cache(), row[None], 0, 20)
     logits = []
     for i in range(20, 30):
         out, mut = eng.model.apply(
@@ -202,7 +203,7 @@ def test_the_lowered_programs_convert_no_parameter(mixed, program):
     eng, _ = mixed
     args = {
         "decode_step": (eng.slots,),
-        "prefill_chunk": (eng.fresh_cache, row_of(30), jnp.int32(0),
+        "prefill_chunk": (eng.new_cache(), row_of(30), jnp.int32(0),
                           jnp.int32(13)),
     }[program]
     jitted = {"decode_step": E._decode_step,
@@ -286,7 +287,7 @@ def test_the_rule_casts_what_a_trace_of_the_programs_casts(layout):
 
     scalar = jax.ShapeDtypeStruct((), jnp.int32)
     traced = cast_mask(
-        programs, eng.params, jnp.bfloat16, eng.slots, eng.fresh_cache,
+        programs, eng.params, jnp.bfloat16, eng.slots, eng.new_cache(),
         jax.ShapeDtypeStruct((LEN,), jnp.int32), scalar, scalar,
     )
     assert eng._cast == promoted_mask(eng.params, jnp.bfloat16) == traced

@@ -9,6 +9,9 @@ Every parity test here asserts token-for-token equality between the two
 admission paths on the same requests.
 """
 
+import re
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -475,3 +478,244 @@ class TestStages:
                 temperature=req.temperature, top_p=req.top_p,
             ))
             np.testing.assert_array_equal(got[req.id], want, err_msg=req.id)
+
+
+# ----- an admission hands its batch-1 cache on by donation (PR 37) --------
+
+REPO = Path(__file__).resolve().parents[1]
+ARGUMENT = re.compile(r"%arg\d+: tensor<[^>]*>( \{[^}]*\})?")
+
+
+def _arguments(lowered):
+    """The marks on each argument of a lowered program's ``main``, in
+    order: ``tf.aliasing_output = n`` is jit's record of a donation it
+    matched with output ``n``."""
+    text = lowered.as_text()
+    head = text[text.index("func.func public @main("):text.index(") -> (")]
+    return [m.group(1) or "" for m in ARGUMENT.finditer(head)]
+
+
+def _operands(length):
+    return (np.int32(1), jnp.zeros((length,), jnp.int32), np.int32(5),
+            np.int32(length), jax.random.PRNGKey(0), np.float32(1.0),
+            np.float32(2.0), np.int32(8), np.bool_(True),
+            np.zeros((length,), np.int32), np.zeros((length,), bool))
+
+
+def _latent_moe():
+    from progen_tpu.config import load_toml_config
+    from progen_tpu.models import build_model
+
+    model = build_model(load_toml_config(
+        str(REPO / "configs/model/latent-moe-small.toml")
+    ))
+    params = model.init(
+        jax.random.PRNGKey(1), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+    return model, params
+
+
+def _drain(engine, slot):
+    out = []
+    for _ in range(64):
+        sampled, was_live, finished = engine.decode_step()
+        if not was_live[slot]:
+            break
+        out.append(int(sampled[slot]))
+        if finished[slot]:
+            break
+    return out
+
+
+class TestAdmissionOwnsItsCache:
+    """From ``begin_prefill`` to the slot's activation the batch-1 cache
+    is the admission's alone: the chunk program and the scatter take it
+    donated, the scatter hands it back holding the model's initial
+    values, nothing but those programs runs, and only a prefix cache
+    makes a copy."""
+
+    @pytest.mark.parametrize("program", ["chunk", "finish"])
+    def test_the_lowered_programs_alias_every_cache_leaf(
+        self, model_and_params, program
+    ):
+        from progen_tpu.serving import engine as E
+
+        model, params = model_and_params
+        eng = ServeEngine(model, params, max_slots=3, max_len=32)
+        n_cache = len(jax.tree.leaves(eng.slots.cache))
+        if program == "chunk":
+            n_kept = len(jax.tree.leaves(eng.served_params))
+            marks = _arguments(E._prefill_chunk.lower(
+                eng.model, eng.served_params, eng.new_cache(),
+                jnp.zeros((32,), jnp.int32), np.int32(0), np.int32(5),
+            ))
+            kept, given = marks[:n_kept], marks[n_kept:n_kept + n_cache]
+            rest = marks[n_kept + n_cache:]
+            assert len(rest) == 3  # the row and the two bounds
+        else:
+            n_pool = len(jax.tree.leaves(eng.slots))
+            marks = _arguments(E._prefill_finish.lower(
+                eng.slots, eng.new_cache(), *_operands(32),
+                new_cache=eng._build_cache,
+            ))
+            kept, given = [], marks[:n_pool + n_cache]
+            rest = marks[n_pool + n_cache:]
+            assert len(rest) == 11
+        assert len(given) >= n_cache > 0
+        outputs = [re.search(r"tf\.aliasing_output = (\d+)", m) for m in given]
+        assert all(outputs), given
+        assert len({m.group(1) for m in outputs}) == len(given)
+        assert not any(kept) and not any(rest)  # weights, operands: read
+
+    @pytest.mark.parametrize("in_flight", [1, 2])
+    def test_an_admission_runs_its_chunks_and_the_scatter_and_nothing_else(
+        self, model_and_params, monkeypatch, in_flight
+    ):
+        """Prime of 10 at 4 positions a call: 3 chunks + 1 scatter, no
+        program for a scalar, a key or a row, and none for a fresh tree
+        but where a second admission is in flight and the recycled tree
+        is taken."""
+        from jax._src import dispatch
+
+        from progen_tpu.serving import engine as E
+
+        model, params = model_and_params
+        eng = ServeEngine(model, params, max_slots=3, max_len=32)
+        prime = np.arange(1, 11, dtype=np.int32)
+        eng.prefill(eng.acquire(), prime, 24, seed=3)  # compiled, recycled
+
+        calls = {}
+
+        def counted(name, fn):
+            def call(*args, **kwargs):
+                calls[name] = calls.get(name, 0) + 1
+                return fn(*args, **kwargs)
+            return call
+
+        for name in ("_prefill_chunk", "_prefill_finish", "_copy_cache"):
+            monkeypatch.setattr(E, name, counted(name, getattr(E, name)))
+        # every eagerly applied primitive is a program of its own, looked
+        # up here (a ``jnp.int32(n)`` is a ``convert_element_type``: the
+        # parent ran 13 of them an admission of three chunks)
+        monkeypatch.setattr(dispatch, "xla_primitive_callable", counted(
+            "eager", dispatch.xla_primitive_callable))
+        # ... but the seed's key, made on the CPU backend, which under
+        # these tests is the default one
+        real_key = E.seed_key
+
+        def host_key(seed):
+            before = calls.get("eager", 0)
+            key = real_key(seed)
+            calls["eager"] = before
+            assert isinstance(key, np.ndarray)
+            return key
+
+        monkeypatch.setattr(E, "seed_key", host_key)
+
+        recycled = eng._spare_cache
+        assert recycled is not None
+        pendings = [
+            eng.begin_prefill(eng.acquire(), prime, 24, seed=4 + i,
+                              temperature=0.7, top_p=0.9, top_k=5)
+            for i in range(in_flight)
+        ]
+        # the first takes the tree the last scatter handed back; a second
+        # in flight beside it gets one from the builder's program
+        assert pendings[0].cache is recycled and eng._spare_cache is None
+        assert all(p.cache is not recycled for p in pendings[1:])
+        for pending in pendings:
+            while not eng.advance_prefill(pending, 4):
+                pass
+            assert pending.cache is None  # handed on
+        assert eng._spare_cache is not None
+        assert calls.pop("eager", 0) == 0
+        assert calls == {"_prefill_chunk": 3 * in_flight,
+                         "_prefill_finish": in_flight}
+        assert eng.pop_counters()["prefill_cache_copies"] == 0
+
+    @pytest.mark.parametrize("family", ["progen", "latent_moe"])
+    def test_the_third_admission_streams_what_a_first_does(
+        self, model_and_params, family
+    ):
+        """The tree a scatter hands back is as good as new: the third
+        admission on one engine (its tree fed, scattered and handed back
+        twice before) streams what the first on a fresh engine does."""
+        model, params = (
+            model_and_params if family == "progen" else _latent_moe()
+        )
+        vocab = model.config.num_tokens
+        rng = np.random.default_rng(5)
+        primes = [rng.integers(1, vocab, size=n).astype(np.int32)
+                  for n in (13, 6, 9)]
+
+        def admit(engine, prime, seed):
+            slot = engine.acquire()
+            pending = engine.begin_prefill(slot, prime, 24, add_bos=True,
+                                           seed=seed)
+            while not engine.advance_prefill(pending, 4):
+                pass
+            return slot
+
+        first = ServeEngine(model, params, max_slots=2, max_len=32)
+        want = _drain(first, admit(first, primes[2], 9))
+        assert len(want) > 2
+
+        engine = ServeEngine(model, params, max_slots=2, max_len=32)
+        for prime, seed in zip(primes[:2], (1, 2)):
+            slot = admit(engine, prime, seed)
+            for _ in range(3):
+                engine.decode_step()
+            engine.release(slot)
+        assert _drain(engine, admit(engine, primes[2], 9)) == want
+
+    def test_a_prefix_caches_snapshots_are_its_own(self, model_and_params):
+        """With a prefix cache attached a tree is copied at the two
+        points where it would be shared, and there alone: a snapshot
+        inserted at a chunk boundary outlives the chunks and the scatter
+        that follow it, a hit leaves it whole for the next hit, and
+        ``prefill_cache_copies`` counts both."""
+        from progen_tpu.sampling import feed_tokens
+
+        model, params = model_and_params
+        prime = np.asarray([5, 12, 3, 3, 8, 19, 2, 7, 14, 9, 4, 22], np.int32)
+        cache = PrefixCache(64 << 20)
+        engine = ServeEngine(model, params, max_slots=2, max_len=32)
+        sched = Scheduler(engine, max_queue=4, prefill_chunk=4,
+                          prefix_cache=cache)
+
+        def serve(rid):
+            assert sched.submit(Request(id=rid, prime=prime, length=28,
+                                        key=jax.random.PRNGKey(11)))[0]
+            _, comps = sched.run_to_completion(max_steps=2000)
+            return comps[0].tokens
+
+        def stored(depth):
+            (tree,) = [entry[0] for (d, _), entry in cache._entries.items()
+                       if d == depth]
+            return tree
+
+        cold = serve("cold")
+        assert cache.inserts == 3 and cache.hits == 0  # depths 4, 8, 11
+        row = np.zeros((32,), np.int32)
+        row[:12] = prime
+        for depth in (4, 8, 11):
+            want = feed_tokens(engine.model, engine.params,
+                               engine._build_cache(), jnp.asarray(row)[None],
+                               0, depth)
+            for got, ref in zip(jax.tree.leaves(stored(depth)),
+                                jax.tree.leaves(want)):
+                assert not got.is_deleted()
+                np.testing.assert_array_equal(np.asarray(got),
+                                              np.asarray(ref))
+        for n, rid in enumerate(("hot", "hot again"), start=1):
+            np.testing.assert_array_equal(serve(rid), cold)
+            assert cache.hits == n and cache.inserts == 3
+            assert not any(leaf.is_deleted()
+                           for leaf in jax.tree.leaves(stored(11)))
+        copies = sched.metrics.snapshot()["prefill_cache_copies"]
+        assert copies == cache.inserts + cache.hits == 5
+
+    def test_without_a_prefix_cache_nothing_is_copied(self, model_and_params):
+        model, params = model_and_params
+        _, _, sched = _run(model, params, _requests(4), prefill_chunk=3)
+        assert sched.metrics.snapshot()["prefill_cache_copies"] == 0
