@@ -39,6 +39,13 @@ are an ARGUMENT, one per row and column, not a counter in the cache: the
 serving pool's rows stand at different positions. ``live`` marks what is
 fed; the rest is computed (shapes are static) but writes nothing and is
 routed nowhere.
+
+Mechanism classes (``jax.named_scope``, ``telemetry/scopes.py``): the
+embedding and the head are ``head``; a layer's attention with its norm
+and residual add is ``project`` (``project/mla``), its reading of the
+latent rows ``attend/mla`` and its row writes ``cache_write``
+(``_write_rows``); a layer's feed-forward with its norm and residual add
+is ``ffn`` (``ffn/route``, ``ffn/experts``, ``ffn/shared``).
 """
 
 from __future__ import annotations
@@ -210,11 +217,12 @@ def _write_rows(buf, new, positions, live):
             n = jnp.where(lv[:, None], n, old)
         return jax.lax.dynamic_update_slice_in_dim(b, n, p0, axis=0)
 
-    if live is None:
-        return jax.vmap(lambda b, n, p0: one(b, n, p0, None))(
-            buf, new, positions[:, 0]
-        )
-    return jax.vmap(one)(buf, new, positions[:, 0], live)
+    with jax.named_scope("cache_write"):
+        if live is None:
+            return jax.vmap(lambda b, n, p0: one(b, n, p0, None))(
+                buf, new, positions[:, 0]
+            )
+        return jax.vmap(one)(buf, new, positions[:, 0], live)
 
 
 def grouped_experts(x, idx, weights, live, w_gate_up, w_down):
@@ -272,11 +280,12 @@ def feed_blocks(model, params, cache, tokens, lo, hi):
     b, last = tokens.shape[0], tokens.shape[-1] - 1
 
     def feed(blk, cache):
-        at = blk * t + jnp.arange(t)
-        live = (at >= lo) & (at < hi)
+        with jax.named_scope("head"):  # the block's tokens and positions
+            at = blk * t + jnp.arange(t)
+            live = (at >= lo) & (at < hi)
+            fed = tokens[:, jnp.minimum(at, last)]
         _, mut = model.apply(
-            {"params": params, "cache": cache},
-            tokens[:, jnp.minimum(at, last)],
+            {"params": params, "cache": cache}, fed,
             jnp.broadcast_to(at, (b, t)), jnp.broadcast_to(live, (b, t)),
             head=False, mutable=["cache"],
         )
@@ -305,7 +314,7 @@ class LatentAttention(nn.Module):
         w_o = self.param("w_o", _init(), (h * dv, d), pd)
         scale = (dn + dr) ** -0.5
 
-        with jax.named_scope("mla/project"):
+        with jax.named_scope("project/mla"):
             q = (u @ w_q).reshape(b, t, h, dn + dr)
             q_nope, q_rope = q[..., :dn], q[..., dn:]
             kva = u @ w_kva
@@ -316,10 +325,10 @@ class LatentAttention(nn.Module):
             w_kvb = w_kvb.reshape(r, h, dn + dv)
 
         if not c.decode:
-            with jax.named_scope("mla/project"):
+            with jax.named_scope("project/mla"):
                 kv = jnp.einsum("bsc,chn->bshn", lat, w_kvb)
                 k_nope, v = kv[..., :dn], kv[..., dn:]
-            with jax.named_scope("mla/attend"):
+            with jax.named_scope("attend/mla"):
                 scores = (
                     jnp.einsum("bthn,bshn->bhts", q_nope, k_nope,
                                preferred_element_type=jnp.float32)
@@ -346,9 +355,9 @@ class LatentAttention(nn.Module):
                 lat_all = _write_rows(lat_all, lat, positions, live)
                 rope_all = _write_rows(rope_all, k_rope, positions, live)
                 cache_c.value, cache_r.value = lat_all, rope_all
-            with jax.named_scope("mla/project"):
+            with jax.named_scope("project/mla"):
                 q_lat = jnp.einsum("bthn,chn->bthc", q_nope, w_kvb[..., :dn])
-            with jax.named_scope("mla/attend"):
+            with jax.named_scope("attend/mla"):
                 scores = (
                     jnp.einsum("bthc,bsc->bhts", q_lat, lat_all,
                                preferred_element_type=jnp.float32)
@@ -361,9 +370,9 @@ class LatentAttention(nn.Module):
                     jnp.where(seen, scores, -jnp.inf), axis=-1
                 ).astype(u.dtype)
                 o_lat = jnp.einsum("bhts,bsc->bthc", p, lat_all)
-            with jax.named_scope("mla/project"):
+            with jax.named_scope("project/mla"):
                 o = jnp.einsum("bthc,chn->bthn", o_lat, w_kvb[..., dn:])
-        with jax.named_scope("mla/project"):
+        with jax.named_scope("project/mla"):
             return o.reshape(b, t, h * dv) @ w_o
 
 
@@ -400,14 +409,14 @@ class MoEFFN(nn.Module):
         x = u.reshape(b * t, d)
         alive = (jnp.ones((b * t,), bool) if live is None
                  else live.reshape(b * t))
-        with jax.named_scope("moe/route"):
+        with jax.named_scope("ffn/route"):
             idx, w = route(x, w_router, bias, c)
         self.sow("intermediates", "experts", idx)  # for the tests
-        with jax.named_scope("moe/experts"):
+        with jax.named_scope("ffn/experts"):
             y, touched, load = grouped_experts(
                 x, idx, w, alive, w_gate_up, w_down
             )
-        with jax.named_scope("moe/shared"):
+        with jax.named_scope("ffn/shared"):
             shared = DenseFFN(c, c.n_shared_experts * f, name="shared")(u)
         y = y.astype(u.dtype).reshape(b, t, d) + shared
         return y, touched, load
@@ -435,28 +444,34 @@ class LatentMoE(nn.Module):
         b, t = tokens.shape
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
-        x = nn.Embed(
-            c.vocab_size, c.hidden_size, dtype=c.compute_dtype,
-            param_dtype=c.params_dtype, embedding_init=_init(), name="embed",
-        )(tokens)
+        with jax.named_scope("head"):
+            x = nn.Embed(
+                c.vocab_size, c.hidden_size, dtype=c.compute_dtype,
+                param_dtype=c.params_dtype, embedding_init=_init(),
+                name="embed",
+            )(tokens)
         stats = []
         for i in range(c.num_hidden_layers):
             scale = self.param(f"attn_norm{i}", nn.initializers.ones,
                                (c.hidden_size,), c.params_dtype)
-            x = x + LatentAttention(c, name=f"attn{i}")(
-                _rms_norm(x, scale, c.rms_norm_eps), positions, live
-            )
+            with jax.named_scope("project"):
+                x = x + LatentAttention(c, name=f"attn{i}")(
+                    _rms_norm(x, scale, c.rms_norm_eps), positions, live
+                )
             scale = self.param(f"ffn_norm{i}", nn.initializers.ones,
                                (c.hidden_size,), c.params_dtype)
-            u = _rms_norm(x, scale, c.rms_norm_eps)
-            if i < c.first_k_dense_replace:
-                x = x + DenseFFN(c, c.intermediate_size, name=f"ffn{i}")(u)
-            else:
-                y, touched, load = MoEFFN(c, name=f"ffn{i}")(u, live)
-                x = x + y
-                stats.append(jnp.stack([touched, load]))
-        stats = (jnp.stack(stats).astype(jnp.int32) if stats
-                 else jnp.zeros((0, 2), jnp.int32))
+            with jax.named_scope("ffn"):
+                u = _rms_norm(x, scale, c.rms_norm_eps)
+                if i < c.first_k_dense_replace:
+                    x = x + DenseFFN(c, c.intermediate_size,
+                                     name=f"ffn{i}")(u)
+                else:
+                    y, touched, load = MoEFFN(c, name=f"ffn{i}")(u, live)
+                    x = x + y
+                    stats.append(jnp.stack([touched, load]))
+        with jax.named_scope("ffn"):
+            stats = (jnp.stack(stats).astype(jnp.int32) if stats
+                     else jnp.zeros((0, 2), jnp.int32))
         if c.decode:
             # what the blocks fed through this cache met, kept with it
             # until a decode step's read carries it to the host: per
@@ -466,9 +481,10 @@ class LatentMoE(nn.Module):
                 lambda: jnp.zeros((b, c.n_expert_layers, 3), jnp.int32),
             )
             if t > 1 and not self.is_initializing():
-                fed.value = fed.value + jnp.concatenate(
-                    [jnp.ones_like(stats[:, :1]), stats], axis=1
-                )[None]
+                with jax.named_scope("cache_write"):
+                    fed.value = fed.value + jnp.concatenate(
+                        [jnp.ones_like(stats[:, :1]), stats], axis=1
+                    )[None]
         logits = None
         if head or self.is_initializing():
             with jax.named_scope("head"):
@@ -505,14 +521,15 @@ class LatentMoE(nn.Module):
              "cache": jax.tree.map(lambda c: c[:, 0], cache)},
             toks[:, None], pos[:, None], live[:, None], mutable=["cache"],
         )
-        new = dict(mut["cache"])
-        fed = jnp.sum(new["moe_feed"], axis=0)
-        new["moe_feed"] = jnp.zeros_like(new["moe_feed"])
-        return (
-            logits[:, 0],
-            jax.tree.map(lambda c: c[:, None], new),
-            jnp.concatenate([stats.reshape(-1), fed.reshape(-1)]),
-        )
+        with jax.named_scope("sample"):  # the slots' bookkeeping
+            new = dict(mut["cache"])
+            fed = jnp.sum(new["moe_feed"], axis=0)
+            new["moe_feed"] = jnp.zeros_like(new["moe_feed"])
+            return (
+                logits[:, 0],
+                jax.tree.map(lambda c: c[:, None], new),
+                jnp.concatenate([stats.reshape(-1), fed.reshape(-1)]),
+            )
 
     def fold_counts(self, counts, n_live: int) -> dict:
         """What ``decode_slots`` reported for one step, as increments of

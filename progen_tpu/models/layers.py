@@ -11,6 +11,13 @@ Behavioral parity targets (cited into /root/reference/progen_transformer/):
 Every weight carries flax logical-axis metadata so the whole model shards
 through one rule table (progen_tpu/parallel/partition.py). LayerNorms are
 scale-only (create_offset=False in the reference, progen.py:22).
+
+Device work is filed under mechanism classes (``jax.named_scope``;
+``telemetry/scopes.py``; the innermost class in an op's name stack wins):
+``ProGen`` puts a whole attention block under ``project`` and a whole
+feed-forward block under ``ffn``; here the attention proper (every
+dispatch path) and the SGU's mix over its gate history are ``attend``,
+and every cache write (``_write_rows``) is ``cache_write``.
 """
 
 from __future__ import annotations
@@ -98,13 +105,14 @@ def _update_at(axis: int):
 def _write_rows(buf, new, start, axis, rows: DecodeRows):
     """Write the block's T rows into ``buf`` at ``start`` along ``axis``,
     keeping what the buffer holds wherever a row is not live."""
-    t = rows.pos.shape[0]
-    if rows.live is not None:
-        shape = [1] * new.ndim
-        shape[axis] = t
-        old = jax.lax.dynamic_slice_in_dim(buf, start, t, axis=axis)
-        new = jnp.where(rows.live.reshape(shape), new, old)
-    return _update_at(axis % buf.ndim)(buf, new, start)
+    with jax.named_scope("cache_write"):
+        t = rows.pos.shape[0]
+        if rows.live is not None:
+            shape = [1] * new.ndim
+            shape[axis] = t
+            old = jax.lax.dynamic_slice_in_dim(buf, start, t, axis=axis)
+            new = jnp.where(rows.live.reshape(shape), new, old)
+        return _update_at(axis % buf.ndim)(buf, new, start)
 
 
 def _cached_shift(module: nn.Module, x: jnp.ndarray,
@@ -272,9 +280,8 @@ class LocalAttentionBlock(nn.Module):
         if c.rotate_value:  # reference rotates v too (progen.py:87)
             v = apply_rotary_pos_emb(v, sin, cos)
 
-        # one scope over every dispatch path: XProf rows read
-        # "attention_core" whether the step ran XLA, ring, or Pallas
-        with jax.named_scope("attention_core"):
+        # one scope over every dispatch path: XLA, ring, or Pallas
+        with jax.named_scope("attend/local"):
             if c.decode:
                 out = self._decode_attend(q, k, v, rows)  # (b, h, T, dh)
             elif (
@@ -450,7 +457,7 @@ class SpatialGatingUnit(nn.Module):
             c.params_dtype,
         )
 
-        with jax.named_scope("sgu_spatial_mix"):
+        with jax.named_scope("attend/sgu"):
             if c.decode:
                 # incremental spatial mix: keep the LayerNormed gate
                 # history and contract the block's causal rows of the
@@ -528,12 +535,11 @@ class FeedForwardBlock(nn.Module):
             name="proj_in",
         )(x)
 
-        with jax.named_scope("ffn_activation"):
-            if self.glu:
-                x, gate = jnp.split(x, 2, axis=-1)
-                x = x * jax.nn.gelu(gate)
-            else:
-                x = jax.nn.gelu(x)
+        if self.glu:
+            x, gate = jnp.split(x, 2, axis=-1)
+            x = x * jax.nn.gelu(gate)
+        else:
+            x = jax.nn.gelu(x)
 
         if self.spatial_gate:
             x = SpatialGatingUnit(c, dim_out=hidden // 2, name="sgu")(x, rows)

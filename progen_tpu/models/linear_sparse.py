@@ -50,6 +50,15 @@ multiple of T (``feed_tokens`` feeds aligned blocks). One row (the
 serving pool's decode step) GATHERS its chosen blocks and never reads a
 whole ``k`` / ``v`` leaf; a block of rows computes its scores over the
 rows up to its own end, in chunks, under the selection's mask.
+
+Mechanism classes (``jax.named_scope``, ``telemetry/scopes.py``): the
+embedding and the head are ``head``; a layer's mixer with its norm and
+residual add is ``project`` (``project/linear``, ``project/sparse``); the
+recurrence (``attend/linear``), the index (``attend/index``: pool, score,
+top-k, select), the attention (``attend/sparse``) and every read of
+cached rows (``_slice_at``) are ``attend``; every row and pooled-key
+write (``_write_rows``) is ``cache_write``; a SwiGLU with its norm and
+residual add is ``ffn``.
 """
 
 from __future__ import annotations
@@ -245,6 +254,10 @@ def _slice_at(size: int):
 
     sliced = jax.custom_batching.custom_vmap(plain)
 
+    def read(buf, start):
+        with jax.named_scope("attend"):
+            return sliced(buf, start)
+
     @sliced.def_vmap
     def per_row(axis_size, in_batched, buf, start):
         buf, start = (
@@ -261,7 +274,7 @@ def _slice_at(size: int):
             for i in range(axis_size)
         ]), True
 
-    return sliced
+    return read
 
 
 def _grouped(rows, groups: int):
@@ -292,9 +305,10 @@ def _write_rows(buf, new, start, live=None):
             b = _update_at(0)(b, rows, i * s + p0)
         return b
 
-    if live is None:
-        return jax.vmap(one)(buf, new, start)
-    return jax.vmap(one)(buf, new, start, live)
+    with jax.named_scope("cache_write"):
+        if live is None:
+            return jax.vmap(one)(buf, new, start)
+        return jax.vmap(one)(buf, new, start, live)
 
 
 # ----- the lightning mixer's core, float32 throughout -----------------------
@@ -384,7 +398,7 @@ class LightningMixer(nn.Module):
         )
         slopes = jnp.asarray(decay_slopes(c, self.layer))
         f32 = jnp.float32
-        with jax.named_scope("linear/project"):
+        with jax.named_scope("project/linear"):
             q = _rms_norm((u @ w_q).reshape(b, t, h, d), q_norm, c.rms_norm_eps)
             k = _rms_norm((u @ w_k).reshape(b, t, h, d), k_norm, c.rms_norm_eps)
             v = (u @ w_v).reshape(b, t, h, d)
@@ -392,7 +406,7 @@ class LightningMixer(nn.Module):
             k = _rope(k, positions, c.rope_theta, False).astype(f32)
             v = v.astype(f32)
             gate = jax.nn.sigmoid(u @ w_g)
-        with jax.named_scope("linear/scan"):
+        with jax.named_scope("attend/linear"):
             if not c.decode:
                 o = _lightning_sequence(q, k, v, slopes, c.feed_rows)
             else:
@@ -404,8 +418,8 @@ class LightningMixer(nn.Module):
                 o, state = step(q, k, v, kept.value.astype(f32), live, slopes)
                 if not self.is_initializing():
                     kept.value = state.astype(STATE_DTYPE)
+        with jax.named_scope("project/linear"):
             o = _rms_norm(o / math.sqrt(d), o_norm.astype(f32), c.rms_norm_eps)
-        with jax.named_scope("linear/project"):
             return (o.reshape(b, t, h * d).astype(u.dtype) * gate) @ w_o
 
 
@@ -616,7 +630,7 @@ class SparseMixer(nn.Module):
         w_o = self.param("w_o", _init(), (h * d, dm), pd)
         q_norm = self.param("q_norm", nn.initializers.ones, (d,), pd)
         k_norm = self.param("k_norm", nn.initializers.ones, (d,), pd)
-        with jax.named_scope("sparse/project"):
+        with jax.named_scope("project/sparse"):
             q = _rms_norm((u @ w_q).reshape(b, t, g, a, d), q_norm,
                           c.rms_norm_eps)
             k = _rms_norm((u @ w_k).reshape(b, t, g, d), k_norm, c.rms_norm_eps)
@@ -629,12 +643,12 @@ class SparseMixer(nn.Module):
             pad = -t % bs
             k_all, v_all = (jnp.pad(x, [(0, 0), (0, 0), (0, pad), (0, 0)])
                             for x in (k, v))
-            with jax.named_scope("sparse/index"):
+            with jax.named_scope("attend/index"):
                 first = jnp.zeros_like(k_all[:, :, :1])  # entry 0: no window
                 ck = jnp.concatenate(
                     [first, _pool_windows(k_all, st).astype(u.dtype)], axis=2)
                 ids, valid, chosen = _choose_for_rows(q, ck, positions, c)
-            with jax.named_scope("sparse/attend"):
+            with jax.named_scope("attend/sparse"):
                 o = _attend_rows(q, k_all, v_all, chosen, positions, c)
         else:
             shape = (b, g * c.cache_len, d)
@@ -653,26 +667,26 @@ class SparseMixer(nn.Module):
                 # writes whether it is live or not: what a dead row of the
                 # serving pool holds means nothing until an admission
                 # rewrites the slot's whole tree
-                with jax.named_scope("sparse/project"):
-                    keep = None if t == 1 else live
-                    k_all = _write_rows(k_all, k, start, keep)
-                    v_all = _write_rows(v_all, v, start, keep)
-                with jax.named_scope("sparse/index"):
+                keep = None if t == 1 else live
+                k_all = _write_rows(k_all, k, start, keep)
+                v_all = _write_rows(v_all, v, start, keep)
+                with jax.named_scope("attend/index"):
                     ck = self._pool(k_all, ck, start, live)
                 cache_k.value, cache_v.value, cache_ck.value = k_all, v_all, ck
             if t == 1:
                 o, ids, valid = self._one_row(q, k_all, v_all, ck, start, live)
             else:
-                with jax.named_scope("sparse/index"):
+                with jax.named_scope("attend/index"):
                     ids, valid, chosen = _choose_for_rows(
                         q, _grouped(ck, g), positions, c)
-                with jax.named_scope("sparse/attend"):
+                with jax.named_scope("attend/sparse"):
                     o = _attend_rows(q, _grouped(k_all, g), _grouped(v_all, g),
                                      chosen, positions, c)
         # for the tests and the benchmark's check: the blocks chosen
         self.sow("intermediates", "blocks", jnp.where(valid, ids, -1))
-        counts = _row_counts(positions, live, valid[:, 0].sum(-1), c)
-        with jax.named_scope("sparse/project"):
+        with jax.named_scope("attend/index"):
+            counts = _row_counts(positions, live, valid[:, 0].sum(-1), c)
+        with jax.named_scope("project/sparse"):
             return (o.reshape(b, t, h * d) * gate) @ w_o, counts
 
     def _pool(self, k_all, ck, start, live):
@@ -718,15 +732,15 @@ class SparseMixer(nn.Module):
                                  _grouped(v_all, g)[:, :, :first], t)
 
         if c.cache_len <= c.sparse_dense_len:  # no row ever selects
-            with jax.named_scope("sparse/attend"):
+            with jax.named_scope("attend/sparse"):
                 k_sel = min(c.sparse_topk, c.cache_len // c.sparse_block_size)
                 return (below()[:, None], jnp.zeros((b, g, 1, k_sel), jnp.int32),
                         jnp.zeros((b, g, 1, k_sel), bool))
-        with jax.named_scope("sparse/index"):
+        with jax.named_scope("attend/index"):
             ids, valid = _select_blocks(
                 _block_scores(q[:, None], _grouped(ck, g), t[:, None], c),
                 t[:, None], c)
-        with jax.named_scope("sparse/attend"):
+        with jax.named_scope("attend/sparse"):
             o = _attend_chosen(q, k_all, v_all, ids[:, :, 0], valid[:, :, 0],
                                t, c)
             dense = t < c.sparse_dense_len
@@ -765,30 +779,35 @@ class LinearSparse(nn.Module):
             positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
         if live is None:
             live = jnp.ones((b, t), bool)
-        x = c.scale_emb * nn.Embed(
-            c.vocab_size, c.hidden_size, dtype=c.compute_dtype,
-            param_dtype=c.params_dtype, embedding_init=_init(), name="embed",
-        )(tokens)
+        with jax.named_scope("head"):
+            x = c.scale_emb * nn.Embed(
+                c.vocab_size, c.hidden_size, dtype=c.compute_dtype,
+                param_dtype=c.params_dtype, embedding_init=_init(),
+                name="embed",
+            )(tokens)
         r = c.scale_depth / math.sqrt(c.depth)
         counts = []
         for i, kind in enumerate(c.mixer_types):
             scale = self.param(f"norm_mix{i}", nn.initializers.ones,
                                (c.hidden_size,), c.params_dtype)
-            u = _rms_norm(x, scale, c.rms_norm_eps)
-            if kind == LIGHTNING:
-                y = LightningMixer(c, c.first_layer + i, name=f"mix{i}")(
-                    u, positions, live)
-            else:
-                y, seen = SparseMixer(c, name=f"mix{i}")(u, positions, live)
-                counts.append(seen)
-            x = x + r * y
+            with jax.named_scope("project"):
+                u = _rms_norm(x, scale, c.rms_norm_eps)
+                if kind == LIGHTNING:
+                    y = LightningMixer(c, c.first_layer + i, name=f"mix{i}")(
+                        u, positions, live)
+                else:
+                    y, seen = SparseMixer(c, name=f"mix{i}")(
+                        u, positions, live)
+                    counts.append(seen)
+                x = x + r * y
             scale = self.param(f"norm_mlp{i}", nn.initializers.ones,
                                (c.hidden_size,), c.params_dtype)
-            with jax.named_scope("mlp"):
+            with jax.named_scope("ffn"):
                 x = x + r * DenseFFN(c, c.intermediate_size, name=f"mlp{i}")(
                     _rms_norm(x, scale, c.rms_norm_eps))
-        counts = (jnp.stack(counts) if counts
-                  else jnp.zeros((0, 3), jnp.int32))
+        with jax.named_scope("attend/index"):
+            counts = (jnp.stack(counts) if counts
+                      else jnp.zeros((0, 3), jnp.int32))
         if c.decode:
             # what the blocks fed through this cache met, kept with it
             # until a decode step's read carries it to the host: per
@@ -800,14 +819,15 @@ class LinearSparse(nn.Module):
                 lambda: jnp.zeros((b, c.n_sparse_layers, 7), jnp.int32),
             )
             if t > 1 and not self.is_initializing():
-                words = jnp.stack(
-                    [counts // _WORD, counts % _WORD], axis=-1
-                ).reshape(-1, 6)
-                total = fed.value + jnp.concatenate(
-                    [jnp.ones_like(words[:, :1]), words], axis=1)[None]
-                carry = total[..., 2::2] // _WORD
-                total = total.at[..., 1::2].add(carry)
-                fed.value = total.at[..., 2::2].add(-carry * _WORD)
+                with jax.named_scope("cache_write"):
+                    words = jnp.stack(
+                        [counts // _WORD, counts % _WORD], axis=-1
+                    ).reshape(-1, 6)
+                    total = fed.value + jnp.concatenate(
+                        [jnp.ones_like(words[:, :1]), words], axis=1)[None]
+                    carry = total[..., 2::2] // _WORD
+                    total = total.at[..., 1::2].add(carry)
+                    fed.value = total.at[..., 2::2].add(-carry * _WORD)
         logits = None
         if head or self.is_initializing():
             with jax.named_scope("head"):
@@ -855,14 +875,15 @@ class LinearSparse(nn.Module):
              "cache": jax.tree.map(lambda c: c[:, 0], cache)},
             toks[:, None], pos[:, None], live[:, None], mutable=["cache"],
         )
-        new = dict(mut["cache"])
-        fed = jnp.sum(new["sparse_feed"], axis=0)
-        new["sparse_feed"] = jnp.zeros_like(new["sparse_feed"])
-        return (
-            logits[:, 0],
-            jax.tree.map(lambda c: c[:, None], new),
-            jnp.concatenate([counts.reshape(-1), fed.reshape(-1)]),
-        )
+        with jax.named_scope("sample"):  # the slots' bookkeeping
+            new = dict(mut["cache"])
+            fed = jnp.sum(new["sparse_feed"], axis=0)
+            new["sparse_feed"] = jnp.zeros_like(new["sparse_feed"])
+            return (
+                logits[:, 0],
+                jax.tree.map(lambda c: c[:, None], new),
+                jnp.concatenate([counts.reshape(-1), fed.reshape(-1)]),
+            )
 
     def fold_counts(self, counts, n_live: int) -> dict:
         """What ``decode_slots`` reported for one step, as increments of

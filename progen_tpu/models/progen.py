@@ -21,10 +21,18 @@ TPU-first deltas:
     hk.Embed's TruncatedNormal(stddev=1.0) default (ref progen.py:207);
     the GPT-style small init trains more stably. Weight-transplant parity
     tests are init-independent (tests/test_reference_parity.py).
+
+Mechanism classes (``jax.named_scope``, ``telemetry/scopes.py``): the
+embedding, final norm and logits are ``head``; each attention block with
+its residual add (and the decode positions and RoPE tables it reads) is
+``project``, each feed-forward block with its residual add ``ffn``;
+``layers.py`` files the attention proper and the SGU mix under
+``attend`` and the cache writes under ``cache_write``.
 """
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
@@ -49,11 +57,15 @@ class UniformBlock(nn.Module):
     @nn.compact
     def __call__(self, x, sin, cos):
         c = self.config
-        x = x + LocalAttentionBlock(c, mesh=self.mesh, name="attn")(
-            x, sin, cos, None
-        )
-        x = x + FeedForwardBlock(c, glu=self.glu, name="ff")(x, None)
-        x = nn.with_logical_constraint(x, ("batch", "seq_act", "embed_act"))
+        with jax.named_scope("project"):
+            x = x + LocalAttentionBlock(c, mesh=self.mesh, name="attn")(
+                x, sin, cos, None
+            )
+        with jax.named_scope("ffn"):
+            x = x + FeedForwardBlock(c, glu=self.glu, name="ff")(x, None)
+            x = nn.with_logical_constraint(
+                x, ("batch", "seq_act", "embed_act")
+            )
         return x, None
 
 
@@ -144,39 +156,45 @@ class ProGen(nn.Module):
         n = tokens.shape[-1]
         assert c.decode or feed_to is None, "feed_to is for decode mode"
 
-        x = nn.Embed(
-            c.num_tokens,
-            c.dim,
-            dtype=c.compute_dtype,
-            param_dtype=c.params_dtype,
-            embedding_init=nn.with_logical_partitioning(
-                nn.initializers.truncated_normal(stddev=0.02), ("vocab", "embed")
-            ),
-            name="embed",
-        )(tokens)
-        x = nn.with_logical_constraint(x, ("batch", "seq_act", "embed_act"))
+        with jax.named_scope("head"):
+            x = nn.Embed(
+                c.num_tokens,
+                c.dim,
+                dtype=c.compute_dtype,
+                param_dtype=c.params_dtype,
+                embedding_init=nn.with_logical_partitioning(
+                    nn.initializers.truncated_normal(stddev=0.02),
+                    ("vocab", "embed"),
+                ),
+                name="embed",
+            )(tokens)
+            x = nn.with_logical_constraint(
+                x, ("batch", "seq_act", "embed_act")
+            )
 
-        if c.decode:
-            # full-length RoPE tables (blocks slice their rows), one
-            # shared position counter advanced per call
-            assert c.window_size % n == 0, (
-                f"a decode call feeds {n} positions, which must divide "
-                f"window_size={c.window_size}"
-            )
-            pos_var = self.variable(
-                "cache", "pos", lambda: jnp.zeros((), jnp.int32)
-            )
-            pos = pos_var.value
-            at = (pos - pos % n if n > 1 else pos) + jnp.arange(n)
-            rows = DecodeRows(
-                at,
-                None if feed_to is None else (at >= pos) & (at < feed_to),
-            )
-            sin, cos = fixed_pos_embedding(c.seq_len, c.dim_head)
-        else:
-            rows = None
-            # RoPE tables are tiny; build in f32 once per trace (progen.py:227)
-            sin, cos = fixed_pos_embedding(n, c.dim_head)
+        with jax.named_scope("project"):
+            if c.decode:
+                # full-length RoPE tables (blocks slice their rows), one
+                # shared position counter advanced per call
+                assert c.window_size % n == 0, (
+                    f"a decode call feeds {n} positions, which must divide "
+                    f"window_size={c.window_size}"
+                )
+                pos_var = self.variable(
+                    "cache", "pos", lambda: jnp.zeros((), jnp.int32)
+                )
+                pos = pos_var.value
+                at = (pos - pos % n if n > 1 else pos) + jnp.arange(n)
+                rows = DecodeRows(
+                    at,
+                    None if feed_to is None else (at >= pos) & (at < feed_to),
+                )
+                sin, cos = fixed_pos_embedding(c.seq_len, c.dim_head)
+            else:
+                rows = None
+                # RoPE tables are tiny; build in f32 once per trace
+                # (progen.py:227)
+                sin, cos = fixed_pos_embedding(n, c.dim_head)
 
         attn_cls, ff_cls = LocalAttentionBlock, FeedForwardBlock
         if c.remat and not c.decode:
@@ -204,31 +222,39 @@ class ProGen(nn.Module):
         for i in range(start, c.depth):
             use_gmlp = (c.depth - i) <= c.global_mlp_depth
             use_glu = (not use_gmlp) and c.ff_glu
-            x = x + attn_cls(c, mesh=self.mesh, name=f"attn{i}")(
-                x, sin, cos, rows
-            )
-            x = x + ff_cls(
-                c, glu=use_glu, spatial_gate=use_gmlp, name=f"ff{i}"
-            )(x, rows)
-            x = nn.with_logical_constraint(x, ("batch", "seq_act", "embed_act"))
+            with jax.named_scope("project"):
+                x = x + attn_cls(c, mesh=self.mesh, name=f"attn{i}")(
+                    x, sin, cos, rows
+                )
+            with jax.named_scope("ffn"):
+                x = x + ff_cls(
+                    c, glu=use_glu, spatial_gate=use_gmlp, name=f"ff{i}"
+                )(x, rows)
+                x = nn.with_logical_constraint(
+                    x, ("batch", "seq_act", "embed_act")
+                )
 
         if c.decode and not self.is_initializing():
-            pos_var.value = pos + (
-                n if rows.live is None
-                else jnp.sum(rows.live.astype(jnp.int32))
-            )
+            with jax.named_scope("cache_write"):
+                pos_var.value = pos + (
+                    n if rows.live is None
+                    else jnp.sum(rows.live.astype(jnp.int32))
+                )
 
-        x = ScaleNorm(c.layer_norm_epsilon, c.compute_dtype, c.params_dtype)(x)
-        logits = nn.Dense(
-            c.num_tokens,
-            dtype=c.compute_dtype,
-            param_dtype=c.params_dtype,
-            kernel_init=nn.with_logical_partitioning(
-                nn.initializers.lecun_normal(), ("embed", "vocab")
-            ),
-            bias_init=nn.with_logical_partitioning(
-                nn.initializers.zeros, ("vocab",)
-            ),
-            name="to_logits",
-        )(x)
-        return logits.astype(jnp.float32)
+        with jax.named_scope("head"):
+            x = ScaleNorm(
+                c.layer_norm_epsilon, c.compute_dtype, c.params_dtype
+            )(x)
+            logits = nn.Dense(
+                c.num_tokens,
+                dtype=c.compute_dtype,
+                param_dtype=c.params_dtype,
+                kernel_init=nn.with_logical_partitioning(
+                    nn.initializers.lecun_normal(), ("embed", "vocab")
+                ),
+                bias_init=nn.with_logical_partitioning(
+                    nn.initializers.zeros, ("vocab",)
+                ),
+                name="to_logits",
+            )(x)
+            return logits.astype(jnp.float32)

@@ -595,12 +595,14 @@ def feed_tokens(model, params, cache, tokens, lo, hi):
 
     def feed(blk, cache):
         # rows past the buffer's end are dead: any token will do there
-        at = jnp.minimum(blk * t + jnp.arange(t), last)
+        with jax.named_scope("head"):  # the block's tokens to embed
+            at = jnp.minimum(blk * t + jnp.arange(t), last)
+            fed = tokens[:, at]
         _, mut = model.apply(
-            {"params": params, "cache": cache}, tokens[:, at], hi,
-            mutable=["cache"],
+            {"params": params, "cache": cache}, fed, hi, mutable=["cache"],
         )
-        return jax.tree.map(_row_major, mut["cache"])
+        with jax.named_scope("cache_write"):
+            return jax.tree.map(_row_major, mut["cache"])
 
     return jax.lax.fori_loop(
         lo // t, jnp.where(hi > lo, -(-hi // t), lo // t), feed, cache

@@ -20,7 +20,12 @@ two that read weights take ``ServeEngine.served_params``: for a float
 engine the tree with every leaf the model would convert at each use held
 in the compute type, for an int8 engine the quantized pair
 (``ops.quant.QuantizedParams``), which the programs dequantize on the
-device. One set of programs, whatever the engine was handed.
+device. One set of programs, whatever the engine was handed. Their
+device ops are filed under the mechanism classes of
+``telemetry/scopes.py``: the model files its own, the decode step's draw
+and bookkeeping after the logits are ``sample``, and ``_prefill_finish``
+scatters the cache under ``cache_write`` and the slot's state under
+``sample``.
 
 A prompt goes through a batch-1 cache in BLOCKS of positions
 (``sampling.feed_tokens``): one ``model.apply`` of ``prefill_width``
@@ -128,41 +133,45 @@ def _scatter_slot(
 ):
     """Scatter a fully primed batch-1 cache + all per-slot state into
     the pool and mark ``slot`` live. Pure data movement (no model
-    arithmetic): the body of ``_prefill_finish``."""
+    arithmetic): the body of ``_prefill_finish``. The cache's scatter is
+    ``cache_write``, the slot's state ``sample`` (its bookkeeping)."""
     length = slots.seqs.shape[1]
-    cache = jax.tree.map(
-        lambda pool, c: jax.lax.dynamic_update_index_in_dim(
-            pool, c, slot, axis=0
-        ),
-        slots.cache,
-        cache1,
-    )
-    # zeros already present in the primed region count toward the
-    # stop-at-second-zero rule (same cumsum the standalone decoders apply)
-    nz0 = jnp.sum(
-        ((tokens == 0) & (jnp.arange(length) < start)).astype(jnp.int32)
-    )
-    return SlotBatch(
-        cache=cache,
-        seqs=jax.lax.dynamic_update_index_in_dim(
-            slots.seqs, tokens, slot, axis=0
-        ),
-        cur=slots.cur.at[slot].set(start - 1),
-        keys=slots.keys.at[slot].set(key),
-        nz=slots.nz.at[slot].set(nz0),
-        target=slots.target.at[slot].set(target),
-        temp=slots.temp.at[slot].set(temp),
-        top_p=slots.top_p.at[slot].set(top_p),
-        top_k=slots.top_k.at[slot].set(top_k),
-        parity=slots.parity.at[slot].set(parity),
-        live=slots.live.at[slot].set(True),
-        template=jax.lax.dynamic_update_index_in_dim(
-            slots.template, template, slot, axis=0
-        ),
-        frozen=jax.lax.dynamic_update_index_in_dim(
-            slots.frozen, frozen, slot, axis=0
-        ),
-    )
+    with jax.named_scope("cache_write"):
+        cache = jax.tree.map(
+            lambda pool, c: jax.lax.dynamic_update_index_in_dim(
+                pool, c, slot, axis=0
+            ),
+            slots.cache,
+            cache1,
+        )
+    with jax.named_scope("sample"):
+        # zeros already present in the primed region count toward the
+        # stop-at-second-zero rule (same cumsum the standalone decoders
+        # apply)
+        nz0 = jnp.sum(
+            ((tokens == 0) & (jnp.arange(length) < start)).astype(jnp.int32)
+        )
+        return SlotBatch(
+            cache=cache,
+            seqs=jax.lax.dynamic_update_index_in_dim(
+                slots.seqs, tokens, slot, axis=0
+            ),
+            cur=slots.cur.at[slot].set(start - 1),
+            keys=slots.keys.at[slot].set(key),
+            nz=slots.nz.at[slot].set(nz0),
+            target=slots.target.at[slot].set(target),
+            temp=slots.temp.at[slot].set(temp),
+            top_p=slots.top_p.at[slot].set(top_p),
+            top_k=slots.top_k.at[slot].set(top_k),
+            parity=slots.parity.at[slot].set(parity),
+            live=slots.live.at[slot].set(True),
+            template=jax.lax.dynamic_update_index_in_dim(
+                slots.template, template, slot, axis=0
+            ),
+            frozen=jax.lax.dynamic_update_index_in_dim(
+                slots.frozen, frozen, slot, axis=0
+            ),
+        )
 
 
 @functools.partial(
@@ -215,7 +224,8 @@ def _prefill_finish(slots, cache1, slot, tokens, start, target, key,
     and no weights."""
     pool = _scatter_slot(slots, cache1, slot, tokens, start, target, key,
                          temp, top_p, top_k, parity, template, frozen)
-    return pool, new_cache()
+    with jax.named_scope("cache_write"):
+        return pool, new_cache()
 
 
 @jax.jit
@@ -259,11 +269,12 @@ def _decode_step_impl(model, params, slots: SlotBatch):
         return logits[0, 0], mut["cache"]
 
     logits, cache = jax.vmap(one)(slots.cache, toks)
-    keys, sampled = jax.vmap(gumbel_step_dynamic)(
-        slots.keys, logits, slots.top_k, slots.parity, slots.temp,
-        slots.top_p,
-    )
-    return _write_sampled(slots, cache, logits, keys, sampled)
+    with jax.named_scope("sample"):
+        keys, sampled = jax.vmap(gumbel_step_dynamic)(
+            slots.keys, logits, slots.top_k, slots.parity, slots.temp,
+            slots.top_p,
+        )
+        return _write_sampled(slots, cache, logits, keys, sampled)
 
 
 def _decode_step_batched(model, params, slots: SlotBatch, pos):
@@ -279,15 +290,16 @@ def _decode_step_batched(model, params, slots: SlotBatch, pos):
     logits, cache, counts = model.decode_slots(
         params, slots.cache, toks, pos, slots.live
     )
-    keys, sampled = gumbel_step_slots(
-        slots.keys, logits, slots.top_k, slots.parity, slots.temp,
-        slots.top_p, slots.live,
-    )
-    new, sampled, live, finished = _write_sampled(
-        slots, cache, logits, keys, sampled
-    )
-    return new, jnp.concatenate([sampled, counts.astype(sampled.dtype)]), \
-        live, finished
+    with jax.named_scope("sample"):
+        keys, sampled = gumbel_step_slots(
+            slots.keys, logits, slots.top_k, slots.parity, slots.temp,
+            slots.top_p, slots.live,
+        )
+        new, sampled, live, finished = _write_sampled(
+            slots, cache, logits, keys, sampled
+        )
+        counts = counts.astype(sampled.dtype)
+        return new, jnp.concatenate([sampled, counts]), live, finished
 
 
 def _write_sampled(slots: SlotBatch, cache, logits, keys, sampled):

@@ -7,8 +7,11 @@ hung or slow run into a one-line diagnosis (MegaScale NSDI'24, Dapper
 2010 — PAPERS.md "Observability"):
 
   * ``span("ckpt/save")`` — context-manager spans emitting begin/end
-    records to a crash-safe ``events.jsonl`` (per-line flush) and
-    entering ``jax.named_scope`` so XProf traces carry the same labels;
+    records to a crash-safe ``events.jsonl`` (per-line flush), on the
+    profiler's clock as host spans (``TraceAnnotation``);
+  * ``scopes.CLASSES`` — the mechanism classes every program files its
+    device ops under (``jax.named_scope``), which the benchmark's
+    ``device_scopes`` reader sums per program from a trace;
   * ``GoodputLedger`` — classifies train-loop wall clock into
     compile/step/data/checkpoint/eval/sample/log buckets and reports
     ``goodput_pct`` next to MFU;

@@ -1,4 +1,4 @@
-"""Span API: context-manager tracing to events.jsonl + jax.named_scope.
+"""Span API: context-manager tracing to events.jsonl.
 
 A span is one timed region of host code (``span("ckpt/save")``). On
 entry a ``B`` (begin) record goes to the sink; on exit an ``E`` (end)
@@ -7,10 +7,12 @@ record with the duration. Crash forensics fall out of the format: a
 process died in — no log-diving required (Dapper-style span trees,
 sized for one process).
 
-Device-side visibility rides the same call: the span body runs under
-``jax.named_scope(name)``, so any op traced inside it carries the span
-name into XProf/TensorBoard timelines. jax is imported lazily and its
-absence is tolerated (pure-host tools can use spans too).
+A span is a span of HOST time: like a stage (below) it shows on the
+profiler's clock through ``jax.profiler.TraceAnnotation`` while a trace
+is being taken. It names no device op: a jitted program does not take
+the name stack it is called under, so the device's work is labelled by
+the programs' own scopes (``telemetry/scopes.py``). jax is imported
+lazily and its absence is tolerated (pure-host tools can use spans too).
 
 The module-level ``span()``/``configure()`` pair operates a process
 global ``Telemetry`` so deep callees (checkpoint.py, bench phases) can
@@ -19,7 +21,7 @@ sink configured spans still maintain the in-memory recent/open ring
 (what the stall watchdog reports) at ~zero cost.
 
 ``stage(name)`` is the span's hot-path sibling: no record, no sink, no
-hook, no ``named_scope`` — two clock reads, a
+hook — two clock reads, a
 ``jax.profiler.TraceAnnotation`` (so the stage shows on the device's
 clock in any profiler trace, and costs nothing while no trace is being
 taken) and one tuple appended to an in-memory ring that a benchmark or
@@ -124,15 +126,6 @@ SPAN_ENTRY_HOOKS: list = []
 # never raise and never block (it runs on the training/serving hot
 # path). Empty list = no-op.
 EMIT_TAPS: list = []
-
-
-def _named_scope(name: str):
-    try:
-        import jax
-
-        return jax.named_scope(name)
-    except Exception:  # jax absent or name rejected: spans still time
-        return contextlib.nullcontext()
 
 
 # bound once: the stage's budget is under two microseconds
@@ -261,8 +254,7 @@ class Telemetry:
             try:
                 for hook in SPAN_ENTRY_HOOKS:
                     hook(name)
-                with _named_scope(name):
-                    yield
+                yield
             finally:
                 dur = time.perf_counter() - t0
                 end = {

@@ -43,12 +43,14 @@ def batch_loss(model, params, data: jnp.ndarray, forward_fn=None) -> jnp.ndarray
     (matches vmap-then-mean of utils.py:67,77). ``forward_fn(params, ids)
     -> logits`` overrides the plain ``model.apply`` (e.g. the pipelined
     forward, parallel/pipeline.make_pipeline_train_step)."""
-    ids, labels = data[..., :-1], data[..., 1:]
+    with jax.named_scope("head"):
+        ids, labels = data[..., :-1], data[..., 1:]
     if forward_fn is None:
         logits = model.apply({"params": params}, ids)
     else:
         logits = forward_fn(params, ids)
-    return cross_entropy(logits, labels).mean()
+    with jax.named_scope("head"):
+        return cross_entropy(logits, labels).mean()
 
 
 def make_train_step(
@@ -67,8 +69,9 @@ def make_train_step(
     """
 
     def train_step(state: TrainState, batch: jnp.ndarray):
-        # named_scope labels land in XProf/TensorBoard traces, so a
-        # profile splits cleanly into grads vs optimizer time
+        # the model files its forward and backward under its mechanism
+        # classes; what the step adds around them — gradient
+        # accumulation, the update, the finite gate — is ``optimizer``
         with nn.logical_axis_rules(rules):
             grad_fn = jax.value_and_grad(
                 lambda p, mb: batch_loss(model, p, mb, forward_fn)
@@ -76,43 +79,44 @@ def make_train_step(
 
             def micro(grads_acc, mb):
                 loss, grads = grad_fn(state.params, mb)
-                grads_acc = jax.tree.map(jnp.add, grads_acc, grads)
+                with jax.named_scope("optimizer"):
+                    grads_acc = jax.tree.map(jnp.add, grads_acc, grads)
                 return grads_acc, loss
 
-            with jax.named_scope("microbatch_grads"):
+            with jax.named_scope("optimizer"):
                 zero_grads = jax.tree.map(jnp.zeros_like, state.params)
-                grads, losses = jax.lax.scan(micro, zero_grads, batch)
+            grads, losses = jax.lax.scan(micro, zero_grads, batch)
+            with jax.named_scope("optimizer"):
                 grads = jax.tree.map(lambda g: g / batch.shape[0], grads)
-
-            with jax.named_scope("optimizer_update"):
                 updates, opt_state = optimizer.update(
                     grads, state.opt_state, state.params
                 )
                 params = optax.apply_updates(state.params, updates)
 
-            # finite gate: the state is DONATED, so a poisoned update can
-            # never be undone host-side — refuse it on-device instead.
-            # When any micro-loss or the grad norm is non-finite the step
-            # re-emits the incoming state (step counter included), and the
-            # anomaly sentinel (resilience/anomaly.py) sees the bad
-            # metrics and decides skip vs rollback.
-            grad_norm = optax.global_norm(grads)
-            with jax.named_scope("finite_gate"):
+                # finite gate: the state is DONATED, so a poisoned update
+                # can never be undone host-side — refuse it on-device
+                # instead. When any micro-loss or the grad norm is
+                # non-finite the step re-emits the incoming state (step
+                # counter included), and the anomaly sentinel
+                # (resilience/anomaly.py) sees the bad metrics and decides
+                # skip vs rollback.
+                grad_norm = optax.global_norm(grads)
                 ok = jnp.isfinite(losses).all() & jnp.isfinite(grad_norm)
                 gate = lambda new, old: jnp.where(ok, new, old)
                 params = jax.tree.map(gate, params, state.params)
                 opt_state = jax.tree.map(gate, opt_state, state.opt_state)
-            # step still advances on a refusal — the batch was consumed,
-            # and the data cursor must agree with the step count on resume
-            new_state = state.replace(
-                step=state.step + 1, params=params, opt_state=opt_state
-            )
-            metrics = {
-                "loss": losses.mean(),
-                "last_micro_loss": losses[-1],
-                "grad_norm": grad_norm,
-                "skipped": (~ok).astype(jnp.int32),
-            }
+                # step still advances on a refusal — the batch was
+                # consumed, and the data cursor must agree with the step
+                # count on resume
+                new_state = state.replace(
+                    step=state.step + 1, params=params, opt_state=opt_state
+                )
+                metrics = {
+                    "loss": losses.mean(),
+                    "last_micro_loss": losses[-1],
+                    "grad_norm": grad_norm,
+                    "skipped": (~ok).astype(jnp.int32),
+                }
             return new_state, metrics
 
     return train_step
@@ -124,7 +128,7 @@ def make_eval_step(model, rules=DEFAULT_RULES):
     a forward-only program."""
 
     def eval_step(state: TrainState, data: jnp.ndarray):
-        with nn.logical_axis_rules(rules), jax.named_scope("eval_forward"):
+        with nn.logical_axis_rules(rules):
             return batch_loss(model, state.params, data)
 
     return eval_step

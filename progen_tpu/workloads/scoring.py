@@ -51,9 +51,11 @@ def score_step(model, params, batch):
     batch of that bucket re-executes."""
     from progen_tpu.training.loss import sequence_scores
 
-    ids, labels = batch[..., :-1], batch[..., 1:]
+    with jax.named_scope("head"):  # the model files the rest itself
+        ids, labels = batch[..., :-1], batch[..., 1:]
     logits = model.apply({"params": params}, ids)
-    return sequence_scores(logits, labels)
+    with jax.named_scope("head"):
+        return sequence_scores(logits, labels)
 
 
 class _ScoreStep:
