@@ -58,6 +58,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from progen_tpu.config import _DTYPES
+from progen_tpu.models.layers import _update_at
 
 # Rows of one prefill block: on a v5e a pass over the weights is bound by
 # reading them up to about 240 rows (sampling._FEED_ROWS has the readings)
@@ -208,21 +209,15 @@ def _swiglu(x, w_gate_up, w_down):
 
 def _write_rows(buf, new, positions, live):
     """Write each row's T consecutive positions into its cache rows,
-    keeping what is there wherever ``live`` is False."""
-    t = new.shape[1]
-
-    def one(b, n, p0, lv):
-        if lv is not None:
-            old = jax.lax.dynamic_slice_in_dim(b, p0, t, axis=0)
-            n = jnp.where(lv[:, None], n, old)
-        return jax.lax.dynamic_update_slice_in_dim(b, n, p0, axis=0)
-
+    keeping what is there wherever ``live`` is False: one in-place update
+    of the batch a leaf (``layers._update_at``, the mask its own operand),
+    not the gather and scatter of a vmapped slice and update."""
     with jax.named_scope("cache_write"):
         if live is None:
-            return jax.vmap(lambda b, n, p0: one(b, n, p0, None))(
-                buf, new, positions[:, 0]
-            )
-        return jax.vmap(one)(buf, new, positions[:, 0], live)
+            return jax.vmap(_update_at(0))(buf, new, positions[:, 0])
+        return jax.vmap(_update_at(0, masked=True))(
+            buf, new, positions[:, 0], live
+        )
 
 
 def grouped_experts(x, idx, weights, live, w_gate_up, w_down):

@@ -22,6 +22,8 @@ and every cache write (``_write_rows``) is ``cache_write``.
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import functools
 from typing import NamedTuple, Optional
 
@@ -31,6 +33,7 @@ import numpy as np
 from flax import linen as nn
 
 from progen_tpu.config import ProGenConfig
+from progen_tpu.ops import pallas_decode_attention, pallas_row_write
 from progen_tpu.ops.attention import local_attention
 from progen_tpu.ops.pallas_decode_attention import (
     decode_attention,
@@ -58,27 +61,100 @@ class DecodeRows(NamedTuple):
     live: Optional[jnp.ndarray]  # (T,) bool, a contiguous run; None = all
 
 
+# A leaf the row-write kernel refuses is rewritten whole, in one select,
+# where that moves at most this many bytes a slot: a pass over 256 KiB
+# read and written costs about what one slot's update costs as an op of
+# its own (about 1 us on a v5e, PERF.md §5), lanes padded to 128.
+_SELECT_BYTES_PER_SLOT = 256 * 1024
+
+# the counters ``row_write_paths`` hands out, innermost last
+_PATH_COUNTERS: list = []
+
+
+@contextlib.contextmanager
+def row_write_paths():
+    """Count, by path (``_row_write_path``), the batched row writes that
+    ``_update_at``'s rule traces inside the block: a Counter, filled when
+    the rule is traced, not when the program runs."""
+    paths = collections.Counter()
+    _PATH_COUNTERS.append(paths)
+    try:
+        yield paths
+    finally:
+        _PATH_COUNTERS.pop()
+
+
+def _row_write_path(axis_size: int, buf, new, axis: int) -> str:
+    """How the batched rule writes one row per slot at ``axis`` of the
+    unbatched leaf, from what the operands show: on a TPU, with more than
+    one slot and one row each, ``"kernel"`` where the row-write kernel
+    fits the pooled leaf (``ops/pallas_row_write.py``: the row axis the
+    second-minor, whole lanes and tiles) and ``"select"`` where the leaf
+    is small enough to rewrite whole (ProGen's ``slot_pos``, written
+    along its lanes); ``"loop"``, one update a slot, anywhere else."""
+    if (
+        axis_size == 1
+        or not pallas_decode_attention.on_tpu()
+        or new.shape[axis + 1] != 1
+    ):
+        return "loop"
+    if pallas_row_write.fits(buf.shape, buf.dtype, axis + 1):
+        return "kernel"
+    if buf.size * buf.dtype.itemsize <= axis_size * _SELECT_BYTES_PER_SLOT:
+        return "select"
+    return "loop"
+
+
 @functools.lru_cache(maxsize=None)
-def _update_at(axis: int):
+def _update_at(axis: int, masked: bool = False):
     """``dynamic_update_slice_in_dim`` along ``axis`` with a batching rule
     of its own. The serving pool vmaps the one-token apply over its slots,
     each with a start of its own; the plain update then becomes a scatter,
     which the TPU compiler runs as a serial loop over the slots — a bounds
     check, a row pick, a select and the update per slot, 74 such loops in
-    a decode step of ProGen-large, a third of its device time (PERF.md,
-    PR 29). One update per slot, written out, is the update alone."""
+    a decode step of ProGen-large, a third of its device time (PERF.md
+    §6). The rule writes every slot's row of a leaf in one call where it
+    can — the row-write kernel, or one select over a small leaf
+    (``_row_write_path``) —, else one update per slot, written out, which
+    is the update alone. ``masked`` adds an operand ``live`` (the T rows,
+    bool): a row that is not live keeps what ``buf`` holds."""
 
-    def plain(buf, new, start):
+    def plain(buf, new, start, *live):
+        if live:
+            shape = [1] * new.ndim
+            shape[axis] = new.shape[axis]
+            old = jax.lax.dynamic_slice_in_dim(
+                buf, start, new.shape[axis], axis=axis
+            )
+            new = jnp.where(live[0].reshape(shape), new, old)
         return jax.lax.dynamic_update_slice_in_dim(buf, new, start, axis=axis)
 
     update = jax.custom_batching.custom_vmap(plain)
 
     @update.def_vmap
-    def per_slot(axis_size, in_batched, buf, new, start):
-        buf, new, start = (
+    def per_slot(axis_size, in_batched, buf, new, start, *live):
+        buf, new, start, *live = (
             x if batched else jnp.broadcast_to(x, (axis_size,) + x.shape)
-            for x, batched in zip((buf, new, start), in_batched)
+            for x, batched in zip((buf, new, start, *live), in_batched)
         )
+        path = _row_write_path(axis_size, buf, new, axis)
+        if _PATH_COUNTERS:
+            _PATH_COUNTERS[-1][path] += 1
+        if path == "kernel":
+            return pallas_row_write.write_rows(
+                buf, new, start, *(x[:, 0] for x in live),
+                interpret=jax.default_backend() != "tpu",
+            ), True
+        size = buf.shape[axis + 1]
+        if path == "select":
+            at = jnp.where(start < 0, start + size, start).clip(0, size - 1)
+            shape = (axis_size,) + (1,) * (buf.ndim - 1)
+            hit = jax.lax.broadcasted_iota(
+                at.dtype, buf.shape, axis + 1
+            ) == at.reshape(shape)
+            if live:
+                hit = hit & live[0].reshape(shape)
+            return jnp.where(hit, new, buf), True
         # lax primitives, bound directly: through ``jnp`` indexing and
         # ``lax.dynamic_update_slice`` each of the slots x leaves updates
         # (2,368 in a decode step of ProGen-large) pays their Python, which
@@ -87,7 +163,7 @@ def _update_at(axis: int):
         # as one vector operation before the loop, the same updates take
         # 0.8 ms longer on a v5e (PERF.md, PR 29).
         zero = np.zeros((), start.dtype)
-        size = np.asarray(buf.shape[axis + 1], start.dtype)
+        size = np.asarray(size, start.dtype)
         at = [zero] * buf.ndim
         for s in range(axis_size):
             i = jax.lax.index_in_dim(start, s, keepdims=False)
@@ -96,6 +172,12 @@ def _update_at(axis: int):
             at[axis + 1] = jax.lax.select(
                 jax.lax.lt(i, zero), jax.lax.add(i, size), i
             )
+            if live:
+                shape = [1] * row.ndim
+                shape[axis + 1] = row.shape[axis + 1]
+                old = jax.lax.dynamic_slice(buf, at, row.shape)
+                keep = jax.lax.index_in_dim(live[0], s, keepdims=False)
+                row = jnp.where(keep.reshape(shape), row, old)
             buf = jax.lax.dynamic_update_slice_p.bind(buf, row, *at)
         return buf, True
 

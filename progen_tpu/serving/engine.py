@@ -66,6 +66,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from progen_tpu.models.layers import row_write_paths
 from progen_tpu.ops.pallas_decode_attention import kernel_block, listed_rows
 from progen_tpu.ops.quant import QuantizedParams, dequantized, quantize_tree
 from progen_tpu.sampling import (
@@ -354,9 +355,18 @@ def _decode_step(model, params, slots):
     every decode step rebuilds the full pool (cache + per-slot state)
     and the caller rebinds ``self.slots``, so without donation the
     engine held two copies of the (max_slots, 2w) K/V pool across every
-    step."""
+    step. Its trace records, by path, the cache-leaf writes it batched
+    (``ServeEngine.row_write_paths``)."""
     params = dequantized(params, model.config.compute_dtype)
-    return _decode_step_impl(model, params, slots)
+    with row_write_paths() as paths:
+        out = _decode_step_impl(model, params, slots)
+    _DECODE_ROW_WRITES[model] = dict(paths)
+    return out
+
+
+# per model, the paths the decode step's trace took for its batched cache
+# writes (``layers._row_write_path``): written when the step is traced
+_DECODE_ROW_WRITES: dict = {}
 
 
 def seed_key(seed: int) -> np.ndarray:
@@ -1082,7 +1092,21 @@ class ServeEngine:
             gauge = kinds.get(path[-1].key, kinds.get("*"))
             if gauge:
                 out[gauge] = out.get(gauge, 0) + leaf.nbytes
+        out.update(self.row_write_paths())
         return out
+
+    def row_write_paths(self) -> dict:
+        """Gauges of how the decode step writes its cache leaves, each
+        leaf's new rows for all slots at once (``layers._update_at``):
+        ``cache_write_leaves_<path>``, the leaf writes that took the
+        row-write kernel, the one select over a small leaf, or one update
+        a slot (``layers._row_write_path``). Recorded when the step is
+        traced: all 0 until its first launch."""
+        paths = _DECODE_ROW_WRITES.get(self.model, {})
+        return {
+            f"cache_write_leaves_{path}": paths.get(path, 0)
+            for path in ("kernel", "select", "loop")
+        }
 
     def collect(self, slot: int) -> np.ndarray:
         """The slot's (target,) token buffer with the standalone

@@ -147,6 +147,21 @@ HELP = {
         "the raw tree; else, while the engine holds a slot, the cast "
         "leaves' served bytes are resident on top of raw_weight_bytes)"
     ),
+    "cache_write_leaves_kernel": (
+        "Cache leaves whose new rows the decode step writes for all slots "
+        "in one row-write kernel call (ops/pallas_row_write.py), counted "
+        "when the step is traced"
+    ),
+    "cache_write_leaves_select": (
+        "Cache leaves, too small to need the kernel or written along their "
+        "lanes, that the decode step rewrites whole in one select for all "
+        "slots' new rows, counted when the step is traced"
+    ),
+    "cache_write_leaves_loop": (
+        "Cache leaves whose new rows the decode step writes as one update "
+        "a slot (off the TPU, or a leaf neither other path takes), counted "
+        "when the step is traced"
+    ),
     "xla_compile_count": (
         "XLA compile-or-load events of the whole process (jax.monitoring), "
         "those the decode/prefill jit-cache counts miss among them"

@@ -215,6 +215,9 @@ class Scheduler:
         self._publish_prefix_gauges()
 
     def _publish_compile_gauges(self) -> None:
+        # the decode step's paths are recorded when it is traced
+        for name, n in self.engine.row_write_paths().items():
+            self.metrics.set_gauge(name, n)
         self.metrics.set_gauge(
             "decode_compile_count", self.engine.decode_compile_count()
         )

@@ -36,7 +36,9 @@ MIXED = dict(
     global_mlp_depth=2, heads=2, dim_head=16, ff_mult=2, dtype="bfloat16",
 )
 SLOTS, LEN = 3, 48
-GAUGES = ("raw_weight_bytes", "served_weight_bytes", "served_leaves_cast")
+GAUGES = ("raw_weight_bytes", "served_weight_bytes", "served_leaves_cast",
+          "cache_write_leaves_kernel", "cache_write_leaves_select",
+          "cache_write_leaves_loop")
 
 
 def progen(**over):

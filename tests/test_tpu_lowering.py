@@ -252,3 +252,57 @@ class TestDecodeAttentionLowering:
             jnp.zeros((slots,), jnp.int32),
         )
         assert "tpu_custom_call" in exp.mlir_module()
+
+
+class TestRowWriteLowering:
+    """A decode step's cache writes (``models/layers.py::_update_at``'s
+    batching rule over the slots) at the leaf shapes the served cells
+    write, 32 slots: the row-write kernel (``ops/pallas_row_write.py``)
+    with its output aliased to the leaf, or, for a leaf it does not fit,
+    the one select that rewrites it."""
+
+    @pytest.mark.parametrize(
+        "leaf,dtype,axis,kernel",
+        [
+            ((1, 14, 1024, 128), jnp.bfloat16, 2, True),  # ProGen-large K, V
+            ((1, 1024, 3584), jnp.float32, 1, True),  # its gate history
+            ((1024,), jnp.int32, 0, False),  # its slot_pos: along lanes
+            ((1536, 512), jnp.bfloat16, 0, True),  # kanana2-30b-a3b latent
+            ((1536, 64), jnp.bfloat16, 0, False),  # its rope key: 64 lanes
+        ],
+    )
+    def test_the_batched_rule_lowers_for_tpu(self, monkeypatch, leaf, dtype,
+                                             axis, kernel):
+        import re
+
+        from progen_tpu.models.layers import _update_at
+        from progen_tpu.ops import pallas_decode_attention
+
+        # the rule as a TPU process traces it: its backend test and the
+        # kernel's interpret switch both read the backend
+        monkeypatch.setattr(pallas_decode_attention, "on_tpu", lambda: True)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        jax.clear_caches()
+        row = list(leaf)
+        row[axis] = 1
+        exp = _export_for_tpu(
+            jax.vmap(_update_at(axis)),
+            jnp.zeros((32,) + leaf, dtype), jnp.zeros((32, *row), dtype),
+            jnp.zeros((32,), jnp.int32),
+        )
+        jax.clear_caches()
+        mlir = exp.mlir_module()
+        calls = [ln for ln in mlir.splitlines()
+                 if "stablehlo.custom_call @tpu_custom_call" in ln]
+        assert len(calls) == int(kernel)
+        if kernel:
+            # the leaf, the kernel body's first argument, is the operand
+            # its output aliases: written in place
+            operands = re.search(r"tpu_custom_call\(([^)]*)\)",
+                                 calls[0]).group(1).split(", ")
+            alias = re.search(r"output_operand_alias<output_tuple_indices = "
+                              r"\[\], operand_index = (\d+)", calls[0])
+            assert operands[int(alias.group(1))] == "%arg0"
+        else:
+            assert "stablehlo.select" in mlir
+            assert "dynamic_update_slice" not in mlir
