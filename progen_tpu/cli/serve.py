@@ -9,7 +9,8 @@ event loop. Requests arrive as JSON lines, one object per request:
 (``id`` and ``prime`` required; everything else optional — ``length``
 defaults to --max-len.) ``"tokens": [ids]`` may stand in place of
 ``prime``: raw token ids, the only way in to a model family without a
-byte codec (``latent_moe``), which answers ids too — its token events
+byte codec (``latent_moe``, ``linear_sparse``, ``window_moe``), which
+answers ids too — its token events
 carry no ``text`` and its done event ``tokens`` in place of ``text``. The router's resume wire (serving/router.py)
 uses three extra optional fields: ``prime_tokens`` (raw token ids,
 bypassing the tokenizer), ``key`` (explicit uint32 PRNG key pair) and

@@ -28,6 +28,8 @@ FAMILIES = {
                    "LatentMoE"),
     "linear_sparse": ("progen_tpu.models.linear_sparse",
                       "LinearSparseConfig", "LinearSparse"),
+    "window_moe": ("progen_tpu.models.window_moe", "WindowMoEConfig",
+                   "WindowMoE"),
 }
 
 

@@ -220,17 +220,21 @@ def _write_rows(buf, new, positions, live):
         )
 
 
-def grouped_experts(x, idx, weights, live, w_gate_up, w_down):
+def grouped_experts(x, idx, weights, live, w_gate_up, w_down, held=None):
     """``sum_k weights[n, k] * E_idx[n, k](x[n])`` for the rows of ``x``
     (N, D) where ``live`` (N,), zero elsewhere. The N*K assignments are
     sorted by expert and each expert's run of rows is multiplied by its
     weights in one grouped product (``lax.ragged_dot``: on a TPU a
     grouped-matmul kernel that walks the groups that have rows, on a CPU
-    a masked dense product). Returns (y (N, D) float32, experts touched,
-    rows of the busiest expert)."""
+    a masked dense product). ``held`` (N, K) bool, where given, marks the
+    assignments whose expert the weights hold (a layer holding a share of
+    the experts the router chooses among); the others go to no expert, as
+    a dead row's do. Returns (y (N, D) float32, experts touched, rows of
+    the busiest expert)."""
     n, k = idx.shape
     e = w_gate_up.shape[0]
-    flat = jnp.where(live[:, None], idx, e).reshape(-1)  # dead: no expert
+    keep = live[:, None] if held is None else live[:, None] & held
+    flat = jnp.where(keep, idx, e).reshape(-1)  # dead: no expert
     order = jnp.argsort(flat, stable=True)
     sizes = jnp.zeros((e + 1,), jnp.int32).at[flat].add(1)[:e]
     h = jax.lax.ragged_dot(x[order // k], w_gate_up, sizes)

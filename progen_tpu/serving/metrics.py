@@ -83,8 +83,21 @@ HELP = {
         "steps"
     ),
     "moe_assignments": (
-        "Token-to-expert assignments computed by decode steps (live slots "
-        "x experts per token x expert layers); none is ever dropped"
+        "Token-to-expert assignments the router made in decode steps (live "
+        "slots x experts per token x expert layers); where a layer holds "
+        "every expert, none is dropped"
+    ),
+    "moe_held_assignments": (
+        "Of moe_assignments, those whose expert the layer holds and "
+        "computes (a layer holding a share of the router's experts drops "
+        "the others)"
+    ),
+    "moe_feed_assignments": (
+        "Token-to-expert assignments the router made in prefill blocks' "
+        "live rows, reported with the decode step that follows"
+    ),
+    "moe_feed_held_assignments": (
+        "Of moe_feed_assignments, those whose expert the layer holds"
     ),
     "moe_feed_expert_layer_blocks": (
         "Expert layers run by prefill blocks (blocks x expert layers); "
@@ -120,8 +133,14 @@ HELP = {
         "reported with the decode step that follows"
     ),
     "kv_cache_bytes": (
-        "Bytes of the slot pool's key and value rows (sparse attention "
-        "layers: max_len rows a slot, layer and key/value group)"
+        "Bytes of the slot pool's key and value rows that grow with the "
+        "sequence (sparse or full attention layers: max_len rows a slot, "
+        "layer and key/value head)"
+    ),
+    "window_cache_bytes": (
+        "Bytes of the slot pool's key and value rings (sliding-window "
+        "layers: sliding_window + one prefill block of rows a slot, layer "
+        "and key/value head, whatever the context)"
     ),
     "index_cache_bytes": (
         "Bytes of the slot pool's pooled keys, the index the block "

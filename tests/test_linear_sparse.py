@@ -312,7 +312,7 @@ def test_build_model_names_the_known_families_from_its_table():
     with pytest.raises(ValueError, match="unknown model family 'mamba'.*"
                        + ", ".join(FAMILIES)):
         build_model({"family": "mamba"})
-    assert list(FAMILIES) == ["progen", "latent_moe", "linear_sparse"]
+    assert list(FAMILIES) == ["progen", "latent_moe", "linear_sparse", "window_moe"]
     with pytest.raises(ValueError, match="linear_sparse family runs on one chip"):
         build_model(SMALL, mesh=object())
 

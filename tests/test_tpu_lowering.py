@@ -269,6 +269,8 @@ class TestRowWriteLowering:
             ((1024,), jnp.int32, 0, False),  # its slot_pos: along lanes
             ((1536, 512), jnp.bfloat16, 0, True),  # kanana2-30b-a3b latent
             ((1536, 64), jnp.bfloat16, 0, False),  # its rope key: 64 lanes
+            ((8, 4608, 128), jnp.bfloat16, 1, True),  # trinity's K, V rings
+            ((8, 11264, 128), jnp.bfloat16, 1, True),  # its full layer's rows
         ],
     )
     def test_the_batched_rule_lowers_for_tpu(self, monkeypatch, leaf, dtype,
