@@ -11,6 +11,7 @@ clock around ``Scheduler.step()`` times every token; the family's counters
 
 from __future__ import annotations
 
+import gc
 import time
 
 import jax
@@ -35,29 +36,30 @@ from progen_tpu.sampling import gumbel_step_dynamic
 # random weights attention is spread over many rows, so a window ignored
 # or a rotation misplaced moves the logits by little and these by much.
 # Every limit stands between a sound reading and a faulty one (PERF.md
-# section 6, PR 40; the faults were planted under this same function, in
-# the re-feed that the check reads): at the published widths on a CPU with
-# 3 layers and a 256-row window (prompt 640), the sound system read RMS
-# 0.89% and largest 4.3% of the logits' deviation, attention outputs 0.66%
-# (sliding) and 0.62% (full), slack 0.0018, exchanged 4.7%, redrawn 32 of
-# 32; the chip's readings at the cell's own size are in PERF.md.
+# section 6; the faults were planted under this same function, in the
+# re-feed that the check reads, on the cell's first weights: unit
+# post-attention scales, a table not centred). Over 29 runs on a TPU v5e at
+# the cell's own size (prompt 7,168, 32 decoded positions) the sound system
+# read at most RMS 0.99% and largest 5.5% of the logits' deviation,
+# attention outputs 0.55% (sliding) and 1.27% (full), slack 0.0029,
+# exchanged 7.0%, and redrew 32 of 32; PERF.md holds every seed's.
 #   logits - the reference with every matrix rounded to the 8-bit float (5
 #     exponent bits, 2 of mantissa) below the bfloat16 the configuration
-#     states: RMS 24%, largest 125%; a share off by one expert 27% / 166%.
-#   attention outputs - a window ignored in the sliding layers 57%; RoPE
-#     on the full layer 29% (its sliding twin unmoved: 0.66%); the 8-bit
-#     reference 12% / 24%.
+#     states: RMS 82%, largest 412%; a share off by one expert 29% / 212%;
+#     a window ignored in the sliding layers 17% / 78%.
+#   attention outputs - a window ignored 27% (sliding); RoPE on the full
+#     layers 16% (full; the sliding one unmoved at 0.48%); the 8-bit
+#     reference 29% / 84%.
 #   decisions, which the logits' limits cannot see (the reference is
 #     handed them) - how far an expert the system used may stand below the
 #     reference's own 4th best score (slack), and the share of (position,
 #     expert layer) choices that are not the reference's own (exchanged):
 #     ties among 256 sigmoid scores that the bfloat16 rounding of the
 #     router's input turns; every fault above moves the router's input and
-#     reads 0.11-0.23 and 17-78%.
+#     reads 0.018-0.44 and 24-98%.
 #   the timed program - of the 32 tokens engine.decode_step drew with all
-#     32 slots live, gumbel_step_dynamic redraws most from the logits this
-#     check reads alone; a pool-wide sampler ignoring top_k redraws ~0
-#     (PR 28).
+#     32 slots live, gumbel_step_dynamic redraws all from the logits this
+#     check reads alone; a pool-wide sampler ignoring top_k redraws none.
 RMS_TOLERANCE = 0.05
 MAX_TOLERANCE = 0.30
 ATTN_TOLERANCE = 0.05
@@ -72,19 +74,61 @@ LIMITS = (("rms_err_over_std", RMS_TOLERANCE),
           ("exchanged_share", EXCHANGED_TOLERANCE))
 
 
+# Random weights make attention average its whole context; the
+# post-attention norm scales that average up into a direction all of a
+# sequence's positions share, and the router follows it. At unit scales a
+# sequence's share of the assignments on this chip's experts spread by
+# 4-6% (absolute) from one sequence to the next, and the runs with it
+# (PERF.md section 6); at 1/8 by 1%, the spread that 1,024 independent
+# assignments have (the CPU, at the published widths). The published
+# sandwich norm is "depth-scaled": its scales start below one, by a rule
+# the config does not give.
+POST_ATTN_NORM_SCALE = 0.125
+BIAS_STD = 0.01  # decides the close choices, as a trained bias does
+
+
 def seeded_params(model, seed: int, device):
     """The model's parameters from the seed, made on the device in the
-    type the configuration states, with ``expert_bias`` drawn too (the
-    family initialises it to zero, which would leave the bias' part in
-    choosing experts untested)."""
+    type the configuration states, with four departures from the family's
+    initialisation, each so that what a run computes does not move with
+    the seed where a trained model's would not (PERF.md section 6):
+
+    * the embedding table centred: its mean row is what every position of
+      every sequence would share (router inputs of different sequences
+      pointed alike by a cosine of 0.33 at 1.5-2k positions, 0.02
+      centred);
+    * the post-attention norms' scales at ``POST_ATTN_NORM_SCALE``;
+    * each router's columns orthogonal, of the norm its normal draw has on
+      average (0.02 x sqrt(hidden)), so that no expert is chosen more
+      often for its draw;
+    * ``expert_bias`` drawn at ``BIAS_STD`` (the family starts it at zero,
+      which would leave the bias' part in choosing experts untested) less
+      its mean over each share of ``num_experts`` experts, so that no
+      chip's share is favoured on the whole: a trained router's bias
+      balances its loads. With a bias that was not centred, the share of
+      assignments this chip held spread by 2.6% (relative) over six
+      seeds."""
+    c = model.config
     params = traffic_mod.seeded_params(model, 8, seed, device)
     key = traffic_mod.fold_key(seed + 1)
 
     def draw(path, leaf):
-        if "expert_bias" not in jax.tree_util.keystr(path):
-            return leaf
-        k = jax.random.fold_in(key, sum(jax.tree_util.keystr(path).encode()))
-        return 0.01 * jax.random.normal(k, leaf.shape, leaf.dtype)
+        name = jax.tree_util.keystr(path)
+        k = jax.random.fold_in(key, sum(name.encode()))
+        if "embedding" in name:
+            table = leaf.astype(jnp.float32)
+            return (table - table.mean(0)).astype(leaf.dtype)
+        if "post_attn_norm" in name:
+            return jnp.full_like(leaf, POST_ATTN_NORM_SCALE)
+        if "w_router" in name:
+            norm = 0.02 * leaf.shape[0] ** 0.5
+            return jax.nn.initializers.orthogonal(norm)(
+                k, leaf.shape, jnp.float32).astype(leaf.dtype)
+        if "expert_bias" in name:
+            bias = BIAS_STD * jax.random.normal(k, leaf.shape, leaf.dtype)
+            shares = bias.reshape(-1, c.num_experts)
+            return (shares - shares.mean(1, keepdims=True)).reshape(leaf.shape)
+        return leaf
 
     with jax.default_device(device):
         return jax.tree_util.tree_map_with_path(draw, params)
@@ -115,7 +159,7 @@ def gen_requests(traffic: dict, vocab: int, seed: int):
     (``size_order_seed``), ids uniform in 1..vocab-1 from the seed: a
     request lasts a good part of the window, so a window holds a few
     cycles and a seed that permuted them would make runs do different
-    work (PR 33's finding)."""
+    work)."""
     pairs = traffic_mod.paired_cycles(
         traffic["prompt_lengths"], traffic["output_lengths"],
         traffic_mod.rng_for(traffic["size_order_seed"], "order"),
@@ -271,6 +315,20 @@ def run(run) -> dict:
     vocab = engine.model.config.num_tokens
     window = engine.model.config.sliding_window
     check = check_against_reference(run, engine)
+    # the check's programs (the reference's, the re-feed's) leave Python
+    # objects a server would not hold: moved out of the collector's way,
+    # and every full collection in the window is timed (``notes``)
+    gc.collect()
+    gc.freeze()
+    collections = []  # [start, seconds] of each full collection
+
+    def timed(phase, info):
+        if info["generation"] == 2 and phase == "start":
+            collections.append([time.perf_counter(), None])
+        elif info["generation"] == 2 and collections:
+            collections[-1][1] = time.perf_counter() - collections[-1][0]
+
+    gc.callbacks.append(timed)
     if run.tracing:
         run.wrap(engine, "decode_step", "engine.decode_step")
         run.wrap(engine, "advance_prefill", "engine.prefill")
@@ -315,11 +373,14 @@ def run(run) -> dict:
         submit(c, residual=(c + 1) / n_clients)
     initial = set(token_t)
 
-    t_open = None
+    t_open, long_steps, then = None, [], time.perf_counter()
     while True:
         with run.span("sched.step"):
             events, completions = sched.step()
         now = time.perf_counter()
+        if t_open is not None and now - then > 0.25:
+            long_steps.append([round(then - t_open, 3), round(now - then, 3)])
+        then = now
         for ev in events:
             times = token_t[ev.request_id]
             if not times:
@@ -345,6 +406,8 @@ def run(run) -> dict:
         elif run.due():
             break
     t_close = run.close_window()
+    gc.callbacks.remove(timed)
+    gc.unfreeze()
     if journal is not None:
         journal.close()
 
@@ -381,6 +444,11 @@ def run(run) -> dict:
              0.06, 0.08, 0.1, 0.15, 0.2, 0.3, 0.5]
     run.notes["itl_histogram"] = {"edges_s": edges,
                                   "counts": stats.histogram(gaps, edges)}
+    # steps over 0.25 s and full collections: [offset in the window, s]
+    run.notes["long_steps"] = long_steps
+    run.notes["full_collections"] = [
+        [round(start - t_open, 3), round(took or 0.0, 4)]
+        for start, took in collections if t_open <= start <= t_close]
     attempted = (n_submitted - sub0) + n_clients  # in flight at open + new
     return {
         "correct": check["ok"] and n_wrong == 0 and run.counters["engine_compiles_in_window"] == 0,

@@ -32,8 +32,9 @@ compared, not of arithmetic:
   before any exchange.
 * The vocabulary is the configuration's slice: ids and logits
   ``[0, vocab_size)``.
-* Weights are random; every norm's scale starts at one whatever the
-  published initialisation's depth scaling.
+* Weights are random, with the norms' scales the caller's (the cell's
+  driver sets them) whatever the published initialisation's depth
+  scaling.
 
 Weights are upcast from what the tree holds (bfloat16 as served) one
 layer, a few experts and a slice of the vocabulary at a time, and queries
