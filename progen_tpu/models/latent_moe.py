@@ -51,6 +51,7 @@ is ``ffn`` (``ffn/route``, ``ffn/experts``, ``ffn/shared``).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Mapping, Optional
 
 import jax
@@ -63,6 +64,18 @@ from progen_tpu.models.layers import _update_at
 # Rows of one prefill block: on a v5e a pass over the weights is bound by
 # reading them up to about 240 rows (sampling._FEED_ROWS has the readings)
 FEED_ROWS = 128
+# On a TPU ``lax.ragged_dot`` is a grouped-matmul kernel that visits only
+# the row tiles its groups cover, but multiplies a group's rows a whole
+# tile at a time, and the compiler takes the tile from the product's row
+# count: the largest power of two up to 512 that divides it (compiled for
+# a v5e: 512 at 512 and 2,048 rows, 256 at 256, 128 at 384, 64 at 192).
+# A held share's ≈ 8 rows an expert (of a 512-row block's 2,048
+# assignments) so cost 512 rows each. Timed on a v5e at 256 such rows
+# over 32 experts of 3072 -> 6144 and 3072 -> 3072, the two products take
+# 4.85 + 2.72 ms tiled by 512 (at 2,048 rows or 512), 3.52 + 2.09 by 256,
+# 2.94 + 1.79 by 128 and 2.81 + 1.69 by 64: a held share's products run
+# over odd multiples of this tile
+PRODUCT_TILE = 64
 
 
 @dataclasses.dataclass(frozen=True)
@@ -220,6 +233,34 @@ def _write_rows(buf, new, positions, live):
         )
 
 
+def _rungs(rows: int) -> tuple:
+    """The row counts a held share's grouped product may run over: the
+    odd multiples ``PRODUCT_TILE * (2^j - 1)``, j >= 2, below ``rows``,
+    then ``rows`` itself. The first is three tiles, so a call of up to
+    192 assignments (the decode step's 32 slots at top-4) keeps its one
+    product over every row."""
+    out, j = [], 2
+    while PRODUCT_TILE * (2 ** j - 1) < rows:
+        out.append(PRODUCT_TILE * (2 ** j - 1))
+        j += 1
+    return (*out, rows)
+
+
+def _rung(kept, rungs):
+    """Index of the smallest of ``rungs`` that holds ``kept`` rows."""
+    return jnp.sum((kept > jnp.asarray(rungs[:-1])).astype(jnp.int32))
+
+
+def product_rows(kept, rows: int):
+    """The rows a held share's grouped product runs over when ``kept`` of
+    its ``rows`` assignments are held: ``rows`` (a Python int) where no
+    rung lies below it, else the smallest rung that holds them."""
+    rungs = _rungs(rows)
+    if len(rungs) == 1:
+        return rows
+    return jnp.asarray(rungs, jnp.int32)[_rung(kept, rungs)]
+
+
 def grouped_experts(x, idx, weights, live, w_gate_up, w_down, held=None):
     """``sum_k weights[n, k] * E_idx[n, k](x[n])`` for the rows of ``x``
     (N, D) where ``live`` (N,), zero elsewhere. The N*K assignments are
@@ -229,19 +270,33 @@ def grouped_experts(x, idx, weights, live, w_gate_up, w_down, held=None):
     a masked dense product). ``held`` (N, K) bool, where given, marks the
     assignments whose expert the weights hold (a layer holding a share of
     the experts the router chooses among); the others go to no expert, as
-    a dead row's do. Returns (y (N, D) float32, experts touched, rows of
-    the busiest expert)."""
+    a dead row's do, and the products run over the first
+    ``product_rows(kept, N*K)`` sorted rows alone, which hold every kept
+    one. Returns (y (N, D) float32, experts touched, rows of the busiest
+    expert)."""
     n, k = idx.shape
     e = w_gate_up.shape[0]
     keep = live[:, None] if held is None else live[:, None] & held
     flat = jnp.where(keep, idx, e).reshape(-1)  # dead: no expert
     order = jnp.argsort(flat, stable=True)
     sizes = jnp.zeros((e + 1,), jnp.int32).at[flat].add(1)[:e]
-    h = jax.lax.ragged_dot(x[order // k], w_gate_up, sizes)
-    gate, up = jnp.split(h, 2, axis=-1)
-    ys = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w_down, sizes)
-    # rows past the last group belong to no expert: whatever is there
-    ys = jnp.where((jnp.arange(n * k) < jnp.sum(sizes))[:, None], ys, 0)
+
+    def product(rows):
+        whole = rows == n * k
+        h = jax.lax.ragged_dot(x[(order if whole else order[:rows]) // k],
+                               w_gate_up, sizes)
+        gate, up = jnp.split(h, 2, axis=-1)
+        ys = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w_down, sizes)
+        # rows past the last group belong to no expert: whatever is there
+        ys = jnp.where((jnp.arange(rows) < jnp.sum(sizes))[:, None], ys, 0)
+        return ys if whole else jnp.pad(ys, ((0, n * k - rows), (0, 0)))
+
+    rungs = (n * k,) if held is None else _rungs(n * k)
+    if len(rungs) == 1:
+        ys = product(n * k)
+    else:
+        ys = jax.lax.switch(_rung(jnp.sum(sizes), rungs),
+                            [functools.partial(product, r) for r in rungs])
     # back to (row, its k-th choice): each row sums its own products in
     # its own top-k order, whatever else the call held
     y = ys[jnp.argsort(order)].reshape(n, k, -1).astype(jnp.float32)

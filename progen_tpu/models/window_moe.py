@@ -27,8 +27,11 @@ layer's attention:
   num_experts)`` — one chip's share where experts are spread over chips —
   and computes only the assignments that land there: the others are
   dropped, with no stand-in for the chips that would hold them and no
-  exchange (``latent_moe.grouped_experts``' held-share mask). Where
-  ``num_experts`` equals ``router_experts`` the layer holds them all.
+  exchange (``latent_moe.grouped_experts``' held-share mask; the grouped
+  products of a call of more than three ``latent_moe.PRODUCT_TILE``
+  assignment rows run over the rows that hold the kept ones, a rung of a
+  ladder chosen on the device). Where ``num_experts`` equals ``router_experts`` the layer
+  holds them all.
 
 The decode mode (``config.decode``) feeds ``T >= 1`` positions of each of
 ``B`` rows through a ``cache`` collection whose state is sized by layer
@@ -66,7 +69,8 @@ from flax import linen as nn
 
 from progen_tpu.config import _DTYPES
 from progen_tpu.models.latent_moe import (DenseFFN, _init, _rms_norm, _rope,
-                                          feed_blocks, grouped_experts, route)
+                                          feed_blocks, grouped_experts,
+                                          product_rows, route)
 from progen_tpu.models.layers import _update_at
 
 SLIDING, FULL = "sliding_attention", "full_attention"
@@ -444,7 +448,7 @@ class WindowMoE(nn.Module):
             )(tokens)
             if c.mup_enabled:
                 x = x * math.sqrt(c.hidden_size)
-        stats = []
+        stats, product = [], []
         for i, kind in enumerate(c.layer_types):
             with jax.named_scope("project"):
                 y = WindowAttention(c, kind, name=f"attn{i}")(
@@ -457,6 +461,10 @@ class WindowMoE(nn.Module):
                 else:
                     y, st = HeldMoE(c, name=f"ffn{i}")(u, live)
                     stats.append(st)
+                    # the rows its grouped products ran over (a Python int
+                    # where they ran over every assignment)
+                    product.append(product_rows(
+                        st[2], b * t * c.num_experts_per_tok))
                 x = x + norm(f"post_mlp_norm{i}", y)
         with jax.named_scope("ffn"):
             stats = (jnp.stack(stats) if stats
@@ -465,17 +473,19 @@ class WindowMoE(nn.Module):
             # what the blocks fed through this cache met, kept with it
             # until a decode step's read carries it to the host: per expert
             # layer the blocks, held experts touched, busiest rows, held
-            # assignments and all assignments of their live rows
+            # assignments and all assignments of their live rows, and the
+            # rows their grouped products ran over
             fed = self.variable(
                 "cache", "moe_feed",
-                lambda: jnp.zeros((b, c.n_expert_layers, 5), jnp.int32),
+                lambda: jnp.zeros((b, c.n_expert_layers, 6), jnp.int32),
             )
             if t > 1 and not self.is_initializing():
                 with jax.named_scope("cache_write"):
                     assigned = jnp.sum(live.astype(jnp.int32)) * c.num_experts_per_tok
                     fed.value = fed.value + jnp.concatenate(
                         [jnp.ones_like(stats[:, :1]), stats,
-                         jnp.broadcast_to(assigned, stats[:, :1].shape)],
+                         jnp.broadcast_to(assigned, stats[:, :1].shape),
+                         jnp.asarray(product, jnp.int32).reshape(-1, 1)],
                         axis=1)[None]
         logits = None
         if head or self.is_initializing():
@@ -501,7 +511,7 @@ class WindowMoE(nn.Module):
         position each. Returns (logits (S, vocab), the pool's new cache,
         int32 counts for the host: per expert layer the held experts
         touched, the busiest one's rows and the held assignments in this
-        step, then the five counts of the prefill blocks whose caches
+        step, then the six counts of the prefill blocks whose caches
         entered the pool since the last step)."""
         (logits, stats), mut = self.apply(
             {"params": params,
@@ -524,7 +534,7 @@ class WindowMoE(nn.Module):
         c = self.config
         n = c.n_expert_layers
         step = counts[: 3 * n].reshape(n, 3)
-        fed = counts[3 * n:].reshape(n, 5)
+        fed = counts[3 * n:].reshape(n, 6)
         return {
             "moe_expert_layer_steps": n,
             "moe_assignments": n * n_live * c.num_experts_per_tok,
@@ -536,4 +546,5 @@ class WindowMoE(nn.Module):
             "moe_feed_max_load_rows": int(fed[:, 2].sum()),
             "moe_feed_held_assignments": int(fed[:, 3].sum()),
             "moe_feed_assignments": int(fed[:, 4].sum()),
+            "moe_feed_product_rows": int(fed[:, 5].sum()),
         }

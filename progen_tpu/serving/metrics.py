@@ -99,6 +99,13 @@ HELP = {
     "moe_feed_held_assignments": (
         "Of moe_feed_assignments, those whose expert the layer holds"
     ),
+    "moe_feed_product_rows": (
+        "Rows the grouped expert products of prefill blocks ran over, "
+        "summed over expert layers: where a layer holds a share of the "
+        "experts, the rung of rows that holds its kept assignments "
+        "(latent_moe.product_rows); over moe_feed_expert_layer_blocks x "
+        "feed rows x experts per token, the share of all rows run"
+    ),
     "moe_feed_expert_layer_blocks": (
         "Expert layers run by prefill blocks (blocks x expert layers); "
         "moe_feed_experts_touched and moe_feed_max_load_rows are summed "

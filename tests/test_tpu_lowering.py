@@ -308,3 +308,46 @@ class TestRowWriteLowering:
         else:
             assert "stablehlo.select" in mlir
             assert "dynamic_update_slice" not in mlir
+
+
+@pytest.fixture(scope="module")
+def one_v5e():
+    """One chip of a described v5e: the TPU compiler runs here without one."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # what is compiled for a described chip cannot be read back without one
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+class TestGroupedProductTiling:
+    """``models/latent_moe.py`` runs a held share's grouped products over
+    rungs of rows that are odd multiples of ``PRODUCT_TILE`` because the
+    compiler tiles a ``lax.ragged_dot``'s rows by the largest power of two
+    up to 512 that divides their count: compiled for a v5e at the
+    trinity cell's widths (32 held experts, 3072 -> 6144), every rung
+    below a 512-row block's 2,048 assignments is tiled by 64, the block's
+    whole 2,048 rows by 512."""
+
+    @pytest.mark.parametrize("rows", [192, 448, 960, 1984, 2048])
+    def test_each_rung_is_tiled_by_the_product_tile(self, one_v5e, rows):
+        import re
+
+        from progen_tpu.models import latent_moe
+
+        assert rows in latent_moe._rungs(2048)
+        shapes = [jax.ShapeDtypeStruct(s, d, sharding=one_v5e) for s, d in (
+            ((rows, 3072), jnp.bfloat16), ((32, 3072, 6144), jnp.bfloat16),
+            ((32,), jnp.int32))]
+        text = jax.jit(jax.lax.ragged_dot).lower(*shapes).compile().as_text()
+        tiles = re.findall(r'ragged_dot_tiling="(\d+),', text)
+        want = 512 if rows == 2048 else latent_moe.PRODUCT_TILE
+        assert tiles and all(int(t) == want for t in tiles)

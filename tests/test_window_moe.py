@@ -311,6 +311,8 @@ def test_a_request_in_company_is_bit_identical_to_the_same_request_alone():
     assert m["moe_feed_expert_layer_blocks"] == 3 * m["prefill_blocks"]
     assert 0 < m["moe_feed_held_assignments"] < m["moe_feed_assignments"]
     assert m["moe_feed_assignments"] == 3 * 4 * m["prefill_tokens"]
+    # a block's 8 rows at top-4 are 32 assignments: under every rung
+    assert m["moe_feed_product_rows"] == 32 * m["moe_feed_expert_layer_blocks"]
     # three rings of 40 rows and one full layer of 128, K and V, 3 slots
     assert m["window_cache_bytes"] == 3 * 2 * 3 * 2 * RING * 16 * 2
     assert m["kv_cache_bytes"] == 3 * 2 * 1 * 2 * 128 * 16 * 2
@@ -384,6 +386,135 @@ def _grouped_experts_before(x, idx, weights, live, w_gate_up, w_down):
     y = jnp.einsum("nk,nkd->nd", weights, y,
                    precision=jax.lax.Precision.HIGHEST)
     return y, jnp.sum((sizes > 0).astype(jnp.int32)), jnp.max(sizes)
+
+
+def _grouped_experts_all_rows(x, idx, weights, live, w_gate_up, w_down,
+                              held=None):
+    """``latent_moe.grouped_experts`` as it stood before a held share's
+    products ran over a rung of rows, verbatim: every product over all
+    N*K sorted rows."""
+    n, k = idx.shape
+    e = w_gate_up.shape[0]
+    keep = live[:, None] if held is None else live[:, None] & held
+    flat = jnp.where(keep, idx, e).reshape(-1)  # dead: no expert
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.zeros((e + 1,), jnp.int32).at[flat].add(1)[:e]
+    h = jax.lax.ragged_dot(x[order // k], w_gate_up, sizes)
+    gate, up = jnp.split(h, 2, axis=-1)
+    ys = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w_down, sizes)
+    # rows past the last group belong to no expert: whatever is there
+    ys = jnp.where((jnp.arange(n * k) < jnp.sum(sizes))[:, None], ys, 0)
+    # back to (row, its k-th choice): each row sums its own products in
+    # its own top-k order, whatever else the call held
+    y = ys[jnp.argsort(order)].reshape(n, k, -1).astype(jnp.float32)
+    y = jnp.einsum("nk,nkd->nd", weights, y,
+                   precision=jax.lax.Precision.HIGHEST)
+    return y, jnp.sum((sizes > 0).astype(jnp.int32)), jnp.max(sizes)
+
+
+# kept assignments of a 512-row block's 2,048, and the rung each runs over
+_RUNG_CASES = [(0, 192), (1, 192), (192, 192), (193, 448), (448, 448),
+               (449, 960), (511, 960), (513, 960), (961, 1984), (1985, 2048),
+               (2048, 2048)]
+
+
+@pytest.mark.parametrize("kept,rung", _RUNG_CASES + [("part", None)])
+def test_a_held_share_runs_its_products_over_the_rung_that_holds_its_rows(
+        kept, rung):
+    """A block of 512 rows, top-4 over 8 held experts: the products over
+    the smallest rung that holds the kept assignments give what the
+    products over all 2,048 rows give, bit for bit, with the same experts
+    touched and busiest rows; ``product_rows`` names the rung; every
+    assignment held takes all 2,048 rows. ``part``: a quarter of the rows
+    live and a random share held."""
+    from progen_tpu.models import latent_moe
+
+    n, k, e, d, f = 512, 4, 8, 16, 8
+    keys = jax.random.split(jax.random.PRNGKey(11), 5)
+    x = jax.random.normal(keys[0], (n, d), jnp.bfloat16)
+    idx = jax.random.randint(keys[1], (n, k), 0, e)
+    weights = jax.random.uniform(keys[2], (n, k))
+    w_gate_up = 0.3 * jax.random.normal(keys[3], (e, d, 2 * f), jnp.bfloat16)
+    w_down = 0.3 * jax.random.normal(keys[4], (e, f, d), jnp.bfloat16)
+    rng = np.random.default_rng(3)
+    if kept == "part":
+        live = jnp.asarray(rng.random(n) < 0.25)
+        held = jnp.asarray(rng.random((n, k)) < 0.3)
+        kept = int((live[:, None] & held).sum())
+        rung = 192
+        assert 0 < kept < 192  # a block whose rows are partly live
+    else:
+        live = jnp.ones((n,), bool)
+        held = np.zeros(n * k, bool)
+        held[rng.permutation(n * k)[:kept]] = True
+        held = jnp.asarray(held.reshape(n, k))
+    args = (x, idx, weights, live, w_gate_up, w_down)
+    got = latent_moe.grouped_experts(*args, held=held)
+    want = _grouped_experts_all_rows(*args, held=held)
+    assert int(latent_moe.product_rows(jnp.int32(kept), n * k)) == rung
+    assert (np.asarray(got[0]) == np.asarray(want[0])).all()
+    assert int(got[1]) == int(want[1]) and int(got[2]) == int(want[2])
+    if kept:
+        assert np.abs(np.asarray(want[0])).max() > 0  # not a comparison of zeros
+
+
+def test_fold_counts_reads_six_columns_of_what_blocks_fed():
+    model, _ = build()
+    n = model.config.n_expert_layers
+    step = np.tile([2, 3, 5], (n, 1))
+    fed = np.tile([4, 7, 9, 40, 96, 128], (n, 1))
+    got = model.fold_counts(np.concatenate([step.reshape(-1),
+                                            fed.reshape(-1)]), 6)
+    assert got["moe_held_assignments"] == 5 * n
+    assert got["moe_feed_expert_layer_blocks"] == 4 * n
+    assert got["moe_feed_assignments"] == 96 * n
+    assert got["moe_feed_product_rows"] == 128 * n
+
+
+def test_window_moe_decode_program_traces_as_before(monkeypatch):
+    """32 slots at top-4 are 128 assignment rows, the cell's decode step:
+    no rung lies below them, so the step is the program it was."""
+    from progen_tpu.serving import engine as E
+
+    model, params = build("bfloat16")
+    eng = ServeEngine(model, params, max_slots=32, max_len=64)
+
+    def program():
+        return str(jax.make_jaxpr(
+            lambda p, s: E._decode_step_impl(eng.model, p, s))(
+                eng.params, eng.slots))
+
+    now = program()
+    monkeypatch.setattr(wm, "grouped_experts", _grouped_experts_all_rows)
+    assert program() == now
+
+
+def test_the_lowered_programs_products_take_each_rungs_rows():
+    """Blocks of 512 rows, top-4 (2,048 assignments a block): each expert
+    layer of the chunk program holds the products of every rung, and the
+    decode step's of 32 slots take 128 rows alone."""
+    import re
+
+    from progen_tpu.models import latent_moe
+    from progen_tpu.serving import engine as E
+
+    model, params = build("bfloat16", feed_rows=512, sliding_window=512)
+    eng = ServeEngine(model, params, max_slots=32, max_len=1024)
+
+    def rows(traced):  # StableHLO for a TPU: ragged_dot stays whole
+        text = traced.lower(lowering_platforms=("tpu",)).as_text()
+        return [int(r) for r in re.findall(
+            r"chlo.ragged_dot.*\(tensor<(\d+)x\d+x", text)]
+
+    chunk = rows(E._prefill_chunk.trace(
+        eng.model, eng.params, eng.new_cache(), jnp.zeros((1024,), jnp.int32),
+        jnp.int32(0), jnp.int32(600)))
+    decode = rows(E._decode_step.trace(eng.model, eng.params, eng.slots))
+    layers = model.config.n_expert_layers
+    assert latent_moe._rungs(2048) == (192, 448, 960, 1984, 2048)
+    assert sorted(chunk) == sorted(
+        [r for r in latent_moe._rungs(2048) for _ in range(2 * layers)])
+    assert decode == [128] * 2 * layers
 
 
 def test_latent_moe_decode_and_chunk_programs_trace_as_before(monkeypatch):
