@@ -47,7 +47,9 @@ rows are the same rule with no wrap). Positions and ``live`` are
 ARGUMENTS, one per row and column, as in the other slot-batched families.
 A block of rows attends a chunk of rows at a time with a running softmax,
 up to the rows its positions reach; one row (the serving pool's decode
-step) reads its whole leaves in one pass.
+step) reads, on a TPU, only the blocks of its leaves that hold a position
+it sees (``ops/pallas_window_decode_attention.py``, where the leaves' shapes
+fit the kernel's tiling), and elsewhere its whole leaves in one pass.
 
 Mechanism classes (``jax.named_scope``, ``telemetry/scopes.py``): the
 embedding and the head are ``head``; a layer's attention with its norms
@@ -72,6 +74,8 @@ from progen_tpu.models.latent_moe import (DenseFFN, _init, _rms_norm, _rope,
                                           feed_blocks, grouped_experts,
                                           product_rows, route)
 from progen_tpu.models.layers import _update_at
+from progen_tpu.ops.pallas_window_decode_attention import (
+    kernel_block, listed_rows, window_decode_attention)
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 # the layer kinds RoPE turns (the published modeling code, not the config)
@@ -258,6 +262,19 @@ def visible(row_pos, t, window: Optional[int]):
     return ok
 
 
+def decode_leaf(config: WindowMoEConfig, kind: str):
+    """(rows, window, block) of the decode cache leaves of a layer of
+    ``kind``: its rows, the window its queries see (None: all rows up to
+    theirs) and the rows of the blocks a one-token step reads them in, None
+    where that step reads them whole (``kernel_block``)."""
+    c = config
+    sliding = kind == SLIDING
+    rows = min(c.ring_rows, c.cache_len) if sliding else c.cache_len
+    window = c.sliding_window if sliding else None
+    return rows, window, kernel_block(rows, window, c.num_key_value_heads,
+                                      c.head_dim, c.compute_dtype)
+
+
 def _key_chunk(rows: int, block: int) -> int:
     """The largest run of whole blocks up to ``_KEY_CHUNK`` rows that
     divides the cache."""
@@ -334,11 +351,12 @@ class WindowAttention(nn.Module):
             k, v = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)  # (B, G, T, d)
             gate = jax.nn.sigmoid(u @ w_g)
         window = c.sliding_window if sliding else None
+        block = None
         if not c.decode:
             # the sequence is its own cache, one row a position
             k_all, v_all, width = k, v, t
         else:
-            rows = min(c.ring_rows, c.cache_len) if sliding else c.cache_len
+            rows, _, block = decode_leaf(c, self.kind)
             names = ("ring_k", "ring_v") if sliding else ("k", "v")
             cache_k, cache_v = (
                 self.variable("cache", n, lambda: jnp.zeros((b, g, rows, d),
@@ -360,7 +378,14 @@ class WindowAttention(nn.Module):
         reading = (jax.named_scope("attend/window") if sliding
                    else jax.named_scope("attend/full"))
         with reading:
-            o = _attend_rows(q, k_all, v_all, positions, window, width)
+            if t == 1 and block is not None:
+                # one query a slot: only the blocks that hold what it sees
+                o = window_decode_attention(
+                    q[:, 0], k_all, v_all, positions[:, 0], window=window,
+                    block=block, interpret=jax.default_backend() != "tpu",
+                )[:, None]
+            else:
+                o = _attend_rows(q, k_all, v_all, positions, window, width)
         with jax.named_scope("project/gqa"):
             y = (o.reshape(b, t, h * d) * gate) @ w_o
         self.sow("intermediates", "out", y)  # for the tests and the check
@@ -527,6 +552,20 @@ class WindowMoE(nn.Module):
                 jax.tree.map(lambda c: c[:, None], new),
                 jnp.concatenate([stats.reshape(-1), fed.reshape(-1)]),
             )
+
+    def attend_rows(self, pos) -> dict:
+        """What a decode step's attention read of the leaves of the slots it
+        advanced (queries at ``pos``), summed over attention layers, as
+        increments of the serving counters: the blocks the kernel lists for
+        them, or whole leaves where the step reads them whole; and the rows
+        the leaves hold (host side, numpy)."""
+        read = held = 0
+        for kind in self.config.layer_types:
+            rows, window, block = decode_leaf(self.config, kind)
+            held += rows * len(pos)
+            read += rows * len(pos) if block is None else int(
+                listed_rows(pos, rows, window, block).sum())
+        return {"attend_rows_read": read, "attend_rows_held": held}
 
     def fold_counts(self, counts, n_live: int) -> dict:
         """What ``decode_slots`` reported for one step, as increments of

@@ -1034,6 +1034,9 @@ class ServeEngine:
             wrote = np.flatnonzero(was_live)
             if not self.slot_batched:
                 self._count_ring_rows(self._cur[wrote])
+            elif hasattr(self.model, "attend_rows"):
+                for name, by in self.model.attend_rows(self._cur[wrote]).items():
+                    self._count(name, by)
             self._cur[wrote] += 1
             self._rows[wrote, self._cur[wrote]] = sampled[wrote]
             self._live[finished] = False
@@ -1064,7 +1067,8 @@ class ServeEngine:
     def pop_counters(self) -> dict:
         """Counter increments gathered since the last call; the scheduler
         adds them to its ``ServingMetrics``: a family's own counts, the
-        ring rows of ProGen's decode steps, and ``prefill_cache_copies``
+        ring rows of ProGen's decode steps, the attention rows of a family
+        that counts them (``model.attend_rows``), and ``prefill_cache_copies``
         (batch-1 trees copied because a prefix cache shares them, counted
         when their admission activates: 0 without one)."""
         out, self._counters = self._counters, {}

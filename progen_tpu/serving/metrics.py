@@ -69,6 +69,19 @@ HELP = {
         "Rows of the live slots' K/V rings (live slots x 2 x window_size), "
         "summed over decode steps: the divisor of ring_rows_read"
     ),
+    "attend_rows_read": (
+        "Rows of the live slots' sliding rings and full-attention rows a "
+        "window_moe decode step's attention read, summed over attention "
+        "layers and decode steps: the blocks that hold a position a slot's "
+        "query sees where the decode kernel runs "
+        "(ops/pallas_window_decode_attention.py), whole leaves where it "
+        "does not"
+    ),
+    "attend_rows_held": (
+        "Rows of the live slots' sliding rings and full-attention rows, "
+        "summed over attention layers and decode steps: the divisor of "
+        "attend_rows_read"
+    ),
     "moe_expert_layer_steps": (
         "Expert layers run by decode steps (steps x expert layers): the "
         "divisor of moe_experts_touched, moe_max_load_rows and "

@@ -351,3 +351,118 @@ class TestGroupedProductTiling:
         tiles = re.findall(r'ragged_dot_tiling="(\d+),', text)
         want = 512 if rows == 2048 else latent_moe.PRODUCT_TILE
         assert tiles and all(int(t) == want for t in tiles)
+
+
+def _served_shapes(model, n_slots, max_len, init_len):
+    """(decode model, served params, a batch-1 cache, the pool) of an
+    engine of ``n_slots`` slots and ``max_len`` rows, as shapes alone: a
+    model at its served size is traced and lowered without its weights."""
+    from flax.core import meta
+
+    from progen_tpu.models import decode_model, unstack_params
+    from progen_tpu.serving.engine import SlotBatch
+    from progen_tpu.serving.served_tree import promoted_mask, serve_tree
+
+    dec = decode_model(model, max_len)
+    dtype = dec.config.compute_dtype
+    params = jax.eval_shape(lambda: unstack_params(meta.unbox(model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, init_len), jnp.int32)))["params"],
+        model.config))
+    mask = promoted_mask(params, dtype)
+    served = jax.eval_shape(lambda p: serve_tree(p, mask, dtype), params)
+    cache1 = jax.eval_shape(lambda: dec.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32))["cache"])
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    s, n = n_slots, max_len
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    slots = SlotBatch(
+        cache=jax.tree.map(lambda c: sd((s,) + c.shape, c.dtype), cache1),
+        seqs=sd((s, n), jnp.int32), cur=sd((s,), jnp.int32),
+        keys=sd((s,) + key.shape, key.dtype), nz=sd((s,), jnp.int32),
+        target=sd((s,), jnp.int32), temp=sd((s,), jnp.float32),
+        top_p=sd((s,), jnp.float32), top_k=sd((s,), jnp.int32),
+        parity=sd((s,), bool), live=sd((s,), bool),
+        template=sd((s, n), jnp.int32), frozen=sd((s, n), bool))
+    return dec, served, cache1, slots
+
+
+def _benchmark_model(name):
+    import json
+    from pathlib import Path
+
+    from progen_tpu.models import build_model
+
+    path = Path(__file__).resolve().parents[1] / "benchmark" / "configs"
+    cfg = json.loads((path / f"{name}.json").read_text())
+    return build_model(cfg), cfg
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """The served programs as a TPU process traces them: each kernel's
+    backend test and interpret switch read the backend. Source locations
+    are left out of the text, because a kernel's body carries them with
+    the checkout's path."""
+    from progen_tpu.ops import pallas_decode_attention
+    from progen_tpu.ops import pallas_window_decode_attention
+
+    monkeypatch.setattr(pallas_decode_attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(pallas_window_decode_attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    was = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+    jax.config.update("jax_traceback_in_locations_limit", was)
+
+
+# sha256 of ProGen-large's decode step (32 slots of 1,024 rows, the
+# large.gen-closed engine) lowered for a TPU by ``_served_shapes`` under
+# ``as_tpu``, taken at the parent commit 698d7b4 (jax 0.9.0): the window
+# decode kernel left ProGen's program as it was
+PROGEN_LARGE_DECODE = (
+    "75c6c5130180469484f2b48c45ab64921b5c307e467d38f820cbf22d18384831")
+
+
+class TestWindowDecodeLowering:
+    """The window_moe decode step's attention kernel
+    (ops/pallas_window_decode_attention.py) in the served programs."""
+
+    def test_the_decode_step_carries_the_kernel_and_the_chunk_none(
+            self, as_tpu):
+        """trinity-large-preview's cell at its served size: every attention
+        layer of the decode step calls the kernel (its four rings and its
+        full layer's rows, blocks of 512); a prefill block runs the plain
+        form."""
+        import re
+
+        from progen_tpu.serving import engine as E
+
+        model, cfg = _benchmark_model("trinity-large-preview")
+        dec, served, cache1, slots = _served_shapes(model, 32, 11264, 8)
+        decode = E._decode_step.trace(dec, served, slots).lower(
+            lowering_platforms=("tpu",)).as_text()
+        kernels = re.findall(r'kernel_name = "(\w+)"', decode)
+        assert kernels.count("window_decode_attention") == 2  # ring, full
+        calls = re.findall(r"call @(window_decode_attention\w*)\(", decode)
+        assert len(calls) == cfg["num_hidden_layers"]
+        row = jax.ShapeDtypeStruct((11264,), jnp.int32)
+        at = jax.ShapeDtypeStruct((), jnp.int32)
+        chunk = E._prefill_chunk.trace(dec, served, cache1, row, at, at).lower(
+            lowering_platforms=("tpu",)).as_text()
+        assert "window_decode_attention" not in chunk
+
+    def test_progen_large_decode_step_is_the_parents(self, as_tpu):
+        import hashlib
+
+        from progen_tpu.serving import engine as E
+
+        model, cfg = _benchmark_model("large")
+        dec, served, _, slots = _served_shapes(model, 32, 1024, cfg["seq_len"])
+        text = E._decode_step.trace(dec, served, slots).lower(
+            lowering_platforms=("tpu",)).as_text()
+        assert hashlib.sha256(text.encode()).hexdigest() == PROGEN_LARGE_DECODE

@@ -316,6 +316,75 @@ def test_a_request_in_company_is_bit_identical_to_the_same_request_alone():
     # three rings of 40 rows and one full layer of 128, K and V, 3 slots
     assert m["window_cache_bytes"] == 3 * 2 * 3 * 2 * RING * 16 * 2
     assert m["kv_cache_bytes"] == 3 * 2 * 1 * 2 * 128 * 16 * 2
+    # off the TPU a decode step reads those leaves whole
+    assert m["attend_rows_read"] == m["attend_rows_held"] == (
+        (3 * RING + 128) * m["decode_tokens"])
+
+
+# ----- the decode kernel through the engine --------------------------------
+
+# the smallest shapes the decode kernel's tiling fits: heads of 128, a
+# window of 256 in a ring of 384 rows, a full layer of 640, blocks of 128
+KERNEL_SHAPES = dict(head_dim=128, sliding_window=256, feed_rows=128)
+
+
+def _decode_through_the_engine(rule, monkeypatch):
+    """Three slots admitted at 250, 380 and 3 positions, then 12 decode
+    steps (the first slot crosses the window's edge, the second the ring's
+    end), under the kernel's backend test answered ``rule == "kernel"``:
+    (tokens a step, live slots a step, the engine's counters, the kernel's
+    calls)."""
+    from progen_tpu.ops import pallas_window_decode_attention as K
+
+    monkeypatch.setattr(K, "on_tpu", lambda: rule == "kernel")
+    calls = []
+    real = wm.window_decode_attention
+    monkeypatch.setattr(wm, "window_decode_attention",
+                        lambda *a, **kw: calls.append(kw) or real(*a, **kw))
+    jax.clear_caches()
+    model, params = build(**KERNEL_SHAPES)
+    engine = ServeEngine(model, params, max_slots=3, max_len=640)
+    for i, n in enumerate((250, 380, 3)):
+        pending = engine.begin_prefill(engine.acquire(), ids(n, 40 + i), 500,
+                                       add_bos=True, top_k=None, seed=i)
+        while not engine.advance_prefill(pending, None):
+            pass
+    engine.pop_counters()
+    tokens, live = [], []
+    for _ in range(12):
+        sampled, was_live, _ = engine.decode_step()
+        tokens.append(np.asarray(sampled))
+        live.append(np.asarray(was_live))
+    jax.clear_caches()
+    return np.stack(tokens), np.stack(live), engine.pop_counters(), calls
+
+
+def test_the_decode_kernel_reads_the_blocks_the_host_counts(monkeypatch):
+    """The same slots decoded by the plain form and by the kernel (interpret
+    mode, its backend test turned): the same tokens; the plain form reads
+    every leaf whole, the kernel the blocks that hold a visible row, as a
+    brute-force count of them says."""
+    with monkeypatch.context() as m:
+        want, live, plain, none = _decode_through_the_engine("plain", m)
+    got, live_k, counted, calls = _decode_through_the_engine("kernel", monkeypatch)
+    assert none == [] and calls
+    assert {kw["block"] for kw in calls} == {128}
+    assert (live == live_k).all() and live.all()
+    assert (got == want).all()
+    kinds = SMALL["layer_types"]
+    held = read = 0
+    for step in range(12):
+        for t in (250 + step, 380 + step, 3 + step):
+            for kind in kinds:
+                rows, window = (384, 256) if kind == wm.SLIDING else (640, None)
+                seen = np.asarray(wm.visible(wm.held_position(
+                    t, jnp.arange(rows), rows), t, window))
+                read += 128 * int(seen.reshape(-1, 128).any(axis=1).sum())
+                held += rows
+    assert plain["attend_rows_read"] == plain["attend_rows_held"] == held
+    assert counted["attend_rows_held"] == held
+    assert counted["attend_rows_read"] == read
+    assert 0.3 < read / held < 1.0
 
 
 # ----- what the family refuses, with a reason ------------------------------
